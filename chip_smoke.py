@@ -1,0 +1,537 @@
+"""The quickest proof that paddle_tpu still starts on the chip.
+
+    python chip_smoke.py                        # every phase the host can run
+    python chip_smoke.py --phases train,four_chip
+
+One process, one TPU host. Drives the main path through the public entry
+points at the full width of the GPT-3-Medium-class decoder the benchmark
+and the example use (hidden 1024, 16 heads, vocab 50304, 24 layers):
+
+- **train**: O2 bf16 + AdamW + fused chunked loss, batch 8 x seq 1024,
+  10 steps on one seeded batch through ``paddle_tpu.TrainStep``;
+- **flash**: the Pallas kernels against the unfused reference at
+  [2, 16, 4096, 64] bf16 (output and all three gradients, then one
+  dropout call), and the same widths at seq 4096 through ``TrainStep``
+  (depth cut to 4 layers for time), whose lowered program must hold the
+  Mosaic custom calls;
+- **serve**: ``InferenceServer(slots=8)`` with the default prefill
+  buckets, warmed up, then 32 concurrent mixed-length requests, greedy
+  and sampled, with zero compiles allowed and one greedy stream compared
+  with ``model.generate()``;
+- **four_chip**: the train model through ``DistributedTrainStep`` on
+  dp=2 x mp=2 and on sdp=4 with ZeRO-2, when the host has four chips.
+
+Any failed check or exception ends the process with a non-zero code and
+the phase named. On success the last line of stdout is
+``{"ok": true, "device": {...}}``. ``main()`` has no CPU path: it pins
+``JAX_PLATFORMS=tpu`` before importing jax and refuses any other
+platform. The phase functions take (config, sizes) so that
+``tests/test_chip_smoke.py`` can rehearse them tiny on the CPU.
+
+Wall and compile times below are set-up information printed as log
+lines; none of them is a benchmark result.
+"""
+from __future__ import annotations
+
+import argparse
+import gc
+import json
+import math
+import os
+import sys
+import time
+
+import numpy as np
+
+PHASES = ("train", "flash", "serve", "four_chip")
+
+
+class CheckFailed(Exception):
+    """A phase ran but one of its checks did not hold."""
+
+
+def check(ok, message: str) -> None:
+    if not ok:
+        raise CheckFailed(message)
+
+
+def log(message: str) -> None:
+    print(message, flush=True)
+
+
+def gpt_config(num_layers: int, seq: int, **overrides):
+    """The full-width config (no width is cut; depth and length are the
+    caller's)."""
+    from paddle_tpu.models.gpt import GPTConfig
+
+    kw = dict(vocab_size=50304, hidden_size=1024, num_layers=num_layers,
+              num_heads=16, max_position_embeddings=seq,
+              hidden_dropout_prob=0.0, attention_dropout_prob=0.0,
+              use_recompute=False, use_flash_attention=True,
+              loss_chunk=256, dtype="bfloat16")
+    kw.update(overrides)
+    return GPTConfig(**kw)
+
+
+def _o2_train_step(cfg, step_cls, **step_kw):
+    """Seeded model + AdamW under O2 bf16 — the recipe bench.py times."""
+    import paddle_tpu as pt
+    from paddle_tpu import amp
+    from paddle_tpu.models.gpt import GPTForCausalLM
+    from paddle_tpu.optimizer import AdamW
+
+    pt.seed(0)
+    model = GPTForCausalLM(cfg)
+    opt = AdamW(learning_rate=1e-4, weight_decay=0.01)
+    model, opt = amp.decorate(model, opt, level="O2", dtype="bfloat16")
+    # loss_fn=None: with loss_chunk the forward returns the loss itself
+    return step_cls(model, opt, loss_fn=None, **step_kw)
+
+
+def _seeded_ids(cfg, batch: int, seq: int) -> np.ndarray:
+    rng = np.random.default_rng(0)
+    return rng.integers(0, cfg.vocab_size, (batch, seq)).astype(np.int32)
+
+
+def _check_first_loss(loss: float, vocab_size: int) -> None:
+    # a randomly initialised LM is near-uniform over the vocabulary
+    check(abs(loss - math.log(vocab_size)) <= 1.0,
+          f"first loss {loss:.4f} is not within 1.0 of "
+          f"ln({vocab_size}) = {math.log(vocab_size):.4f}")
+
+
+def _run_steps(step, batch, steps: int, phase: str):
+    """``steps`` calls of ``step``, each ended by ``block_until_ready``
+    and a host read of a finite loss. Returns the losses and, for the
+    last call, the seconds until dispatch returned, until the result was
+    ready, and the host read took after that."""
+    import jax
+
+    losses = []
+    for i in range(steps):
+        t0 = time.perf_counter()
+        loss = step(batch)
+        t_dispatch = time.perf_counter() - t0
+        jax.block_until_ready(loss)
+        t_ready = time.perf_counter() - t0
+        value = float(np.asarray(loss))      # host read after the fence
+        t_read = time.perf_counter() - t0 - t_ready
+        check(np.isfinite(value), f"step {i}: loss is {value}")
+        losses.append(value)
+    log(f"[{phase}] losses: " + " ".join(f"{v:.4f}" for v in losses))
+    return losses, (t_dispatch, t_ready, t_read)
+
+
+# ---------------------------------------------------------------- train
+def train_phase(cfg, batch: int, seq: int, steps: int) -> dict:
+    import paddle_tpu as pt
+
+    step = _o2_train_step(cfg, pt.TrainStep)
+    ids = _seeded_ids(cfg, batch, seq)
+    losses, (t_dispatch, t_ready, t_read) = _run_steps(
+        step, (ids, ids), steps, "train")
+    # does block_until_ready fence? If it does, the step's time sits
+    # between dispatch and ready, and the read that follows finds the
+    # value already there
+    log(f"[train] last step: dispatch returned after "
+        f"{t_dispatch * 1e3:.1f} ms, block_until_ready after "
+        f"{t_ready * 1e3:.1f} ms, host read then took {t_read * 1e3:.2f} ms")
+    check(t_read < max(0.05, 0.2 * t_ready),
+          f"host read after block_until_ready took {t_read:.3f}s of a "
+          f"{t_ready:.3f}s step: block_until_ready did not wait for the "
+          f"result")
+    _check_first_loss(losses[0], cfg.vocab_size)
+    check(losses[-1] < losses[0],
+          f"loss did not fall on a fixed batch: {losses[0]:.4f} -> "
+          f"{losses[-1]:.4f}")
+    compiles = step.cache_stats()["compiles"]
+    check(compiles == 1, f"TrainStep traced {compiles} programs, want 1")
+    return {"first_loss": losses[0], "last_loss": losses[-1]}
+
+
+# ---------------------------------------------------------------- flash
+# Why 2% of the reference's largest element: kernel and reference both
+# compute in f32 from the same bf16 inputs (the package pins
+# jax_default_matmul_precision to float32, inside the kernels too) and
+# both round their results to bf16, so they differ by summation order and
+# one bf16 rounding — an ulp is 2^-8 = 0.4% of the value it rounds. On the
+# v5e the largest difference measured 0.5% of the largest element (PR 21).
+# A wrong mask, block index or softmax statistic moves the result by its
+# own magnitude, fifty times the tolerance.
+KERNEL_TOL = 2e-2
+
+
+def _kernel_vs_reference(shape, dtype: str, dropout_p: float) -> None:
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.kernels import flash_attention as fa
+
+    rng = np.random.default_rng(1)
+    q, k, v, w = (jnp.asarray(rng.standard_normal(shape), dtype)
+                  for _ in range(4))
+
+    def out_and_grads(attn):
+        """jit of (q, k, v, w, *extra) -> (out, dq, dk, dv) for the
+        cotangent ``w``; ``extra`` reaches ``attn`` traced."""
+        def run(q, k, v, w, *extra):
+            o, vjp = jax.vjp(lambda q, k, v: attn(q, k, v, *extra), q, k, v)
+            return (o, *vjp(w))
+        return jax.jit(run)
+
+    got = out_and_grads(
+        lambda q, k, v: fa.flash_attention_bhld(q, k, v, causal=True))(
+            q, k, v, w)
+    # the reference materialises [H, L, L] f32 scores several times over
+    # in its backward: one batch row at a time bounds that to a few GB
+    ref_fn = out_and_grads(
+        lambda q, k, v: fa.reference_attention_bhld(q, k, v, causal=True))
+    rows = [ref_fn(q[b:b + 1], k[b:b + 1], v[b:b + 1], w[b:b + 1])
+            for b in range(shape[0])]
+    want = [jnp.concatenate(parts, axis=0) for parts in zip(*rows)]
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        check(np.isfinite(a).all(), f"kernel {name} has non-finite values")
+        err, scale = float(np.abs(a - b).max()), float(np.abs(b).max())
+        log(f"[flash] kernel vs reference {name}: max|diff| {err:.3e} "
+            f"= {err / scale:.2e} of max|ref| {scale:.3e}")
+        check(err <= KERNEL_TOL * scale,
+              f"kernel {name} differs from the reference by {err:.3e} "
+              f"(> {KERNEL_TOL} x {scale:.3e})")
+
+    if dropout_p > 0.0:
+        # the flagship runs dropout 0, so only this call compiles the
+        # in-kernel PRNG path (forward and both backward kernels); the
+        # seed is a traced argument, as in a training step
+        drop = out_and_grads(
+            lambda q, k, v, seed: fa.flash_attention_bhld(
+                q, k, v, causal=True, dropout_p=dropout_p, seed=seed))
+        a, b, c = ([np.asarray(x, np.float32)
+                    for x in drop(q, k, v, w, jnp.int32(seed))]
+                   for seed in (7, 7, 8))
+        check(all(np.isfinite(x).all() for x in a),
+              "dropout call produced non-finite values")
+        check(all((x == y).all() for x, y in zip(a, b)),
+              "dropout is not deterministic for a fixed seed")
+        check((a[0] != c[0]).any(), "dropout ignores its seed")
+        log(f"[flash] dropout {dropout_p}: deterministic per seed, "
+            f"seed-sensitive, forward and backward finite")
+
+
+def flash_phase(cfg, batch: int, seq: int, steps: int, kernel_shape,
+                kernel_dtype: str = "bfloat16", dropout_p: float = 0.1,
+                min_mosaic_calls: int = 3) -> dict:
+    """``min_mosaic_calls``: how many Mosaic custom calls the lowered step
+    must hold at least — one each for the forward, dq and dk/dv kernels,
+    whose jitted wrappers lower to functions that every layer shares; the
+    interpreted kernels of the CPU rehearsal lower to none."""
+    import paddle_tpu as pt
+
+    _kernel_vs_reference(kernel_shape, kernel_dtype, dropout_p)
+
+    step = _o2_train_step(cfg, pt.TrainStep)
+    ids = _seeded_ids(cfg, batch, seq)
+    log(f"[flash] TrainStep at seq {seq} x {cfg.num_layers} layers")
+    losses, _ = _run_steps(step, (ids, ids), steps, "flash")
+    _check_first_loss(losses[0], cfg.vocab_size)
+    # the gate (should_use_flash) falls back to the XLA path silently, so
+    # look at the program itself
+    mosaic_calls = step.lower((ids, ids)).as_text().count("tpu_custom_call")
+    log(f"[flash] Mosaic custom calls in the lowered step: {mosaic_calls}")
+    check(mosaic_calls >= min_mosaic_calls,
+          f"lowered seq-{seq} step holds {mosaic_calls} Mosaic custom "
+          f"calls, want >= {min_mosaic_calls}: attention took the XLA path")
+    compiles = step.cache_stats()["compiles"]
+    check(compiles == 1, f"TrainStep traced {compiles} programs, want 1")
+    return {"mosaic_calls": mosaic_calls}
+
+
+# ---------------------------------------------------------------- serve
+def _report_divergence(model, prompt, served, solo) -> str:
+    """First differing position of two greedy streams and the logits
+    that decided it, from a teacher-forced forward over the common
+    prefix."""
+    import paddle_tpu as pt
+
+    n = min(len(served), len(solo))
+    pos = next((i for i in range(n) if served[i] != solo[i]), n)
+    if pos == n:
+        return f"lengths differ: served {len(served)}, solo {len(solo)}"
+    ids = np.concatenate([prompt, solo[:pos]]).astype(np.int32)[None]
+    logits = np.asarray(pt.EvalStep(model)(ids), np.float32)[0, -1]
+    top = np.argsort(logits)[::-1][:2]
+    a, b = int(served[pos]), int(solo[pos])
+    return (f"first difference at generated position {pos}: served {a} "
+            f"(logit {logits[a]:.6f}) vs generate() {b} (logit "
+            f"{logits[b]:.6f}), gap {abs(logits[a] - logits[b]):.3e}; "
+            f"teacher-forced top-2 {int(top[0])}/{int(top[1])} gap "
+            f"{logits[top[0]] - logits[top[1]]:.3e}")
+
+
+def serve_phase(cfg, slots: int, prompt_lens, n_requests: int,
+                new_tokens=(8, 24), expect_donation: bool = True,
+                timeout: float = 600.0) -> dict:
+    """``expect_donation``: the engine donates its KV cache to the decode
+    program on an accelerator and, by its own branch, not on the CPU."""
+    import jax
+    import paddle_tpu as pt
+    from paddle_tpu.framework import compile_cache
+    from paddle_tpu.models.gpt import GPTForCausalLM
+    from paddle_tpu.serving import InferenceServer
+
+    pt.seed(0)
+    model = GPTForCausalLM(cfg)
+    model.eval()
+    server = InferenceServer(model, slots=slots)
+    engine = server.engine
+    buckets = engine.prefill_buckets
+    used = sorted({engine.bucket_for_prompt(n) for n in prompt_lens})
+    check(len(used) >= min(3, len(buckets)),
+          f"prompt lengths {list(prompt_lens)} cover only buckets {used}")
+
+    warm = engine.warmup()
+    log(f"[serve] buckets {list(buckets)}; warmup traced "
+        f"{warm['prefill_compiles']} prefill + {warm['decode_compiles']} "
+        f"decode programs")
+    check(warm["prefill_compiles"] == len(buckets)
+          and warm["decode_compiles"] == 1,
+          f"warmup traced {warm}, want {len(buckets)} prefill + 1 decode")
+
+    rng = np.random.default_rng(2)
+    requests = []
+    for i in range(n_requests):
+        n = int(prompt_lens[i % len(prompt_lens)])
+        requests.append((
+            rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32),
+            int(rng.integers(new_tokens[0], new_tokens[1] + 1)),
+            bool(i % 2)))                  # odd requests sample
+
+    cache_before = jax.tree.leaves(engine.live_cache)[0]
+    # zero compiles from here on: a trace anywhere in the process raises
+    # inside the serve loop and fails the request that caused it
+    with compile_cache.retrace_guard(0, label="serve traffic"), server:
+        handles = [
+            server.submit(p, max_new_tokens=n, do_sample=sample,
+                          temperature=0.8, top_p=0.95, seed=100 + i)
+            for i, (p, n, sample) in enumerate(requests)]
+        results = [h.result(timeout=timeout) for h in handles]
+        snap = server.snapshot()
+
+    for i, ((p, n, _), toks) in enumerate(zip(requests, results)):
+        check(len(toks) == n, f"request {i} asked {n} tokens, got {len(toks)}")
+        check(((toks >= 0) & (toks < cfg.vocab_size)).all(),
+              f"request {i} produced a token outside [0, {cfg.vocab_size})")
+    # the serve loop survives faults by resetting and requeueing; a fault
+    # it recovered from is still a fault here
+    for key in ("requests_failed", "requests_requeued", "requests_expired",
+                "requests_rejected"):
+        check(snap[key] == 0, f"serving metrics report {key}={snap[key]}")
+    check(snap["requests_completed"] == n_requests,
+          f"{snap['requests_completed']} of {n_requests} requests completed")
+    decoded = snap["tokens_emitted"] - snap["prefills"]
+    mean_batch = decoded / max(snap["decode_steps"], 1)
+    log(f"[serve] {n_requests} requests, {snap['tokens_emitted']} tokens, "
+        f"{snap['decode_steps']} decode steps, mean live slots per step "
+        f"{mean_batch:.2f} of {slots}")
+    check(mean_batch >= slots / 2,
+          f"slots never filled: mean {mean_batch:.2f} live of {slots}")
+
+    cc = engine.cache_stats()
+    traced = cc["prefill"]["compiles"] + cc["decode"]["compiles"]
+    check(traced == len(buckets) + 1,
+          f"{traced} serving programs traced, want #buckets + 1 = "
+          f"{len(buckets) + 1}")
+    donated = cache_before.is_deleted()
+    log(f"[serve] programs {traced} = {len(buckets)} buckets + 1; "
+        f"pre-traffic KV buffer deleted (donated): {donated}")
+    check(donated == expect_donation,
+          f"KV cache donation is {donated}, want {expect_donation}")
+
+    # greedy parity with the offline engine (own programs, own cache)
+    i0 = next(i for i, r in enumerate(requests) if not r[2])
+    prompt, n, _ = requests[i0]
+    solo = np.asarray(model.generate(prompt[None], max_new_tokens=n))[0]
+    served = np.asarray(results[i0])
+    if not np.array_equal(served, solo):
+        raise CheckFailed(
+            "greedy stream != model.generate(): "
+            + _report_divergence(model, prompt, served, solo))
+    log(f"[serve] greedy request {i0} ({len(prompt)}-token prompt, {n} new "
+        f"tokens) equals model.generate()")
+    return {"programs": traced, "donated": donated}
+
+
+# ------------------------------------------------------------ four chips
+def four_chip_phase(cfg, batch: int, seq: int, ref_first_loss: float,
+                    n_devices: int = 4, loss_tol: float = 0.05,
+                    balance: float = 1.5) -> dict:
+    """``loss_tol``: sharding changes the order of bf16 reductions (mp
+    splits every contraction in two and sums the halves), which moves
+    the f32 loss in its third decimal; a missing or doubled collective
+    moves it by whole units."""
+    import jax
+    from paddle_tpu.distributed.mesh import init_mesh, set_mesh
+    from paddle_tpu.distributed.shard import DistributedTrainStep
+
+    devices = jax.devices()[:n_devices]
+    ids = _seeded_ids(cfg, batch, seq)
+    out = {}
+    for label, axes, stage in (("dp2xmp2", {"dp": 2, "mp": 2}, 0),
+                               ("sdp4-zero2", {"sdp": n_devices}, 2)):
+        mesh = init_mesh(devices=devices, **axes)
+        try:
+            step = _o2_train_step(cfg, DistributedTrainStep, mesh=mesh,
+                                  sharding_stage=stage)
+            loss = step((ids, ids))
+            jax.block_until_ready(loss)
+            loss = float(np.asarray(loss))
+            log(f"[four_chip] {label}: first loss {loss:.4f} "
+                f"(single chip {ref_first_loss:.4f})")
+            check(abs(loss - ref_first_loss) <= loss_tol,
+                  f"{label}: first loss {loss:.4f} differs from the "
+                  f"single-chip {ref_first_loss:.4f} by more than {loss_tol}")
+            compiles = step.cache_stats()["compiles"]
+            check(compiles == 1, f"{label}: traced {compiles} programs")
+
+            leaves = jax.tree.leaves(
+                {"params": step.params, "opt_state": step.opt_state})
+            spread = {len(x.sharding.device_set) for x in leaves}
+            check(spread == {n_devices},
+                  f"{label}: state lives on device sets of sizes {spread}")
+            named = set()
+            for x in leaves:
+                for entry in getattr(x.sharding, "spec", ()):
+                    named.update(entry if isinstance(entry, tuple)
+                                 else (entry,))
+            want = {a for a in axes if a != "dp"}   # dp shards the batch only
+            check(want <= named,
+                  f"{label}: no state leaf is sharded over {want - named}")
+
+            # the eager Layer keeps its own copy of the parameters on the
+            # default device; everything else should be spread evenly
+            layer_bytes = sum(int(p.nbytes) for _, p in
+                              step.model.named_parameters())
+            in_use = [d.memory_stats()["bytes_in_use"] for d in devices]
+            even = [in_use[0] - layer_bytes] + in_use[1:]
+            log(f"[four_chip] {label}: bytes in use per device "
+                f"{[round(b / 2**30, 2) for b in in_use]} GiB (device 0 "
+                f"holds the Layer's own {layer_bytes / 2**30:.2f} GiB)")
+            check(min(even) > 0 and max(even) <= balance * min(even),
+                  f"{label}: memory is not spread over the devices: "
+                  f"{in_use} bytes in use")
+            out[label] = loss
+        finally:
+            set_mesh(None)
+        del step, leaves
+        _release_device_memory()
+    return out
+
+
+# ------------------------------------------------------------------ main
+def _release_device_memory() -> None:
+    """Free the last phase's HBM: the step classes hold reference cycles
+    (a jit of a bound method) and compiled programs pin their buffers."""
+    import jax
+
+    gc.collect()
+    jax.clear_caches()
+    gc.collect()
+
+
+def _run_phase(name: str, fn):
+    from paddle_tpu.framework import compile_cache
+
+    before = compile_cache.backend_compile_stats()
+    t0 = time.perf_counter()
+    log(f"[{name}] start")
+    try:
+        result = fn()
+    except Exception:
+        log(f"[{name}] FAILED after {time.perf_counter() - t0:.1f} s")
+        raise
+    after = compile_cache.backend_compile_stats()
+    log(f"[{name}] passed: wall {time.perf_counter() - t0:.1f} s, of which "
+        f"trace+lower+compile "
+        f"{after['compile_seconds'] - before['compile_seconds']:.1f} s; "
+        f"executables requested {after['requests'] - before['requests']}, "
+        f"persistent-cache hits "
+        f"{after['persistent_hits'] - before['persistent_hits']}, backend "
+        f"compiles {after['backend_compiles'] - before['backend_compiles']}")
+    _release_device_memory()
+    return result
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--phases", default=",".join(PHASES),
+                    help="comma-separated subset of " + ",".join(PHASES))
+    args = ap.parse_args(argv)
+    phases = [p for p in args.phases.split(",") if p]
+    unknown = sorted(set(phases) - set(PHASES))
+    if unknown:
+        ap.error(f"unknown phases {unknown}")
+
+    # the TPU, or JAX's own error: there is no other backend to fall to
+    os.environ["JAX_PLATFORMS"] = "tpu"
+    t_start = time.perf_counter()
+    import jax
+
+    jax.config.update("jax_platforms", "tpu")
+    devices = jax.devices()
+    device = {"platform": devices[0].platform,
+              "kind": devices[0].device_kind, "count": len(devices)}
+    if device["platform"] != "tpu":
+        raise RuntimeError(f"chip_smoke needs a TPU, jax gave {device}")
+    import importlib.metadata
+
+    from paddle_tpu.framework import compile_cache
+
+    cache_dir = compile_cache.enable_persistent_cache()
+
+    import jaxlib
+
+    log(f"device: {json.dumps(device)}")
+    log(f"versions: jax {jax.__version__}, jaxlib {jaxlib.__version__}, "
+        f"libtpu {importlib.metadata.version('libtpu')}, "
+        f"python {sys.version.split()[0]}")
+    log(f"compile cache: {cache_dir}")
+
+    done = {}
+    train = None
+    if "train" in phases:
+        train = _run_phase("train", lambda: train_phase(
+            gpt_config(24, 1024), batch=8, seq=1024, steps=10))
+        done["train"] = "passed"
+    if "flash" in phases:
+        _run_phase("flash", lambda: flash_phase(
+            gpt_config(4, 4096), batch=2, seq=4096, steps=2,
+            kernel_shape=(2, 16, 4096, 64)))
+        done["flash"] = "passed"
+    if "serve" in phases:
+        # the serving preset of tools/decode_bench.py: f32 weights, bf16
+        # KV cache, every default prefill bucket up to 1024
+        _run_phase("serve", lambda: serve_phase(
+            gpt_config(24, 1024, loss_chunk=0), slots=8,
+            prompt_lens=(20, 50, 100, 200, 400, 900), n_requests=32))
+        done["serve"] = "passed"
+    if "four_chip" in phases:
+        if len(devices) < 4:
+            log(f"[four_chip] SKIPPED: needs 4 devices, this host has "
+                f"{len(devices)}")
+            done["four_chip"] = f"skipped: {len(devices)} device(s)"
+        elif train is None:
+            raise RuntimeError("the four_chip phase compares with the "
+                               "train phase's first loss: select both")
+        else:
+            _run_phase("four_chip", lambda: four_chip_phase(
+                gpt_config(24, 1024), batch=8, seq=1024,
+                ref_first_loss=train["first_loss"]))
+            done["four_chip"] = "passed"
+
+    log(f"all selected phases passed in {time.perf_counter() - t_start:.1f} s")
+    print(json.dumps({"ok": True, "device": device, "phases": done}),
+          flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -15,7 +15,6 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import _env  # noqa: F401,E402  (cpu-pinned runs skip accelerator discovery)
 
 import numpy as np
 

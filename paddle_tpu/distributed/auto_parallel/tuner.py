@@ -9,8 +9,7 @@ TPU-native reformulation: the search space is the GSPMD mesh itself —
 (dp, sdp/ZeRO, mp, pp, sp) factorizations of the chip count — scored by a
 roofline cost model whose constants come from MEASUREMENTS:
 
-- achieved MFU from the recorded end-to-end bench (``bench.py`` JSON /
-  ``tools/op_bench_baseline_tpu.json``),
+- achieved MFU from the recorded end-to-end bench (``bench.py`` JSON),
 - ICI bandwidth from a live collective micro-bench (:func:`measure_ici`)
   when a mesh is available.
 

@@ -49,54 +49,40 @@ def init_parallel_env(coordinator_address: Optional[str] = None,
             # which broadcasts to assert value equality) dies with
             # "Multiprocess computations aren't implemented on the CPU
             # backend" — the simulated-mesh test/CI path needs gloo
-            try:
-                jax.config.update("jax_cpu_collectives_implementation",
-                                  "gloo")
-            except (AttributeError, ValueError):  # jaxlib without gloo
-                pass
-            else:
-                if _backends_initialized():
-                    # the config only shapes CpuClient CONSTRUCTION — a
-                    # backend built before this call has no collectives
-                    # layer, and the update above is silently inert
-                    import warnings
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
+            if _backends_initialized():
+                # the config only shapes CpuClient CONSTRUCTION — a
+                # backend built before this call has no collectives
+                # layer, and the update above is silently inert
+                import warnings
 
-                    warnings.warn(
-                        "init_parallel_env: the CPU backend was already "
-                        "initialized, so the gloo collectives config "
-                        "cannot take effect — cross-process programs will "
-                        "fail with 'Multiprocess computations aren't "
-                        "implemented on the CPU backend'. Call "
-                        "init_parallel_env before anything touches a jax "
-                        "array.", RuntimeWarning)
+                warnings.warn(
+                    "init_parallel_env: the CPU backend was already "
+                    "initialized, so the gloo collectives config "
+                    "cannot take effect — cross-process programs will "
+                    "fail with 'Multiprocess computations aren't "
+                    "implemented on the CPU backend'. Call "
+                    "init_parallel_env before anything touches a jax "
+                    "array.", RuntimeWarning)
         jax.distributed.initialize(coordinator_address=coord,
                                    num_processes=nproc, process_id=pid)
     _initialized = True
 
 
 def _backends_initialized() -> bool:
-    try:
-        from jax._src import xla_bridge
+    # no public spelling of this query exists in jax 0.9
+    from jax._src import xla_bridge
 
-        return xla_bridge.backends_are_initialized()
-    except Exception:
-        return False
+    return xla_bridge.backends_are_initialized()
 
 
 def _cpu_platform_requested() -> bool:
-    """True when the process is pinned to the CPU backend (env or config)
-    but no backend is live yet — ``jax.default_backend()`` would initialise
-    one, so prefer the declared intent."""
-    try:
-        from jax._src import xla_bridge
-
-        if xla_bridge.backends_are_initialized():
-            return jax.default_backend() == "cpu"
-    except Exception:
-        pass
-    plats = (os.environ.get("JAX_PLATFORMS", "")
-             or getattr(jax.config, "jax_platforms", None) or "")
-    return "cpu" in str(plats).split(",")
+    """True when the process is pinned to the CPU backend. Before a
+    backend is live the declared platform decides —
+    ``jax.default_backend()`` would initialise one."""
+    if _backends_initialized():
+        return jax.default_backend() == "cpu"
+    return "cpu" in (jax.config.jax_platforms or "").split(",")
 
 
 def get_rank() -> int:

@@ -244,7 +244,8 @@ class IntegrityChecker:
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+
+        from ..framework.jax_compat import shard_map
 
         flat_opt: Dict[str, Any] = {}
         for slot, val in opt_state.items():
@@ -300,7 +301,7 @@ class IntegrityChecker:
         in_specs = tuple(P(*s) if not isinstance(s, P) else s for s in specs)
         return shard_map(per_replica, mesh=self.mesh, in_specs=in_specs,
                          out_specs=P(self.vote_axis, None),
-                         check_rep=False)(*vals)
+                         check_vma=False)(*vals)
 
 
 # ---------------------------------------------------------------------------

@@ -21,8 +21,7 @@ from __future__ import annotations
 import weakref
 from typing import Dict, Optional
 
-from .embedding import (SparseEmbedding, StagedPull, callbacks_supported,
-                        make_lookup)
+from .embedding import SparseEmbedding, StagedPull, make_lookup
 from .coordinator import (ClientInfoAttr, Coordinator, FLClient, FLStrategy)
 from .graph import (DistGraphClient, GraphDataGenerator, GraphServer,
                     GraphTable, launch_graph_servers)
@@ -35,7 +34,7 @@ from .table import (MemoryDenseTable, MemorySparseTable, SSDSparseTable,
 __all__ = [
     "SparseAccessorConfig", "MemorySparseTable", "MemoryDenseTable",
     "SSDSparseTable", "PsRpcError",
-    "SparseEmbedding", "StagedPull", "callbacks_supported", "make_lookup",
+    "SparseEmbedding", "StagedPull", "make_lookup",
     "PsServer", "PsClient", "Communicator", "launch_servers", "shard_of",
     "ClientInfoAttr", "Coordinator", "FLClient", "FLStrategy",
     "GraphTable", "GraphServer", "DistGraphClient", "GraphDataGenerator",

@@ -17,8 +17,9 @@ microseconds dispatch. This module makes that cost visible and bounded:
   budget warns or raises :class:`RetraceError` *at trace time*, naming the
   offending function and signature;
 - :func:`enable_persistent_cache` wires jax's persistent compilation cache
-  (``FLAGS_persistent_compile_cache`` / ``FLAGS_compile_cache_dir``), so
-  a restarted process pays tracing but not backend compilation.
+  (at ``JAX_COMPILATION_CACHE_DIR`` when set, else a fixed path in the
+  checkout), so a restarted process pays tracing but not backend
+  compilation.
 
 Trace count is the retrace signal, not XLA's internal executable cache:
 a trace is exactly one new specialization from the framework's point of
@@ -37,7 +38,7 @@ from typing import Any, Callable, Dict, Optional
 __all__ = [
     "RetraceError", "cache_stats", "reset_stats", "instrument",
     "register_name", "retrace_guard", "enable_persistent_cache",
-    "initialize_from_flags",
+    "backend_compile_stats", "initialize_from_flags",
 ]
 
 
@@ -226,47 +227,107 @@ def retrace_guard(max_compiles: int = 0, action: str = "raise",
 # ------------------------------------------------- persistent XLA cache
 _persistent_dir: Optional[str] = None
 
+#: where the cache lives when nobody says otherwise: a fixed path next to
+#: the package (the checkout root), so two runs of the same command from
+#: the same tree share it; listed in ``.gitignore``
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
 
 def enable_persistent_cache(cache_dir: Optional[str] = None,
                             min_compile_secs: Optional[float] = None) -> str:
-    """Point jax's persistent compilation cache at ``cache_dir``.
+    """Turn on jax's persistent compilation cache; returns its directory.
+
+    ONE rule places the cache, and this function is the only code that
+    applies it:
+
+    - ``JAX_COMPILATION_CACHE_DIR`` set in the environment => jax has
+      already adopted that directory at import; it is the cache and this
+      function sets no other (``cache_dir`` and ``FLAGS_compile_cache_dir``
+      both lose to it);
+    - otherwise ``cache_dir``, else ``FLAGS_compile_cache_dir``, else
+      :data:`DEFAULT_CACHE_DIR` (``<checkout>/.jax_cache``).
 
     Subsequent processes that compile an identical program (same HLO,
     flags, backend) load the executable from disk instead of recompiling.
-    Returns the directory in use. Safe to call repeatedly.
+    Call it before the first compile of the process: jax decides once
+    whether the cache is in use. Safe to call repeatedly.
     """
     global _persistent_dir
     from . import flags
 
     import jax
 
-    cache_dir = (cache_dir or flags.flag("FLAGS_compile_cache_dir")
-                 or os.path.join(os.path.expanduser("~"), ".cache",
-                                 "paddle_tpu", "xla"))
-    cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
-    os.makedirs(cache_dir, exist_ok=True)
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        cache_dir = env_dir
+    else:
+        cache_dir = os.path.abspath(os.path.expanduser(
+            cache_dir or flags.flag("FLAGS_compile_cache_dir")
+            or DEFAULT_CACHE_DIR))
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     if min_compile_secs is None:
         min_compile_secs = flags.flag(
             "FLAGS_persistent_cache_min_compile_secs")
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    for opt, val in (
-            ("jax_persistent_cache_min_compile_time_secs",
-             float(min_compile_secs)),
-            ("jax_persistent_cache_min_entry_size_bytes", 0),
-    ):
-        try:
-            jax.config.update(opt, val)
-        except AttributeError:  # knob not present on this jax
-            pass
-    try:  # older jax needs the explicit initializer as well
-        from jax.experimental.compilation_cache import compilation_cache as cc
-
-        if hasattr(cc, "set_cache_dir"):
-            cc.set_cache_dir(cache_dir)
-    except Exception:
-        pass
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      float(min_compile_secs))
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     _persistent_dir = cache_dir
+    _listen_for_backend_compiles()
     return cache_dir
+
+
+# what jax itself reports about the persistent cache (jax.monitoring):
+# every executable it needs is one request; a request the cache answers
+# is a hit; the rest are real backend compiles
+_backend = {"requests": 0, "hits": 0, "seconds": 0.0, "listening": False}
+_COMPILE_DURATION_EVENTS = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration")
+
+
+def _listen_for_backend_compiles() -> None:
+    import jax
+
+    with _lock:
+        if _backend["listening"]:
+            return
+        _backend["listening"] = True
+
+    def on_event(event, **_):
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            with _lock:
+                _backend["requests"] += 1
+        elif event == "/jax/compilation_cache/cache_hits":
+            with _lock:
+                _backend["hits"] += 1
+
+    def on_duration(event, seconds, **_):
+        if event in _COMPILE_DURATION_EVENTS:
+            with _lock:
+                _backend["seconds"] += seconds
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+
+def backend_compile_stats() -> dict:
+    """Process totals since :func:`enable_persistent_cache`: executables
+    jax asked for (``requests``), how many the persistent cache answered
+    (``persistent_hits``), how many the backend really compiled
+    (``backend_compiles``) and the seconds spent tracing, lowering and
+    compiling or loading them (``compile_seconds``). Unlike
+    :func:`cache_stats` — which counts traces of instrumented programs —
+    this sees every executable, eager ops included; callers diff two
+    readings to attribute a phase."""
+    with _lock:
+        return {"requests": _backend["requests"],
+                "persistent_hits": _backend["hits"],
+                "backend_compiles": _backend["requests"] - _backend["hits"],
+                "compile_seconds": round(_backend["seconds"], 3)}
 
 
 def persistent_cache_dir() -> Optional[str]:

@@ -20,7 +20,7 @@ _DEFAULTS: Dict[str, Any] = {
     # persistent XLA compile cache (framework/compile_cache.py): warm
     # processes skip backend compilation for programs already on disk
     "FLAGS_persistent_compile_cache": False,
-    "FLAGS_compile_cache_dir": "",         # "" -> ~/.cache/paddle_tpu/xla
+    "FLAGS_compile_cache_dir": "",         # "" -> <checkout>/.jax_cache
     "FLAGS_persistent_cache_min_compile_secs": 0.0,
     # accepted-but-inert (XLA/jax own these concerns on TPU; XLA:TPU is
     # deterministic by default, verbosity goes through absl/glog env)

@@ -1,72 +1,9 @@
-"""Version compatibility shims over the jax API surface.
-
-The codebase targets the modern spelling (``jax.shard_map`` with the
-``check_vma`` kwarg); older jax releases (< 0.5) ship it as
-``jax.experimental.shard_map.shard_map`` with the kwarg named
-``check_rep``. Import :func:`shard_map` from here instead of from jax so
-every call site works on both.
-"""
+"""The single import point for the jax SPMD primitives the package uses,
+so a jax upgrade that moves one of them is a one-file change."""
 from __future__ import annotations
 
-try:  # modern jax: top-level export
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax < 0.5: experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg is detected from the SIGNATURE, not the
-# import location: mid-band releases export jax.shard_map while still
-# spelling the kwarg check_rep
-import inspect as _inspect
-
-_KWARG = ("check_vma" if "check_vma"
-          in _inspect.signature(_shard_map).parameters else "check_rep")
+from jax import make_array_from_process_local_data, shard_map
+from jax.lax import axis_size, pcast
 
 __all__ = ["shard_map", "axis_size", "pcast",
            "make_array_from_process_local_data"]
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-    if check_vma is not None:
-        kw[_KWARG] = check_vma
-    elif _KWARG == "check_rep":
-        # code written for the VMA era relies on pcast to reconcile varying
-        # types; the pre-VMA replication checker has no such escape hatch
-        # and false-positives on those patterns, so default it off
-        kw[_KWARG] = False
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-
-
-try:  # modern jax
-    from jax.lax import axis_size  # type: ignore[attr-defined]
-except ImportError:
-    def axis_size(axis_name):
-        """Size of a mapped mesh axis. On older jax, ``psum(1, axis)`` over
-        a unit constant folds to the static axis size (a plain int), so it
-        remains usable in shapes."""
-        from jax import lax
-        return lax.psum(1, axis_name)
-
-
-try:  # modern jax: VMA cast between varying/invariant manual types
-    from jax.lax import pcast  # type: ignore[attr-defined]
-except ImportError:
-    def pcast(t, axis_names=None, *, to=None):
-        """Pre-VMA jax has no varying/invariant distinction inside
-        shard_map — the cast is the identity."""
-        return t
-
-
-try:  # jax >= 0.4.26: per-host slice -> global sharded array, no
-    # replicated staging copy on the way to the GSPMD layout
-    from jax import (  # type: ignore[attr-defined]
-        make_array_from_process_local_data,
-    )
-except ImportError:
-    def make_array_from_process_local_data(sharding, local_data,
-                                           global_shape=None):
-        """Older jax: land the host batch through device_put — correct
-        (single-process: local IS global) but via a replicated copy."""
-        import jax
-
-        return jax.device_put(local_data, sharding)

@@ -34,20 +34,17 @@ def _transfer_leaf(x, sharding, device):
 
     arr = np.asarray(x)
     if sharding is not None:
+        from jax.sharding import NamedSharding, PartitionSpec
+
         from ..framework.jax_compat import make_array_from_process_local_data
 
-        try:
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            if (isinstance(sharding, NamedSharding)
-                    and arr.ndim < len(sharding.spec)):
-                # lower-rank rider (e.g. the [B] validity mask next to
-                # [B, S] data): clip the spec to the leaf's rank instead
-                # of crashing on the rank mismatch
-                sharding = NamedSharding(
-                    sharding.mesh, PartitionSpec(*sharding.spec[:arr.ndim]))
-        except ImportError:
-            pass
+        if (isinstance(sharding, NamedSharding)
+                and arr.ndim < len(sharding.spec)):
+            # lower-rank rider (e.g. the [B] validity mask next to
+            # [B, S] data): clip the spec to the leaf's rank instead
+            # of crashing on the rank mismatch
+            sharding = NamedSharding(
+                sharding.mesh, PartitionSpec(*sharding.spec[:arr.ndim]))
         return make_array_from_process_local_data(sharding, arr)
     if device is not None:
         return jax.device_put(arr, device)

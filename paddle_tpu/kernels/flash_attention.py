@@ -27,17 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pallas TPU backend only exists on TPU-enabled jaxlibs
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
-# older jax spells it TPUCompilerParams; a LOCAL alias (never mutate the
-# foreign pltpu namespace — other libraries version-sniff it)
-_CompilerParams = ((getattr(pltpu, "CompilerParams", None)
-                    or getattr(pltpu, "TPUCompilerParams", None))
-                   if pltpu is not None else None)
 
 def _operand_dtype(*refs):
     """Dot-operand dtype policy, decided over ALL of a kernel body's
@@ -83,10 +74,9 @@ def should_use_flash(q, k, attn_mask, dropout_p) -> bool:
     if jax.default_backend() != "tpu":
         return False
     Lq, Lk = q.shape[1], k.shape[1]
-    # below ~2k tokens XLA's fused-softmax attention outperforms the
-    # blockwise kernel on the MXU (measured on v5e: 0.44 vs 0.30 step MFU at
-    # L=1024, D=64) and the O(L^2) scores still fit — the Pallas path is the
-    # long-context/memory play, not a universal win
+    # below ~2k tokens the O(L^2) scores still fit and attention stays on
+    # XLA's fused-softmax path; which side of this line is faster is not
+    # measured on today's code (ROADMAP S3/D8)
     if Lq < 2048 or Lq % 128 != 0 or Lk % 128 != 0:
         return False
     if attn_mask is not None:
@@ -398,7 +388,7 @@ def _flash_fwd_impl(q, k, v, bias, seed, causal, dropout_p,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
     )(*args)
@@ -470,7 +460,7 @@ def _flash_bwd_impl(q, k, v, bias, seed, o, lse, do, causal, dropout_p,
         out_specs=dq_out_specs,
         out_shape=dq_out_shape,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
     )(*seed_args, q, k, v, *bias_args, do, lse, delta)
@@ -526,7 +516,7 @@ def _flash_bwd_impl(q, k, v, bias, seed, o, lse, do, causal, dropout_p,
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
     )(*seed_args, q, k, v, *bias_args, do, lse, delta)

@@ -411,6 +411,12 @@ def warm_boot_env(cache_dir: str) -> Dict[str, str]:
     pays the compile; every later replica — and every later boot —
     deserializes it and boots warm (pair with
     ``ContinuousBatchingEngine.warmup()`` in the child before it calls
-    ``host_server``)."""
+    ``host_server``).
+
+    ``JAX_COMPILATION_CACHE_DIR`` in the child's environment outranks
+    these flags (``compile_cache.enable_persistent_cache``): a caller
+    that needs THIS directory — the cold/warm boot drill in
+    ``tools/serve_bench.py --disagg``, whose "cold" boot must find an
+    empty cache — drops that variable from the child's environment."""
     return {"FLAGS_persistent_compile_cache": "1",
             "FLAGS_compile_cache_dir": str(cache_dir)}

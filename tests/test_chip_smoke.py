@@ -1,0 +1,118 @@
+"""chip_smoke.py: no CPU path in its entry point, its phases rehearsed
+tiny on the CPU mesh, and the one rule that places the compile cache."""
+import os
+import subprocess
+import sys
+
+import jax
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu.framework import compile_cache
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+
+def test_entry_point_refuses_a_machine_without_a_tpu():
+    """Under the sandbox's own environment (JAX_PLATFORMS=cpu exported)
+    the script must die on JAX's missing-TPU error, not run on the CPU."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                          env=env, capture_output=True, text=True, timeout=120)
+    assert proc.returncode != 0
+    assert "Unable to initialize backend 'tpu'" in proc.stderr
+    assert '"ok"' not in proc.stdout
+
+
+def _tiny(seq, loss_chunk=16):
+    return chip_smoke.gpt_config(1, seq, vocab_size=256, hidden_size=64,
+                                 num_heads=1, loss_chunk=loss_chunk)
+
+
+def test_train_phase_tiny():
+    out = chip_smoke.train_phase(_tiny(32), batch=2, seq=32, steps=3)
+    assert out["last_loss"] < out["first_loss"]
+
+
+def test_flash_phase_tiny(interpret_pallas, monkeypatch):
+    from paddle_tpu.kernels import flash_attention as fa
+
+    # the gate refuses the CPU backend and short sequences; the kernels
+    # themselves run interpreted at any 128-multiple length
+    monkeypatch.setattr(fa, "should_use_flash", lambda q, k, m, p: True)
+    chip_smoke.flash_phase(
+        _tiny(128), batch=1, seq=128, steps=1,
+        kernel_shape=(2, 1, 128, 64), dropout_p=0.0,  # TPU PRNG only
+        min_mosaic_calls=0)
+    # three kernels for the stand-alone check and — at another shape, so
+    # nothing is reused from a trace cache — three more for the model's
+    # step: it took the Pallas path, not the XLA one
+    assert sorted(interpret_pallas) == sorted(
+        2 * ["_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel"])
+
+
+def test_flash_phase_notices_the_xla_fallback(interpret_pallas):
+    """On the CPU the gate says no and the model's step holds no Mosaic
+    call: the phase must fail, not pass on the fallback path."""
+    with pytest.raises(chip_smoke.CheckFailed, match="XLA path"):
+        chip_smoke.flash_phase(
+            _tiny(128), batch=1, seq=128, steps=1,
+            kernel_shape=(1, 1, 128, 64), kernel_dtype="float32",
+            dropout_p=0.0, min_mosaic_calls=3)
+
+
+def test_serve_phase_tiny():
+    out = chip_smoke.serve_phase(
+        _tiny(64, loss_chunk=0), slots=4, prompt_lens=(10, 40),
+        n_requests=8, new_tokens=(4, 8), expect_donation=False)
+    assert out["programs"] == 3           # buckets 32, 64 + decode
+
+
+@pytest.fixture()
+def restore_cache_config():
+    old = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    yield
+    for k, v in old.items():
+        jax.config.update(k, v)
+
+
+def test_cache_dir_env_wins(monkeypatch, tmp_path, restore_cache_config):
+    env_dir = str(tmp_path / "from_env")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    # jax adopts the variable at import; stand in for that here
+    jax.config.update("jax_compilation_cache_dir", env_dir)
+    pt.set_flags({"FLAGS_compile_cache_dir": str(tmp_path / "from_flag")})
+    try:
+        got = compile_cache.enable_persistent_cache(
+            str(tmp_path / "from_arg"))
+    finally:
+        pt.set_flags({"FLAGS_compile_cache_dir": ""})
+    assert got == env_dir == jax.config.jax_compilation_cache_dir
+    assert not (tmp_path / "from_arg").exists()
+    assert not (tmp_path / "from_flag").exists()
+
+
+def test_cache_dir_default_is_fixed_in_checkout(monkeypatch, tmp_path,
+                                                restore_cache_config):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    # the default must not land test artifacts in the real checkout
+    monkeypatch.setattr(compile_cache, "DEFAULT_CACHE_DIR",
+                        str(tmp_path / ".jax_cache"))
+    first = compile_cache.enable_persistent_cache()
+    second = compile_cache.enable_persistent_cache()
+    assert first == second == str(tmp_path / ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == first
+    explicit = compile_cache.enable_persistent_cache(str(tmp_path / "mine"))
+    assert explicit == str(tmp_path / "mine")
+
+
+def test_default_cache_dir_sits_in_the_checkout_and_is_ignored():
+    assert compile_cache.DEFAULT_CACHE_DIR == os.path.join(REPO, ".jax_cache")
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

@@ -442,9 +442,12 @@ class TestCompileCache:
         assert g["compiles"] >= 1
         assert step._cc_name in g["functions"]
 
-    def test_persistent_cache_wiring(self, tmp_path):
+    def test_persistent_cache_wiring(self, tmp_path, monkeypatch):
         import jax
 
+        # an explicit directory is honoured only when the environment
+        # names none (tests/test_chip_smoke.py covers the other direction)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         old = jax.config.jax_compilation_cache_dir
         try:
             d = compile_cache.enable_persistent_cache(

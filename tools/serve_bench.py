@@ -1058,6 +1058,9 @@ def _fairness_main(args) -> int:
 # compile; the deferred warm-boot replica — released mid-window by a
 # wait-file touch, the scale-out moment — deserializes them and must
 # boot in a fraction of the cold window (PR 16 measured ~7.4s cold).
+# The drill measures a cold boot on purpose, on the CPU: its cache
+# directories are fresh temporaries, and JAX_COMPILATION_CACHE_DIR
+# (which would outrank them) is removed from the children's environment.
 #
 # Gates: migrated-prefill streams token-identical to a solo generate
 # (greedy + seeded), zero lost requests (fallback-to-local-recompute
@@ -1184,9 +1187,13 @@ def _disagg_main(args) -> int:
     def child_env(cache_dir):
         # children serve on host CPU (a real fleet maps each to its own
         # accelerator); the warm_boot_env flags point their persistent
-        # compile cache at the shared per-role directory
-        return dict(os.environ, PYTHONPATH=repo, JAX_PLATFORMS="cpu",
-                    **warm_boot_env(cache_dir))
+        # compile cache at the shared per-role directory.
+        # JAX_COMPILATION_CACHE_DIR outranks those flags, so it is
+        # dropped: an ambient cache would make the "cold" boot warm
+        env = dict(os.environ, PYTHONPATH=repo, JAX_PLATFORMS="cpu",
+                   **warm_boot_env(cache_dir))
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        return env
 
     plan = []      # (role, rpc name, rank, cache dir, deferred)
     rank = 1
