@@ -427,6 +427,19 @@ def four_chip_phase(cfg, batch: int, seq: int, ref_first_loss: float,
 
 
 # ------------------------------------------------------------------ main
+def device_report(devices) -> dict:
+    """The device as JAX reports it."""
+    return {"platform": str(devices[0].platform),
+            "kind": str(devices[0].device_kind), "count": len(devices)}
+
+
+def result_line(devices) -> str:
+    """The last line of stdout on success. Its reader accepts exactly the
+    keys "ok" and "device" {"platform", "kind", "count"} and nothing
+    else; per-phase outcomes go out as log lines before it."""
+    return json.dumps({"ok": True, "device": device_report(devices)})
+
+
 def _release_device_memory() -> None:
     """Free the last phase's HBM: the step classes hold reference cycles
     (a jit of a bound method) and compiled programs pin their buffers."""
@@ -477,8 +490,7 @@ def main(argv=None) -> int:
 
     jax.config.update("jax_platforms", "tpu")
     devices = jax.devices()
-    device = {"platform": devices[0].platform,
-              "kind": devices[0].device_kind, "count": len(devices)}
+    device = device_report(devices)
     if device["platform"] != "tpu":
         raise RuntimeError(f"chip_smoke needs a TPU, jax gave {device}")
     import importlib.metadata
@@ -527,9 +539,9 @@ def main(argv=None) -> int:
                 ref_first_loss=train["first_loss"]))
             done["four_chip"] = "passed"
 
+    log(f"phases: {json.dumps(done)}")
     log(f"all selected phases passed in {time.perf_counter() - t_start:.1f} s")
-    print(json.dumps({"ok": True, "device": device, "phases": done}),
-          flush=True)
+    print(result_line(devices), flush=True)      # nothing after this line
     return 0
 
 

@@ -27,6 +27,22 @@ def test_entry_point_refuses_a_machine_without_a_tpu():
     assert '"ok"' not in proc.stdout
 
 
+def test_result_line_has_exactly_the_contract_keys():
+    """The driver refuses a last line with any key beyond "ok" and
+    "device" {"platform", "kind", "count"}."""
+    import json
+
+    line = chip_smoke.result_line(jax.devices())
+    assert "\n" not in line
+    got = json.loads(line)
+    assert list(got) == ["ok", "device"] and got["ok"] is True
+    assert list(got["device"]) == ["platform", "kind", "count"]
+    assert got["device"] == {"platform": jax.devices()[0].platform,
+                             "kind": jax.devices()[0].device_kind,
+                             "count": len(jax.devices())}
+    assert type(got["device"]["count"]) is int
+
+
 def _tiny(seq, loss_chunk=16):
     return chip_smoke.gpt_config(1, seq, vocab_size=256, hidden_size=64,
                                  num_heads=1, loss_chunk=loss_chunk)
