@@ -372,8 +372,9 @@ class TrainStep(StepSeams):
             from ..amp.grad_scaler import unscale_and_check
 
             grads, found = unscale_and_check(grads, scaler_state)
-            new_params, new_opt_state = self.optimizer.update(
-                grads, opt_state, params)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt_state = self.optimizer.update(
+                    grads, opt_state, params)
             (new_params, new_buffers, new_opt_state), new_scaler_state, \
                 ok, found_inf = scaler_guard(
                     loss, found, scaler_state,
@@ -381,7 +382,9 @@ class TrainStep(StepSeams):
                     (params, buffers, opt_state))
             return (loss, *extras, new_params, new_buffers, new_opt_state,
                     accum, new_scaler_state, ok, found_inf)
-        new_params, new_opt_state = self.optimizer.update(grads, opt_state, params)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt_state = self.optimizer.update(
+                grads, opt_state, params)
         if with_check:
             ok, (new_params, new_buffers, new_opt_state) = finite_guard(
                 grads, (new_params, new_buffers, new_opt_state),

@@ -257,7 +257,10 @@ class HangWatchdog:
     def stop(self) -> None:
         self._stop.set()
         if self._thread is not None:
-            self._thread.join(timeout=self.step_timeout)
+            # the watcher leaves within one poll unless it is writing a
+            # flight dump: wait that out (seconds on a loaded machine,
+            # whatever step_timeout is), but not a stuck disk
+            self._thread.join(timeout=max(self.step_timeout, 5.0))
 
     def beat(self) -> None:
         """A step completed (or the loop is alive at a boundary)."""
@@ -280,7 +283,6 @@ class HangWatchdog:
             if elapsed <= self.step_timeout:
                 continue
             self._fired = True
-            self.hangs_detected += 1
             profiler.bump_counter("train.hang")
             # flight-record the incident BEFORE any exit path: a hard
             # os._exit leaves nothing else behind. The watcher thread has
@@ -289,6 +291,9 @@ class HangWatchdog:
             _flight.dump("hang", extra={"elapsed_s": round(elapsed, 3),
                                         "step_timeout_s": self.step_timeout,
                                         "action": self.action})
+            # counted once the dump is on disk: whoever polls this
+            # counter may read the recorder's last_dump_path next
+            self.hangs_detected += 1
             msg = (f"train step exceeded step_timeout={self.step_timeout}s "
                    f"(no heartbeat for {elapsed:.1f}s) — stuck H2D or hung "
                    f"collective?")

@@ -265,6 +265,7 @@ def filter_logits(logits, temperature=1.0, top_k: int = 0, top_p=1.0,
     return l
 
 
+@jax.named_scope("sample")
 def sample_logits(logits, key=None, temperature=1.0, top_k: int = 0,
                   top_p=1.0, greedy: bool = False,
                   use_top_p: Optional[bool] = None):
@@ -296,6 +297,7 @@ def per_row_keys(key, batch: int, position=None):
         k, jnp.arange(batch, dtype=jnp.uint32))
 
 
+@jax.named_scope("sample")
 def sample_logits_rows(logits, row_keys, temperature=1.0, top_k: int = 0,
                        top_p=1.0, *, use_top_p: bool = False,
                        greedy_mask=None):
@@ -371,9 +373,10 @@ class GenerationEngine:
     def _prefill_fn(self, params, buffers, cache, ids, last_index, key,
                     eos_id, temperature, top_p, *, top_k, greedy,
                     use_top_p):
-        (logits, cache), _ = functional_call(
-            self.model, params, buffers, ids, cache=cache,
-            position_offset=0, gather_last=last_index)
+        with jax.named_scope("prefill"):
+            (logits, cache), _ = functional_call(
+                self.model, params, buffers, ids, cache=cache,
+                position_offset=0, gather_last=last_index)
         cache = _constrain_cache(cache, ids.shape[0],
                                  self.spec["num_kv_heads"])
         logits = logits[:, 0, :]
@@ -391,9 +394,10 @@ class GenerationEngine:
     def _decode_fn(self, params, buffers, cache, token, pos, key, done,
                    eos_id, temperature, top_p, *, top_k, greedy,
                    use_top_p):
-        (logits, cache), _ = functional_call(
-            self.model, params, buffers, token, cache=cache,
-            position_offset=pos)
+        with jax.named_scope("decode"):
+            (logits, cache), _ = functional_call(
+                self.model, params, buffers, token, cache=cache,
+                position_offset=pos)
         cache = _constrain_cache(cache, token.shape[0],
                                  self.spec["num_kv_heads"])
         logits = logits[:, -1, :]

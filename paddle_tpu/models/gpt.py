@@ -109,6 +109,7 @@ class GPTAttention(Layer):
             weight_attr=Normal(0.0, cfg.initializer_range / math.sqrt(2 * cfg.num_layers)),
             has_bias=True, input_is_parallel=True)
 
+    @jax.named_scope("attention")
     def forward(self, x, cache=None, position_offset=0):
         B, L, _ = x.shape
         qkv = self.qkv_proj(x)  # [B, L, 3*H*D] (mp-sharded feature dim)
@@ -139,6 +140,7 @@ class GPTMLP(Layer):
             weight_attr=Normal(0.0, cfg.initializer_range / math.sqrt(2 * cfg.num_layers)),
             has_bias=True, input_is_parallel=True)
 
+    @jax.named_scope("mlp")
     def forward(self, x):
         return self.fc_out(F.gelu(self.fc_in(x), approximate=True))
 
@@ -243,6 +245,7 @@ class GPTForCausalLM(Layer):
             return self.gpt.embeddings.word_embeddings.weight
         return None
 
+    @jax.named_scope("lm_head")
     def _logits(self, h):
         if self.cfg.tie_word_embeddings:
             return parallel_matmul(h, self._head_weight(), transpose_y=True)
@@ -295,6 +298,7 @@ class GPTForCausalLM(Layer):
 
         return generate(self, input_ids, max_new_tokens, **kwargs)
 
+    @jax.named_scope("loss_head")
     def loss(self, logits, labels):
         """Shifted LM loss: predict token t+1 from prefix ..t."""
         shift_logits = logits[:, :-1, :]

@@ -226,6 +226,7 @@ class DecoderBlockList(Layer):
         return x, tuple(new_caches)
 
 
+@jax.named_scope("loss_head")
 def chunked_lm_loss(h, labels, logits_fn, ce, chunk: int = 256):
     """Shifted next-token loss over ``h`` [B, L, H] without full logits.
 

@@ -13,16 +13,25 @@ where every correlation id is its own named lane.
 Hot-path discipline: recording a span is two ``time.time()`` reads and
 a deque append under a small lock — no device sync, no allocation
 beyond the tuple. Every record site sits on the host side of an
-EXISTING dispatch point (the server's per-token fan-out loop, the
+EXISTING dispatch point (the serve loop's phase boundaries, the
 engine's admission read-back, the generate() loop), so tracing adds
 zero host↔device round-trips (tpu_lint R1 clean) and zero compiled
 programs. ``PT_TRACE=0`` disables recording entirely; the buffer is
 bounded (``PT_TRACE_BUFFER``, default 65536 spans) and counts what it
 drops.
 
+A span that is recorded while it runs (:func:`span`, or :func:`begin`
+/ :func:`end`) also enters a ``jax.profiler.TraceAnnotation`` of the
+same name, so an ordinary ``jax.profiler`` capture shows it on the host
+plane beside the device; :func:`record_span` takes bounds that are
+already past and can only write the ring.
+
 Timestamps are wall-clock (``time.time()``) on purpose: spans from
 different processes (fleet replicas) must merge onto one timeline in
-``tools/trace_view.py``.
+``tools/trace_view.py``, and a profiler trace stamps its own start on
+the same clock (``profile_start_time`` on the ``Task Environment``
+plane, nanoseconds since the epoch), so ``trace_view --xplane`` lays
+the spans over the device's events with no further calibration.
 """
 from __future__ import annotations
 
@@ -37,8 +46,8 @@ from typing import Dict, List, Optional
 
 __all__ = [
     "enabled", "enable", "new_correlation_id", "current", "set_current",
-    "correlate", "record_span", "record_event", "span", "spans", "clear",
-    "stats", "chrome_trace", "export_chrome_trace",
+    "correlate", "record_span", "record_event", "begin", "end", "span",
+    "spans", "clear", "stats", "chrome_trace", "export_chrome_trace",
 ]
 
 
@@ -72,6 +81,7 @@ _INHERIT = object()
 # distinguishes processes that share a pid namespace epoch (fork-heavy
 # launchers recycle pids fast enough to collide within one trace dir)
 _proc_token = os.urandom(3).hex()
+_TraceAnnotation = None     # jax.profiler's, imported by the first begin()
 
 
 def enabled() -> bool:
@@ -138,18 +148,42 @@ def record_event(name: str, corr=_INHERIT, **tags) -> None:
     record_span(name, t, t, corr=corr, tags=tags or None)
 
 
+def begin(name: str, t0: Optional[float] = None):
+    """Open a span now, or at the wall-clock time ``t0`` the caller has
+    just read: enters a ``jax.profiler.TraceAnnotation`` named ``name``
+    and returns what :func:`end` needs — ``None`` when recording is off,
+    which :func:`end` takes too."""
+    if not _buf.enabled:
+        return None
+    global _TraceAnnotation
+    if _TraceAnnotation is None:   # jax only once a span is live
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    ann = _TraceAnnotation(name)
+    ann.__enter__()
+    return name, time.time() if t0 is None else t0, ann
+
+
+def end(opened, t1: Optional[float] = None, corr=_INHERIT,
+        tags: Optional[dict] = None) -> None:
+    """Close what :func:`begin` opened, now or at ``t1``, and record the
+    span; ``corr`` follows :func:`record_span` semantics."""
+    if opened is None:
+        return
+    name, t0, ann = opened
+    ann.__exit__(None, None, None)
+    record_span(name, t0, time.time() if t1 is None else t1, corr=corr,
+                tags=tags)
+
+
 @contextmanager
 def span(name: str, corr=_INHERIT, **tags):
     """Context manager recording the wrapped block as one span;
     ``corr`` follows :func:`record_span` semantics."""
-    if not _buf.enabled:
-        yield
-        return
-    t0 = time.time()
+    opened = begin(name)
     try:
         yield
     finally:
-        record_span(name, t0, time.time(), corr=corr, tags=tags or None)
+        end(opened, corr=corr, tags=tags or None)
 
 
 def spans(corr: Optional[str] = None,

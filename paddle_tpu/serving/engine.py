@@ -34,7 +34,6 @@ the batch.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
@@ -43,7 +42,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..framework import compile_cache
-from ..observability import tracing as _tracing
 from ..framework.dtype import convert_dtype
 from ..io.batching import bucket_for
 from ..models.generation import (DEFAULT_PREFILL_BUCKETS, _constrain_cache,
@@ -54,6 +52,7 @@ from ..models.generation import (DEFAULT_PREFILL_BUCKETS, _constrain_cache,
 from ..lora import adapter_rows as _adapter_rows_ctx
 from ..lora.store import AdapterStore, normalize_adapter_id
 from ..nn.layer import buffer_state, functional_call, param_state
+from .metrics import LoopClock
 from .prefix_cache import BlockPool
 
 __all__ = ["ContinuousBatchingEngine", "SlotEvent"]
@@ -122,6 +121,10 @@ class ContinuousBatchingEngine:
         self.allow_top_p = bool(allow_top_p)
         self.pool = self._normalize_pool(prefix_cache)
         self.store = self._normalize_store(adapter_store)
+        #: whose time admit() and step() divide at their device waits.
+        #: The engine's own books nothing; an InferenceServer's loop
+        #: thread puts its clock here (``ServingMetrics`` counters).
+        self.clock = LoopClock()
         model_name = type(model).__name__
         self._cc_prefill = compile_cache.register_name(
             f"serve:prefill:{model_name}")
@@ -311,9 +314,10 @@ class ContinuousBatchingEngine:
         costs one compile per bucket — not per bucket per slot, and no
         separate scatter program."""
         slot_cache = self._slot_zero_cache()
-        (logits, slot_cache), _ = functional_call(
-            self.model, params, buffers, ids, cache=slot_cache,
-            position_offset=0, gather_last=last_index)
+        with jax.named_scope("prefill"):
+            (logits, slot_cache), _ = functional_call(
+                self.model, params, buffers, ids, cache=slot_cache,
+                position_offset=0, gather_last=last_index)
         logits = logits[:, 0, :]
         rows = per_row_keys(key, 1)
         next_tok = sample_logits_rows(
@@ -344,9 +348,10 @@ class ContinuousBatchingEngine:
         a traced offset), which is what makes the prefix K/V reusable
         without re-running its FLOPs."""
         slot_cache = gather_cache_blocks(pool, read_idx, self.max_length)
-        (logits, slot_cache), _ = functional_call(
-            self.model, params, buffers, ids, cache=slot_cache,
-            position_offset=n_matched, gather_last=last_index)
+        with jax.named_scope("prefill"):
+            (logits, slot_cache), _ = functional_call(
+                self.model, params, buffers, ids, cache=slot_cache,
+                position_offset=n_matched, gather_last=last_index)
         logits = logits[:, 0, :]
         rows = per_row_keys(key, 1)
         next_tok = sample_logits_rows(
@@ -382,9 +387,10 @@ class ContinuousBatchingEngine:
 
     def _decode_fn(self, params, buffers, live_cache, tokens, positions,
                    keys, done, eos, temperature, top_p, greedy_mask):
-        (logits, live_cache), _ = functional_call(
-            self.model, params, buffers, tokens, cache=live_cache,
-            position_offset=positions)
+        with jax.named_scope("decode"):
+            (logits, live_cache), _ = functional_call(
+                self.model, params, buffers, tokens, cache=live_cache,
+                position_offset=positions)
         live_cache = _constrain_cache(live_cache, self.slots,
                                       self.spec["num_kv_heads"])
         logits = logits[:, -1, :]
@@ -514,9 +520,12 @@ class ContinuousBatchingEngine:
         the first token), and how many prompt tokens were served from
         the prefix cache (0 without a pool). The live batch keeps
         decoding other slots' requests before/after this call — only
-        this call itself runs the prefill program."""
-        from ..profiler import RecordEvent
+        this call itself runs the prefill program.
 
+        Two boundaries of ``self.clock`` lie inside: the prefill's
+        dispatch has returned (``admit_wait`` begins: the first token's
+        read-back) and the token is on the host (``admit_host`` again,
+        the admission's tail, inside the caller's span)."""
         if self.requests[slot] is not None:
             raise RuntimeError(f"slot {slot} is occupied")
         prompt = np.asarray(request.prompt, np.int32).ravel()
@@ -547,12 +556,10 @@ class ContinuousBatchingEngine:
             # namespace.
             a_row, a_salt = self.store.acquire(adapter_id, with_salt=True)
         hit_tokens = 0
-        bucket = 0
-        t_span = time.time()
         try:
             lora_args = () if self.store is None else (
                 self.store.tensors, np.asarray([a_row], np.int32))
-            with RecordEvent("serve:prefill"), self._eval_mode():
+            with self._eval_mode():
                 compile_cache.record_call(self._cc_prefill)
                 if self.pool is None:
                     bucket = self.bucket_for_prompt(L)
@@ -604,23 +611,16 @@ class ContinuousBatchingEngine:
                 # the request never reached a slot: its page pin is void
                 self.store.release(a_row)
             raise
+        clock = self.clock
+        clock.enter("admit_wait", "serve.prefill.wait",
+                    getattr(request, "corr_id", None))
         # ONE batched transfer for both scalars — two np.asarray reads
         # here cost two serialized device round-trips per admission.
         # tpu-lint: disable=R1(admission's single batched sync point — the first token must reach the client now)
         first_h, fin_h = jax.device_get((tok, done0))
+        clock.enter("admit_host")
         first = int(first_h)
         fin = bool(fin_h)
-        # host-side of the admission's existing sync point: the prefill
-        # span (bucket + prefix-hit + adapter tags) lands in the request's
-        # trace lane with zero extra device round-trips
-        tags = {"bucket": int(bucket), "prompt_len": L, "slot": int(slot)}
-        if self.pool is not None:
-            tags["prefix_hit_tokens"] = int(hit_tokens)
-        if adapter_id is not None:
-            tags["adapter"] = adapter_id
-        _tracing.record_span("prefill", t_span, time.time(),
-                             corr=getattr(request, "corr_id", None),
-                             tags=tags)
         self.requests[slot] = request
         self._adapter_slots[slot] = a_row
         self._positions[slot] = L
@@ -638,13 +638,16 @@ class ContinuousBatchingEngine:
         event per occupied, not-yet-done slot (its new token and done
         flag); free slots decode as masked filler. The per-step host read
         of ``[B]`` tokens is what streams results out — continuous
-        batching's equivalent of the generate() loop's done-check."""
-        from ..profiler import RecordEvent
+        batching's equivalent of the generate() loop's done-check.
 
+        Two boundaries of ``self.clock`` lie inside: the decode
+        program's dispatch has returned (``decode_wait`` begins) and the
+        tokens are on the host (``emit`` begins); their spans carry the
+        tags of the ``decode_dispatch`` span the caller opened."""
+        clock = self.clock
         lora_args = () if self.store is None else (
             self.store.tensors, self._adapter_slots)
-        t_span = time.time()
-        with RecordEvent("serve:decode"), self._eval_mode():
+        with self._eval_mode():
             compile_cache.record_call(self._cc_decode)
             tok, done, self.live_cache = self._decode_compiled(
                 self._params, self._buffers, self.live_cache, *lora_args,
@@ -654,14 +657,12 @@ class ContinuousBatchingEngine:
         # one batched transfer for the whole [B] step readback (token +
         # done vectors) instead of two serialized np.array round-trips;
         # np.array then makes writable copies: admit() scribbles slots
+        clock.enter("decode_wait", "serve.decode.wait", tags=clock.tags)
         # tpu-lint: disable=R1(the per-step [B]-token readback IS the streaming output; one batched transfer per decode step)
         tok_h, done_h = jax.device_get((tok, done))
+        clock.enter("emit", "serve.emit", tags=clock.tags)
         toks = np.array(tok_h)
         dns = np.array(done_h)
-        # batch-level decode-step span (uncorrelated lane): the compute
-        # timeline behind every live request's per-token spans
-        _tracing.record_span("decode_step", t_span, time.time(), corr=None,
-                             tags={"active": int(self.active_count)})
         events: List[SlotEvent] = []
         for i, req in enumerate(self.requests):
             if req is None:

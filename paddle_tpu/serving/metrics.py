@@ -12,10 +12,12 @@ The four signals a serving operator actually pages on:
 Histograms use reservoir sampling (bounded memory under unbounded
 traffic) with exact counts/sums; ``snapshot()`` returns one plain dict —
 the shape ``tools/serve_bench.py`` emits as JSON. Device-free and
-import-light on purpose: the profiler's ``RecordEvent`` spans
-(``serve:admit`` / ``serve:prefill`` / ``serve:decode``) carry the
-per-phase timing into trace tooling; this module carries the fleet-level
-numbers.
+import-light on purpose.
+
+The serve loop's own time is here too: :class:`LoopClock` divides every
+pass of the loop thread into the phases of :data:`LOOP_PHASES`, and at
+each boundary one read of the clocks feeds both the always-on counters
+(``snapshot()["loop"]``) and the phase's span in the tracing ring.
 """
 from __future__ import annotations
 
@@ -26,8 +28,18 @@ import time
 from typing import Dict, List, Optional
 
 from ..observability import registry as _obs_registry
+from ..observability import tracing as _tracing
 
-__all__ = ["LatencyHistogram", "ServingMetrics"]
+__all__ = ["LOOP_PHASES", "LatencyHistogram", "LoopClock", "ServingMetrics"]
+
+#: What the serve loop's thread can be doing; disjoint, and together its
+#: whole wall time. ``admit_wait`` and ``decode_wait`` wait for the
+#: device (the read-back of an admission's first token, of a step's
+#: tokens); ``idle`` waits for work; the other four are host work: wall
+#: less CPU in one of those is time the thread was runnable or blocked
+#: but not running (the interpreter lock, another lock, the machine).
+LOOP_PHASES = ("idle", "schedule", "admit_host", "admit_wait",
+               "decode_dispatch", "decode_wait", "emit")
 
 _metrics_serial = itertools.count()
 
@@ -85,6 +97,50 @@ class LatencyHistogram:
             out.max = max(out.max, h.max)
             out._samples.extend(h._samples)
         return out
+
+
+class LoopClock:
+    """Where the serve loop's thread is, and since when.
+
+    The thread is always in exactly one phase. :meth:`enter` is a
+    boundary: it reads the wall clock and the thread's CPU clock once,
+    books the time since the last boundary to the phase that ends
+    (``book(phase, wall_ns, cpu_ns)`` — ``ServingMetrics.loop_phase``)
+    and closes that phase's span with the same reading; so the phases
+    cannot overlap and leave nothing out. The wall clock is
+    ``time.time_ns()``: what the span ring and a device trace's
+    ``profile_start_time`` are stamped with. One thread owns a clock."""
+
+    def __init__(self, book=None):
+        self._book = book
+        self.phase = "idle"
+        self.t = time.time_ns()          # the last boundary, wall ns
+        self._cpu = time.thread_time_ns()
+        self._open = None
+        self.corr = None                 # of the span that is open
+        self.tags = None
+
+    def enter(self, phase: str, name: Optional[str] = None, corr=None,
+              tags: Optional[dict] = None) -> None:
+        """The thread leaves its phase for ``phase``; ``name`` opens the
+        new phase's span (:meth:`span`)."""
+        t, cpu = time.time_ns(), time.thread_time_ns()
+        if self._book is not None:
+            self._book(self.phase, t - self.t, cpu - self._cpu)
+        if self._open is not None:
+            _tracing.end(self._open, t * 1e-9, corr=self.corr,
+                         tags=self.tags)
+            self._open = None
+        self.phase, self.t, self._cpu = phase, t, cpu
+        if name is not None:
+            self.span(name, corr, tags)
+
+    def span(self, name: str, corr=None, tags: Optional[dict] = None):
+        """Open the running phase's span at the boundary just read (the
+        next boundary closes it). Apart from :meth:`enter` so that a
+        caller can open a parent span around it at the same instant."""
+        self.corr, self.tags = corr, tags
+        self._open = _tracing.begin(name, self.t * 1e-9)
 
 
 class ServingMetrics:
@@ -174,8 +230,21 @@ class ServingMetrics:
             # only when the engine serves through an AdapterStore; the
             # base model's share books under "base"
             self._per_adapter: Dict[str, dict] = {}
+            # [count, wall ns, CPU ns] a phase. One writer, the serve
+            # loop, and no lock on its side: a reset swaps the table, so
+            # a booking that races it lands in the old one
+            self._loop = {p: [0, 0, 0] for p in LOOP_PHASES}
 
     # ------------------------------------------------------------ events
+    def loop_phase(self, phase: str, wall_ns: int, cpu_ns: int) -> None:
+        """Book one ended instance of a serve-loop phase (the loop
+        thread's :class:`LoopClock` calls this at every boundary). A
+        clock stepped backwards books nothing rather than less."""
+        c = self._loop[phase]
+        c[0] += 1
+        c[1] += max(wall_ns, 0)
+        c[2] += cpu_ns
+
     def _advance_occupancy(self, now: float) -> None:
         self._occ_integral += self.active_slots * (now - self._occ_last_t)
         self._occ_last_t = now
@@ -280,6 +349,12 @@ class ServingMetrics:
                 "ttft": self.ttft.summary(),
                 "inter_token": self.inter_token.summary(),
                 "queue_wait": self.queue_wait.summary(),
+                # a phase is booked when it ends: the one the loop is in
+                # now is not in here yet (idle ends at every wake-up of
+                # its wait, at most 0.1 s)
+                "loop": {p: {"count": c[0], "wall_s": c[1] * 1e-9,
+                             "cpu_s": c[2] * 1e-9}
+                         for p, c in self._loop.items()},
                 **({"compile_stats": compile_stats}
                    if compile_stats is not None else {}),
                 **({"prefix_cache": prefix_cache}

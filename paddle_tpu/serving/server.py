@@ -10,6 +10,15 @@ fan its tokens out to the request handles. Finished slots free
 immediately — a new request admits into the hole while everyone else
 keeps decoding.
 
+Where the loop thread's time goes is measured where it happens: its
+``LoopClock`` divides every pass into the phases of
+``metrics.LOOP_PHASES`` (``idle``, ``schedule``, ``admit_host``,
+``admit_wait``, ``decode_dispatch``, ``decode_wait``, ``emit``), counted
+always (``snapshot()["loop"]``) and, while the span ring is on, recorded
+as ``serve.schedule`` / ``serve.admit`` (request lane; children
+``serve.prefill.dispatch`` and ``serve.prefill.wait``) /
+``serve.decode.dispatch`` / ``serve.decode.wait`` / ``serve.emit``.
+
 Failure story (``distributed/resilience`` conventions):
 
 - **backpressure**: an over-depth queue rejects at ``submit`` with
@@ -47,7 +56,7 @@ from ..observability import flight as _flight
 from ..observability import registry as _obs_registry
 from ..observability import tracing as _tracing
 from .engine import ContinuousBatchingEngine
-from .metrics import ServingMetrics
+from .metrics import LoopClock, ServingMetrics
 from .scheduler import (FifoScheduler, Overloaded, QueueFull, RateLimited,
                         Request, SchedulerClosed)
 
@@ -74,12 +83,11 @@ class RequestHandle:
         #: prompt tokens served from the prefix cache at admission (0
         #: without a pool); clients read it off the handle to see reuse
         self.cache_hit_tokens: int = 0
+        # monotonic; a span's wall-clock bounds are the recording
+        # boundary's time.time() less a difference of these
         self._submit_t = time.monotonic()
-        # wall-clock twin of _submit_t: trace spans use time.time() so
-        # fleet replicas merge onto one timeline (tools/trace_view.py)
-        self._submit_wall = time.time()
+        self._first_token_t: Optional[float] = None
         self._last_token_t: Optional[float] = None
-        self._last_token_wall: Optional[float] = None
 
     # ---- worker-side (single writer: the serve loop) ----
     def _push(self, tok: int) -> None:
@@ -196,6 +204,7 @@ class InferenceServer:
         self.max_request_retries = int(max_request_retries)
         self._cv = threading.Condition()
         self._thread: Optional[threading.Thread] = None
+        self._clock: Optional[LoopClock] = None   # the loop thread's own
         self._stop = False
         self._drain = True
         # absorb this server's live state into the process metrics
@@ -244,11 +253,9 @@ class InferenceServer:
         gathers its own pages inside the one compiled decode program.
 
         ``correlation_id`` keys the request's trace lane (queue wait →
-        prefill → per-token decode → stream end); ``None`` mints a fresh
+        admission → one decode span → stream end); ``None`` mints a fresh
         one. The router passes its own id through here so a rerouted
         request keeps ONE lane across replicas."""
-        from ..profiler import RecordEvent
-
         prompt = np.asarray(prompt, np.int32).ravel()
         self.engine.validate(int(prompt.shape[0]), int(max_new_tokens))
         if top_p < 1.0 and not self.engine.allow_top_p:
@@ -283,36 +290,35 @@ class InferenceServer:
         handle = RequestHandle(req)
         req.handle = handle
         self.start()
-        with RecordEvent("serve:admit"):
-            try:
-                self.scheduler.submit(req)
-            except Overloaded:
-                # deadline-aware shed at the door: the fast-fail half of
-                # overload control (the request learns NOW, within
-                # microseconds of submit, not after its whole deadline)
-                self.metrics.inc("requests_shed")
-                self._adapter_fail(req)
-                _tracing.record_event("shed", corr=corr,
-                                      queue_depth=self.scheduler.depth)
-                raise
-            except RateLimited as e:
-                # the tenant is over ITS admission rate — the system
-                # working as designed, not an availability failure: no
-                # _adapter_fail, so an abusive tenant's rejects cannot
-                # burn an SLO window and buy fleet capacity through the
-                # autoscaler. The flight note carries the tenant label
-                # into every subsequent dump (trace_view --list).
-                self.metrics.inc("requests_rate_limited")
-                _tracing.record_event("rate_limited", corr=corr,
-                                      tenant=e.tenant)
-                _flight.note("rate_limited", corr=corr, tenant=e.tenant,
-                             retry_after_s=round(e.retry_after, 3))
-                raise
-            except QueueFull:
-                self.metrics.inc("requests_rejected")
-                _tracing.record_event("rejected", corr=corr,
-                                      queue_depth=self.scheduler.depth)
-                raise
+        try:
+            self.scheduler.submit(req)
+        except Overloaded:
+            # deadline-aware shed at the door: the fast-fail half of
+            # overload control (the request learns NOW, within
+            # microseconds of submit, not after its whole deadline)
+            self.metrics.inc("requests_shed")
+            self._adapter_fail(req)
+            _tracing.record_event("shed", corr=corr,
+                                  queue_depth=self.scheduler.depth)
+            raise
+        except RateLimited as e:
+            # the tenant is over ITS admission rate — the system
+            # working as designed, not an availability failure: no
+            # _adapter_fail, so an abusive tenant's rejects cannot
+            # burn an SLO window and buy fleet capacity through the
+            # autoscaler. The flight note carries the tenant label
+            # into every subsequent dump (trace_view --list).
+            self.metrics.inc("requests_rate_limited")
+            _tracing.record_event("rate_limited", corr=corr,
+                                  tenant=e.tenant)
+            _flight.note("rate_limited", corr=corr, tenant=e.tenant,
+                         retry_after_s=round(e.retry_after, 3))
+            raise
+        except QueueFull:
+            self.metrics.inc("requests_rejected")
+            _tracing.record_event("rejected", corr=corr,
+                                  queue_depth=self.scheduler.depth)
+            raise
         self.metrics.inc("requests_submitted")
         _tracing.record_event("submit", corr=corr, request_id=req.id,
                               prompt_len=int(prompt.shape[0]))
@@ -434,10 +440,16 @@ class InferenceServer:
 
     # ------------------------------------------------------------ worker
     def _loop(self) -> None:
+        # made here: the clock reads the CPU time of the thread it is on
+        clock = self._clock = self.engine.clock = LoopClock(
+            self.metrics.loop_phase)
         while True:
             with self._cv:
                 while (not self._stop and self.engine.active_count == 0
                        and self.scheduler.depth == 0):
+                    # entered anew at every wake-up, so that a snapshot
+                    # lacks at most one wait of an idle stretch
+                    clock.enter("idle")
                     self._cv.wait(0.1)
                 if self._stop:
                     if not self._drain or (self.engine.active_count == 0
@@ -448,6 +460,7 @@ class InferenceServer:
             except Exception as e:  # a fault must never kill the loop
                 self._recover(e)
         self._fail_backlog()
+        clock.enter("idle")     # book the last phase
 
     def _fail_backlog(self) -> None:
         """Shutdown tail: terminate whatever was not drained. Queued
@@ -475,6 +488,8 @@ class InferenceServer:
         self.metrics.set_queue_depth(0)
 
     def _tick(self) -> None:
+        clock = self._clock
+        clock.enter("schedule", "serve.schedule")
         for req in self.scheduler.pop_expired():
             self._expire(req)
         for req in self.scheduler.pop_predicted_misses():
@@ -501,16 +516,21 @@ class InferenceServer:
                     # recovery — dropping them would hang their clients
                     self._recover(e, extra=admits[i:])
                     return
+        live = self.engine.active_count
         self.metrics.set_queue_depth(self.scheduler.depth)
-        self.metrics.set_active_slots(self.engine.active_count)
-        if self.engine.active_count == 0:
+        self.metrics.set_active_slots(live)
+        if live == 0:
             return
         fault_point("serve.step")
-        events = self.engine.step()
+        # the step's three spans share one tag dict: its number, as
+        # snapshot()["decode_steps"] will count it, and the live slots
+        clock.enter("decode_dispatch", "serve.decode.dispatch",
+                    tags={"step": self.metrics.decode_steps + 1,
+                          "live": live})
+        events = self.engine.step()     # leaves the clock in "emit"
         self.metrics.inc("decode_steps")
         per_adapter = self.engine.store is not None
         now = time.monotonic()
-        now_wall = time.time()
         for ev in events:
             req = self.engine.requests[ev.slot]
             h = req.handle
@@ -521,63 +541,84 @@ class InferenceServer:
             if h._last_token_t is not None:
                 self.metrics.observe_inter_token(now - h._last_token_t)
             h._last_token_t = now
-            # per-token decode span in the request's lane, bracketed by
-            # the existing step read-back (no extra sync): one "decode"
-            # slice per emitted token, spanning since its previous token
-            _tracing.record_span(
-                "decode", h._last_token_wall or now_wall, now_wall,
-                corr=req.corr_id, tags={"slot": ev.slot})
-            h._last_token_wall = now_wall
             if ev.done or h._count() >= req.max_new_tokens:
                 self._finish(req, ev.slot)
 
     def _admit(self, req: Request, slot: int) -> None:
-        req.attempts += 1   # count BEFORE any fault: a failed admission
-        fault_point("serve.admit")  # spends retry budget, never loops
-        now = time.monotonic()
-        self.metrics.observe_queue_wait(now - req.handle._submit_t)
-        # the queue-wait lane slice: submit wall-time -> this admission
-        # (a requeued request's later admissions re-enter the lane as
-        # fresh queue_wait slices after the engine_reset marker)
-        _tracing.record_span("queue_wait", req.handle._submit_wall,
-                             time.time(), corr=req.corr_id,
-                             tags={"attempt": req.attempts})
-        first, fin, hit_tokens = self.engine.admit(req, slot)
-        self.metrics.inc("prefills")
-        if self.engine.pool is not None:
-            req.handle.cache_hit_tokens = hit_tokens
-            self.metrics.inc("prefix_hit_tokens", hit_tokens)
-            self.metrics.inc("prefix_miss_tokens",
-                             len(req.prompt) - hit_tokens)
-        h = req.handle
-        h._push(first)
-        self.metrics.inc("tokens_emitted")
-        t1 = time.monotonic()
-        if self.engine.store is not None:
-            self.metrics.adapter_tokens(req.adapter_id)
-        if h.ttft_s is None:  # a requeued request keeps its FIRST ttft
-            h.ttft_s = t1 - h._submit_t
-            self.metrics.observe_ttft(h.ttft_s)
+        clock, h, corr = self._clock, req.handle, req.corr_id
+        clock.enter("admit_host")
+        # the admission's own span opens at that boundary, around the
+        # spans of its parts; the engine divides it at the first token's
+        # read-back, and what follows that is this span's own time
+        t_admit = clock.t * 1e-9
+        admission = _tracing.begin("serve.admit", t_admit)
+        clock.span("serve.prefill.dispatch", corr)
+        tags = {"slot": int(slot), "prompt_len": len(req.prompt)}
+        try:
+            req.attempts += 1   # count BEFORE any fault: a failed admission
+            fault_point("serve.admit")  # spends retry budget, never loops
+            wait = time.monotonic() - h._submit_t
+            self.metrics.observe_queue_wait(wait)
+            # the queue-wait lane slice: submit -> this admission (a
+            # requeued request's later admissions re-enter the lane as
+            # fresh queue_wait slices after the engine_reset marker)
+            _tracing.record_span("queue_wait", t_admit - wait, t_admit,
+                                 corr=corr, tags={"attempt": req.attempts})
+            first, fin, hit_tokens = self.engine.admit(req, slot)
+            tags["bucket"] = self.engine.bucket_for_prompt(
+                len(req.prompt) - hit_tokens)
+            if req.adapter_id is not None:
+                tags["adapter"] = req.adapter_id
+            self.metrics.inc("prefills")
+            if self.engine.pool is not None:
+                tags["prefix_hit_tokens"] = int(hit_tokens)
+                h.cache_hit_tokens = hit_tokens
+                self.metrics.inc("prefix_hit_tokens", hit_tokens)
+                self.metrics.inc("prefix_miss_tokens",
+                                 len(req.prompt) - hit_tokens)
+            h._push(first)
+            self.metrics.inc("tokens_emitted")
+            t1 = time.monotonic()
             if self.engine.store is not None:
-                # under the first-admission guard, like TTFT: a crash-
-                # requeued request is ONE request, not one per attempt
-                # (requests_submitted counts it once; per_adapter must
-                # agree or per-tenant goodput skews)
-                self.metrics.adapter_request(req.adapter_id)
-                self.metrics.observe_adapter_ttft(req.adapter_id, h.ttft_s)
-        h._last_token_t = t1
-        h._last_token_wall = time.time()
-        if fin or req.max_new_tokens == 1:
-            # eos straight out of prefill: zero decode iterations
-            self._finish(req, slot)
+                self.metrics.adapter_tokens(req.adapter_id)
+            if h.ttft_s is None:  # a requeued request keeps its FIRST ttft
+                h.ttft_s = t1 - h._submit_t
+                self.metrics.observe_ttft(h.ttft_s)
+                if self.engine.store is not None:
+                    # under the first-admission guard, like TTFT: a crash-
+                    # requeued request is ONE request, not one per attempt
+                    # (requests_submitted counts it once; per_adapter must
+                    # agree or per-tenant goodput skews)
+                    self.metrics.adapter_request(req.adapter_id)
+                    self.metrics.observe_adapter_ttft(req.adapter_id,
+                                                      h.ttft_s)
+            h._first_token_t = h._last_token_t = t1
+            if fin or req.max_new_tokens == 1:
+                # eos straight out of prefill: zero decode iterations
+                self._finish(req, slot)
+        finally:
+            # a failed admission took the loop's time too: its span ends
+            # with the tags it got to
+            clock.enter("schedule")
+            _tracing.end(admission, clock.t * 1e-9, corr=corr, tags=tags)
+            clock.span("serve.schedule")
 
     def _finish(self, req: Request, slot: int) -> None:
         self.engine.release(slot)
         self.metrics.inc("requests_completed")
         self.metrics.set_active_slots(self.engine.active_count)
-        _tracing.record_event("stream_end", corr=req.corr_id,
-                              tokens=req.handle._count())
-        req.handle._finish()
+        h = req.handle
+        n = h._count()
+        if n > 1:
+            # the request's decode stretch as ONE span, first token to
+            # last; the steps behind it are the untraced lane's
+            # serve.decode.* spans, the gaps are metrics.inter_token
+            now = time.time()
+            _tracing.record_span(
+                "decode", now - (time.monotonic() - h._first_token_t), now,
+                corr=req.corr_id, tags={"tokens": n - 1, "slot": int(slot)})
+        _tracing.record_event("stream_end", corr=req.corr_id, tokens=n)
+        h._finish()
 
     def _adapter_fail(self, req: Request) -> None:
         """Per-tenant failure accounting — the availability input the

@@ -3,9 +3,10 @@ recorder (paddle_tpu/observability/ + the wiring through serving,
 profiler, supervisor and tools/trace_view.py).
 
 The tentpole acceptance lives here: one served request yields a single
-merged chrome-trace lane spanning router submit → queue wait → prefill
-(bucket/prefix tags) → per-token decode → stream end, keyed by its
-correlation id; and a crash drill (FaultPlan engine reset) emits a
+merged chrome-trace lane spanning router submit → queue wait → admission
+(bucket/prefix tags; prefill dispatch and wait inside) → one decode span
+→ stream end, keyed by its correlation id, with the decode steps behind
+it in the untraced lane; and a crash drill (FaultPlan engine reset) emits a
 flight-recorder dump carrying that id.
 """
 import json
@@ -333,6 +334,39 @@ def test_hang_watchdog_dumps_flight_artifact(tmp_path):
     assert dump["extra"]["step_timeout_s"] == pytest.approx(0.05)
 
 
+def test_hang_is_counted_once_its_dump_is_on_disk(tmp_path, monkeypatch):
+    """The race behind the flaky test above, made certain: with a dump
+    that takes 0.3 s to reach the disk, whoever sees ``hangs_detected``
+    move must find the hang's artifact, not an earlier one."""
+    from paddle_tpu.framework.supervisor import HangWatchdog
+
+    import warnings
+
+    flight.configure(dump_dir=str(tmp_path))
+    flight.dump("earlier")
+    real_fsync = os.fsync
+
+    def slow_fsync(fd):
+        time.sleep(0.3)
+        real_fsync(fd)
+
+    monkeypatch.setattr(os, "fsync", slow_fsync)
+    wd = HangWatchdog(step_timeout=0.05, action="warn")
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        wd.start()
+        wd.beat()
+        deadline = time.monotonic() + 5.0
+        while wd.hangs_detected == 0 and time.monotonic() < deadline:
+            time.sleep(0.01)
+        path = flight.flight_recorder().stats()["last_dump_path"]
+        wd.stop()
+    assert wd.hangs_detected == 1
+    with open(path) as f:
+        assert json.load(f)["reason"] == "hang"
+    assert not wd._thread.is_alive()      # stop() waited the watcher out
+
+
 def test_supervisor_before_batch_stamps_train_corr(tmp_path):
     from paddle_tpu.framework.supervisor import (RecoveryPolicy,
                                                  TrainingSupervisor)
@@ -357,11 +391,13 @@ def test_supervisor_before_batch_stamps_train_corr(tmp_path):
 
 # ------------------------------------------------- serving end-to-end
 def test_served_request_yields_one_trace_lane(lm, fleet):
-    """THE acceptance test: router submit → queue wait → prefill (with
-    bucket tag) → per-token decode → stream end, one lane, one corr."""
+    """THE acceptance test: router submit → queue wait → admission (with
+    bucket tag, its prefill dispatch and wait inside) → ONE decode span →
+    stream end, one lane, one corr; the steps in the untraced lane."""
     model, cfg = lm
     router, srv = fleet
     p = _prompt(cfg, 9, seed=1)
+    t_submit = time.time()
     h = router.submit(p, max_new_tokens=5)
     out = h.result(timeout=300)
     assert out.shape[0] == 5
@@ -369,16 +405,40 @@ def test_served_request_yields_one_trace_lane(lm, fleet):
     assert corr and corr == h._current().correlation_id
     spans = tracing.spans(corr=corr)
     names = [s["name"] for s in spans]
-    for expected in ("submit", "router:submit", "queue_wait", "prefill",
+    for expected in ("submit", "router:submit", "queue_wait", "serve.admit",
+                     "serve.prefill.dispatch", "serve.prefill.wait",
                      "decode", "stream_end"):
         assert expected in names, f"missing {expected} in {names}"
-    assert names.count("decode") == 4   # 5 tokens = prefill + 4 decode
-    prefill = next(s for s in spans if s["name"] == "prefill")
-    assert prefill["tags"]["bucket"] == 16
-    assert prefill["tags"]["prompt_len"] == 9
+    # 5 tokens = the admission's first + 4 decoded, in ONE decode span
+    assert names.count("decode") == 1
+    decode = next(s for s in spans if s["name"] == "decode")
+    assert decode["tags"]["tokens"] == 5 - 1
+    admit = next(s for s in spans if s["name"] == "serve.admit")
+    assert admit["tags"]["bucket"] == 16
+    assert admit["tags"]["prompt_len"] == 9
+    # the admission's parts lie inside it, the decode stretch after it
+    for part in ("serve.prefill.dispatch", "serve.prefill.wait"):
+        s = next(s for s in spans if s["name"] == part)
+        assert admit["t0"] <= s["t0"] <= s["t1"] <= admit["t1"]
+    assert admit["t0"] < decode["t0"] <= admit["t1"] < decode["t1"]
     ct = tracing.chrome_trace(corr=corr)
     lanes = {e["tid"] for e in ct["traceEvents"] if e["ph"] in ("X", "i")}
     assert len(lanes) == 1          # ONE merged lane for the request
+    # the 4 decode steps: untraced lane, consecutive step numbers, each
+    # a dispatch, a wait and an emit span that follow one another
+    steps = {}
+    for s in tracing.spans():
+        if s["name"] in ("serve.decode.dispatch", "serve.decode.wait",
+                         "serve.emit") and s["t0"] >= t_submit:
+            assert s["corr"] is None
+            steps.setdefault(s["tags"]["step"], {})[s["name"]] = s
+    mine = sorted(steps)
+    assert len(mine) == 4 and mine == list(range(mine[0], mine[0] + 4))
+    for n in mine:
+        d, w, e = (steps[n][k] for k in ("serve.decode.dispatch",
+                                         "serve.decode.wait", "serve.emit"))
+        assert d["t1"] == w["t0"] and w["t1"] == e["t0"]
+        assert d["tags"] == w["tags"] == e["tags"] == {"step": n, "live": 1}
     # a second request gets its own id and its own lane
     h2 = router.submit(_prompt(cfg, 6, seed=2), max_new_tokens=3)
     h2.result(timeout=300)

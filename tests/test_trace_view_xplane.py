@@ -1,0 +1,129 @@
+"""``tools/trace_view.py --xplane``: the program's spans laid over a
+profiler trace by the one clock both are stamped with. The trace is the
+recorded v5e one of the benchmark's rehearsal (two ``jit__step`` runs);
+the spans are synthetic, placed from its ``profile_start_time``."""
+import json
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(ROOT, "tools"))
+
+import trace_view  # noqa: E402
+
+XPLANE = os.path.join(ROOT, "benchmarks", "tests", "tiny_train_v5e.xplane.pb")
+
+
+@pytest.fixture(scope="module")
+def xp():
+    return trace_view.read_xplane(XPLANE)
+
+
+def _step_spans(xp, shift_s=0.0):
+    """A dispatch / wait / emit chain around each program run, as the
+    serve loop would have recorded it. The two runs are 3 us apart (a
+    train loop runs ahead of the device), so the first step's emit and
+    the second's dispatch share that stretch; each wait span is its run
+    to the float's last digit (0.24 us at this epoch)."""
+    (_, s1, d1), (_, s2, d2) = xp["devices"][0]["modules"]
+    mid = (s1 + d1 + s2) / 2
+    marks = [("serve.schedule", 0, s1 - 400e3, s1 - 300e3),
+             ("serve.decode.dispatch", 1, s1 - 300e3, s1),
+             ("serve.decode.wait", 1, s1, s1 + d1),
+             ("serve.emit", 1, s1 + d1, mid),
+             ("serve.decode.dispatch", 2, mid, s2),
+             ("serve.decode.wait", 2, s2, s2 + d2),
+             ("serve.emit", 2, s2 + d2, s2 + d2 + 4e3)]
+    return [{"name": n, "corr": None,
+             "t0": xp["start_s"] + a * 1e-9 + shift_s,
+             "t1": xp["start_s"] + b * 1e-9 + shift_s,
+             "tags": {"step": step, "live": 1} if step else {}}
+            for n, step, a, b in marks]
+
+
+def test_profile_start_time_is_read(xp):
+    assert xp["start_s"] == pytest.approx(1790466512.049697214, abs=1e-6)
+    mods = xp["devices"][0]["modules"]
+    assert [m[0].split("(")[0] for m in mods] == ["jit__step", "jit__step"]
+    # the gaps are the reduction's own: same total as its idle time
+    red = xp["reduced"]
+    d = xp["devices"][0]
+    assert (d["gap1"] - d["gap0"]).sum() * 1e-9 == pytest.approx(
+        red["window_s"] - red["busy_s"], abs=1e-9)
+
+
+def test_gap_between_programs_goes_to_the_covering_span(xp):
+    spans = _step_spans(xp)
+    c = trace_view.check_causality(spans, xp, "jit__step")
+    (_, s1, d1), (_, s2, _) = xp["devices"][0]["modules"]
+    between = (s2 - s1 - d1) * 1e-9
+    assert c["judged"] == 2 and c["outside"] == 0 and c["ok"]
+    # 0.3 ms into the first step's dispatch, 1.5 us into the second's
+    assert c["offset_ms"] == pytest.approx((0.3 + between * 1e3 / 2) / 2,
+                                           abs=1e-3)
+    assert c["worst_ms"] == pytest.approx(0.3, abs=1e-3)
+    by = trace_view.idle_by_phase(spans, xp)
+    # no operation runs between the two runs: that stretch is idle, and
+    # under the first step's emit and the second's dispatch, half each
+    assert 1e-6 < between < 10e-6
+    assert by["serve.emit"] == pytest.approx(between / 2, abs=5e-7)
+    assert by["serve.decode.dispatch"] == pytest.approx(between / 2,
+                                                        abs=5e-7)
+    # the rest of the idle time lies inside the runs, under the waits
+    idle = xp["reduced"]["window_s"] - xp["reduced"]["busy_s"]
+    assert by["serve.decode.wait"] == pytest.approx(idle - between,
+                                                    abs=1e-6)
+    assert by[trace_view.UNCOVERED] == pytest.approx(0, abs=1e-9)
+    assert sum(by.values()) == pytest.approx(idle, rel=1e-9)
+
+
+def test_spans_off_by_50_ms_trip_the_causality_check(xp, tmp_path, capsys):
+    spans = _step_spans(xp, shift_s=0.050)
+    c = trace_view.check_causality(spans, xp, "jit__step")
+    assert not c["ok"] and c["judged"] == 0
+    # through the command: says so, attributes nothing, exits 3
+    f = tmp_path / "spans.json"
+    f.write_text(json.dumps(spans))
+    rc = trace_view.main([str(f), "--xplane", XPLANE, "--program",
+                          "jit__step", "-o", str(tmp_path / "m.json")])
+    out = capsys.readouterr().out
+    assert rc == 3 and "by the host span" not in out
+    assert not (tmp_path / "m.json").exists()
+    # half a run's length: both runs still start inside a step's spans,
+    # and end after the read-back that waited for them had returned
+    spans = _step_spans(xp, shift_s=-0.004)
+    c = trace_view.check_causality(spans, xp, "jit__step")
+    assert c["judged"] == 2 and c["outside"] == 2 and not c["ok"]
+    assert c["offset_ms"] > 1.0
+
+
+def test_command_writes_the_merged_trace_with_a_device_lane(xp, tmp_path,
+                                                            capsys):
+    f = tmp_path / "spans.json"
+    f.write_text(json.dumps(_step_spans(xp)))
+    out = tmp_path / "merged.json"
+    rc = trace_view.main([str(f), "--xplane", XPLANE, "--program",
+                          "jit__step", "-o", str(out)])
+    text = capsys.readouterr().out
+    assert rc == 0
+    assert "2 of 2 runs" in text and "serve.decode.wait" in text
+    assert "between step and step" in text
+    ev = json.loads(out.read_text())["traceEvents"]
+    dev = [e for e in ev if e.get("pid") == 2 and e["ph"] == "X"]
+    host = [e for e in ev if e.get("name") == "serve.decode.dispatch"]
+    assert len(dev) == 2 and len(host) == 2
+    # one timeline: the first run starts 0.3 ms into its dispatch span
+    assert dev[0]["ts"] - host[0]["ts"] == pytest.approx(300.0, abs=1.0)
+
+
+def test_innermost_segments_give_a_parent_only_what_no_child_covers():
+    spans = [{"name": "serve.admit", "t0": 0.0, "t1": 10.0},
+             {"name": "serve.prefill.dispatch", "t0": 0.0, "t1": 3.0},
+             {"name": "serve.prefill.wait", "t0": 3.0, "t1": 8.0},
+             {"name": "serve.schedule", "t0": 10.0, "t1": 11.0},
+             {"name": "zero", "t0": 5.0, "t1": 5.0}]
+    assert trace_view.innermost_segments(spans) == [
+        (0.0, 3.0, "serve.prefill.dispatch"), (3.0, 8.0, "serve.prefill.wait"),
+        (8.0, 10.0, "serve.admit"), (10.0, 11.0, "serve.schedule")]

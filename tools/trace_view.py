@@ -26,7 +26,25 @@ by recording host when more than one contributed.
     python tools/trace_view.py --corr req-1f03ab-000004 dumps/*.json \\
         -o one_request.json
 
-Exit codes: 0 ok; 2 no spans found / unreadable input.
+``--xplane <file.xplane.pb>`` lays the spans over a ``jax.profiler``
+trace of the same process. The trace stamps its start on the clock the
+spans are stamped with (``profile_start_time`` on the ``Task
+Environment`` plane, nanoseconds since the epoch; every event of the
+trace is an offset from it), so nothing is calibrated: the device's
+programs join the merged chrome trace as a lane of their own, and the
+device's idle gaps are printed by the serve-loop span that covers them
+(``serve.schedule``, ``serve.decode.dispatch``, ...; a parent span
+counts only where no child covers) beside the by-neighbouring-programs
+table of ``benchmarks/harness/trace_reduce.py``. Before it attributes
+anything it checks causality: every run of ``--program`` (default the
+decode program) must start and end on the device inside one step's
+``serve.decode.dispatch`` .. ``serve.decode.wait``; if over 1 % do not,
+the clocks disagree, and it says by how much and stops.
+
+    python tools/trace_view.py spans.json --xplane t.xplane.pb -o m.json
+
+Exit codes: 0 ok; 2 no spans found / unreadable input; 3 the spans and
+the trace are not on one clock.
 """
 from __future__ import annotations
 
@@ -206,6 +224,214 @@ def group_by_host(spans: List[dict]) -> dict:
     return {h: sorted(srcs) for h, srcs in sorted(by_host.items())}
 
 
+# ------------------------------------------------- spans over a trace
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+LOOP_SPAN_PREFIX = "serve."        # what the serve loop's thread records
+STEP_SPANS = ("serve.decode.dispatch", "serve.decode.wait")
+UNCOVERED = "(no span)"
+
+
+def _trace_reduce():
+    """The device side of a trace is the benchmark's reduction, not a
+    copy of it."""
+    bench = os.path.join(ROOT, "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import trace_reduce
+
+    return trace_reduce
+
+
+def read_xplane(path: str) -> dict:
+    """``{"start_s", "devices": [{"name", "modules", "gap0", "gap1"}],
+    "reduced"}``: the trace's start in seconds since the epoch, and a
+    chip's program runs ``(name, start_ns, duration_ns)`` and idle gaps
+    (two arrays, ns) as offsets from it."""
+    import jax
+    import numpy as np
+
+    tr = _trace_reduce()
+    reduced = tr.reduce_trace(path)
+    programs = {d["name"]: d["modules"] for d in reduced["devices"]}
+    start_ns = None
+    devices = []
+    # the reduction keeps a chip's gaps only as sums by label: the gaps
+    # themselves come from the operations' intervals, by its own union
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name == "Task Environment":
+            start_ns = dict(plane.stats).get("profile_start_time")
+        if not programs.get(plane.name):
+            continue
+        ops = [(e.start_ns, e.start_ns + e.duration_ns)
+               for line in plane.lines if line.name == tr.OPS_LINE
+               for e in line.events]
+        _, g0, g1 = tr._union(*np.asarray(ops, float).T)
+        devices.append({"name": plane.name, "gap0": g0, "gap1": g1,
+                        "modules": programs[plane.name]})
+    if start_ns is None:
+        raise ValueError(f"{path}: no profile_start_time on a 'Task "
+                         f"Environment' plane")
+    return {"start_s": int(start_ns) * 1e-9, "devices": devices,
+            "reduced": reduced}
+
+
+def innermost_segments(spans: List[dict]) -> List[Tuple[float, float, str]]:
+    """Disjoint ``(t0, t1, name)`` pieces of one thread's spans, each
+    named by the innermost span over it: a parent keeps only what no
+    child covers."""
+    out: List[Tuple[float, float, str]] = []
+    stack: List[Tuple[float, str]] = []      # (t1, name) of open spans
+    at = None
+
+    def close_until(t):
+        nonlocal at
+        while stack and stack[-1][0] <= t:
+            t1, name = stack.pop()
+            if t1 > at:
+                out.append((at, t1, name))
+                at = t1
+
+    for s in sorted(spans, key=lambda s: (s["t0"], -s["t1"])):
+        t0, t1 = float(s["t0"]), float(s["t1"])
+        if t1 <= t0:
+            continue
+        close_until(t0)
+        if stack and t0 > at:
+            out.append((at, t0, stack[-1][1]))
+        at = t0
+        stack.append((t1, s["name"]))
+    close_until(float("inf"))
+    return out
+
+
+def check_causality(spans: List[dict], xp: dict, program: str) -> dict:
+    """Does every device run of ``program`` lie inside one step's
+    ``serve.decode.dispatch`` .. ``serve.decode.wait``: started after the
+    host began to dispatch it, finished before its read-back returned?
+    Runs outside the stretch the step spans cover are not judged. A slip
+    smaller than the slack around a run (the dispatch and the read-back's
+    latency) cannot be seen this way; ``offset_ms``, the median of device
+    start less the start of the last dispatch span begun before it (over
+    the runs that fit; over all when the clocks disagree), is what to
+    read then."""
+    import numpy as np
+
+    by_step: dict = {}
+    for s in spans:
+        if s.get("name") in STEP_SPANS:
+            step = (s.get("tags") or {}).get("step")
+            w = by_step.setdefault(step, [float("inf"), float("-inf")])
+            w[0], w[1] = min(w[0], s["t0"]), max(w[1], s["t1"])
+    if not by_step:
+        return {"judged": 0, "outside": 0, "ok": False, "offset_ms": None,
+                "why": "no serve.decode.* span among the inputs"}
+    win = np.asarray(sorted(by_step.values()))
+    lo = (win[:, 0] - xp["start_s"]) * 1e9
+    hi = (win[:, 1] - xp["start_s"]) * 1e9
+    runs = np.asarray([(s, s + d) for dev in xp["devices"]
+                       for n, s, d in dev["modules"] if program in n],
+                      float).reshape(-1, 2)
+    runs = runs[(runs[:, 0] >= lo[0]) & (runs[:, 0] <= hi[-1])]
+    if not len(runs):
+        return {"judged": 0, "outside": 0, "ok": False, "offset_ms": None,
+                "why": f"no run of a program named *{program}* inside the "
+                       f"stretch the step spans cover"}
+    start, end = runs[:, 0], runs[:, 1]
+    i = np.clip(np.searchsorted(lo, start, side="right") - 1, 0, len(lo) - 1)
+    slack = 1e3     # a float of epoch seconds resolves 0.24 us
+    fits = (start >= lo[i] - slack) & (end <= hi[i] + slack)
+    outside = int((~fits).sum())
+    ok = outside <= 0.01 * len(runs)
+    off = (start - lo[i])[fits if ok else slice(None)]
+    return {"judged": len(runs), "outside": outside, "ok": bool(ok),
+            "offset_ms": float(np.median(off)) * 1e-6,
+            "worst_ms": float(off[np.abs(off).argmax()]) * 1e-6}
+
+
+def idle_by_phase(spans: List[dict], xp: dict) -> dict:
+    """Seconds of device idle gaps under each serve-loop span (innermost
+    first), and under none, averaged over the chips that ran."""
+    import numpy as np
+
+    loop = [s for s in spans
+            if str(s.get("name", "")).startswith(LOOP_SPAN_PREFIX)]
+    segs = innermost_segments(loop)
+    out: dict = {}
+    chips = max(1, len(xp["devices"]))
+    for dev in xp["devices"]:
+        g0, g1 = dev["gap0"], dev["gap1"]
+        cum = np.concatenate([[0.0], np.cumsum(g1 - g0)])
+
+        def gap_before(t):
+            """Idle ns before ``t`` (an array): the whole gaps that
+            began before it, the last of them only as far as ``t``."""
+            k = np.searchsorted(g0, t, side="right") - 1   # last gap begun
+            last = np.maximum(k, 0)
+            return np.where(k < 0, 0.0, cum[last]
+                            + np.minimum(t, g1[last]) - g0[last])
+
+        total = float(cum[-1])
+        covered = 0.0
+        if segs and len(g0):
+            a = (np.asarray([s[0] for s in segs]) - xp["start_s"]) * 1e9
+            b = (np.asarray([s[1] for s in segs]) - xp["start_s"]) * 1e9
+            under = gap_before(b) - gap_before(a)
+            for (_, _, name), ns in zip(segs, under):
+                out[name] = out.get(name, 0.0) + float(ns) * 1e-9 / chips
+            covered = float(under.sum())
+        out[UNCOVERED] = out.get(UNCOVERED, 0.0) + \
+            (total - covered) * 1e-9 / chips
+    return out
+
+
+def device_lane_events(xp: dict, pid: int = 2) -> List[dict]:
+    """The device's program runs as chrome events on the spans' clock."""
+    events = [{"ph": "M", "name": "process_name", "pid": pid, "tid": 0,
+               "args": {"name": "device (jax.profiler trace)"}}]
+    for tid, dev in enumerate(xp["devices"]):
+        events.append({"ph": "M", "name": "thread_name", "pid": pid,
+                       "tid": tid, "args": {"name": dev["name"]}})
+        for name, start_ns, dur_ns in dev["modules"]:
+            events.append({"name": name, "ph": "X", "pid": pid, "tid": tid,
+                           "ts": xp["start_s"] * 1e6 + start_ns * 1e-3,
+                           "dur": dur_ns * 1e-3, "args": {}})
+    return events
+
+
+def report_xplane(spans: List[dict], xp: dict, program: str) -> int:
+    """Print the causality check and, if it holds, the device's idle by
+    host phase beside the by-neighbouring-programs table; 0 or 3."""
+    tr = _trace_reduce()
+    red = xp["reduced"]
+    print(f"trace starts at {xp['start_s']:.6f} s since the epoch; window "
+          f"{red['window_s']:.4f} s, busy {red['busy_s']:.4f} s, idle "
+          f"{red['window_s'] - red['busy_s']:.4f} s")
+    c = check_causality(spans, xp, program)
+    if c["judged"] == 0:
+        print(f"causality: not checked: {c['why']}")
+        return 3
+    print(f"causality: {c['judged'] - c['outside']} of {c['judged']} runs "
+          f"of *{program}* lie on the device inside their step's "
+          f"dispatch..wait spans")
+    if not c["ok"]:
+        print(f"the clocks disagree: a run starts a median "
+              f"{c['offset_ms']:.3f} ms (worst {c['worst_ms']:.3f} ms) "
+              f"after the last dispatch span begun before it, and "
+              f"{c['outside']} do not end inside that step; nothing "
+              f"attributed")
+        return 3
+    print(f"device start less dispatch start: median {c['offset_ms']:.3f} "
+          f"ms, worst {c['worst_ms']:.3f} ms")
+    print("device idle by the host span that covers it, s:")
+    for name, sec in sorted(idle_by_phase(spans, xp).items(),
+                            key=lambda kv: -kv[1]):
+        print(f"  {sec:9.4f}  {name}")
+    print("device idle by the programs around it, s:")
+    for label, sec in tr.breakdown(red)["idle_gaps"]:
+        print(f"  {sec:9.4f}  {label}")
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("inputs", nargs="+",
@@ -218,6 +444,14 @@ def main(argv=None) -> int:
     ap.add_argument("--list", action="store_true",
                     help="print one line per correlation id instead of "
                          "writing a trace")
+    ap.add_argument("--xplane", default=None, metavar="FILE.xplane.pb",
+                    help="a jax.profiler trace of the process that "
+                         "recorded the spans: check the clocks, print the "
+                         "device's idle gaps by host phase, add the "
+                         "device's programs to the merged trace")
+    ap.add_argument("--program", default="decode",
+                    help="with --xplane: the device program whose runs "
+                         "the causality check places (default: decode)")
     args = ap.parse_args(argv)
 
     spans: List[dict] = []
@@ -252,6 +486,19 @@ def main(argv=None) -> int:
 
     trace = merge_chrome(spans, corr=args.corr)
     n = sum(1 for ev in trace["traceEvents"] if ev["ph"] in ("X", "i"))
+    if args.xplane:
+        try:
+            xp = read_xplane(args.xplane)
+        except Exception as e:
+            print(f"trace_view: {args.xplane}: {type(e).__name__}: {e}",
+                  file=sys.stderr)
+            return 2
+        rc = report_xplane(spans, xp, args.program)
+        if rc:
+            return rc
+        trace["traceEvents"].extend(device_lane_events(xp))
+        if not args.output:
+            return 0
     if args.output:
         with open(args.output, "w") as f:
             json.dump(trace, f)
