@@ -9,8 +9,7 @@ against the chip's peak FLOPs. ``vs_baseline`` = measured MFU / 0.35.
 
 Breadth phases ride in ``extra``:
   - ``long_context``: GPT at seq=4096, which takes the Pallas
-    flash-attention path (asserted in-run via ``should_use_flash``);
-    its ``bf16_mode`` repeats the run under ``PT_FLASH_BF16=1``.
+    flash-attention path (asserted in-run via ``should_use_flash``).
   - ``gpt_1p3b``: the BASELINE.md north-star width on one chip.
   - ``gpt_decode`` / ``gpt_serve``: the offline and continuous-batching
     serving engines.
@@ -25,7 +24,6 @@ from __future__ import annotations
 import contextlib
 import io
 import json
-import os
 import sys
 import traceback
 
@@ -105,8 +103,7 @@ def _random_ids(cfg, batch: int, seq: int):
 
 def bench_long_context() -> dict:
     """GPT at seq>=4096: the config that exercises the Pallas flash kernel
-    (should_use_flash asserted live) — the long-context proof. The dot
-    operands' dtype follows ``PT_FLASH_BF16`` as the environment has it."""
+    (should_use_flash asserted live) — the long-context proof."""
     import jax.numpy as jnp
     from paddle_tpu.kernels.flash_attention import should_use_flash
     from paddle_tpu.models.gpt import GPTConfig, gpt_flops_per_token
@@ -132,22 +129,6 @@ def bench_long_context() -> dict:
     return {"seq": seq, "batch": batch, "flash_active": True,
             "tokens_per_sec": round(tokens_per_sec, 1),
             "mfu": round(mfu, 4)}
-
-
-def bench_long_context_bf16(f32_operands: dict) -> dict:
-    """The same step with native-bf16 MXU operands inside the Pallas
-    kernels (kernels/flash_attention.py:_operand_dtype). The variable is
-    read at trace time; the caller has dropped the jit caches since the
-    f32-operand run, so this one compiles afresh."""
-    os.environ["PT_FLASH_BF16"] = "1"
-    try:
-        out = bench_long_context()
-    finally:
-        os.environ.pop("PT_FLASH_BF16", None)
-    if "tokens_per_sec" in f32_operands:
-        out["speedup_vs_f32_operands"] = round(
-            out["tokens_per_sec"] / f32_operands["tokens_per_sec"], 3)
-    return out
 
 
 def bench_gpt_1p3b() -> dict:
@@ -421,8 +402,6 @@ def main() -> int:
         return result
 
     long_ctx = breadth("long_context", bench_long_context)
-    long_ctx["bf16_mode"] = breadth(
-        "long_context_bf16", lambda: bench_long_context_bf16(long_ctx))
     g13 = breadth("gpt_1p3b", bench_gpt_1p3b)
     decode = breadth("gpt_decode", bench_gpt_decode)
     serve = breadth("gpt_serve", bench_gpt_serve)

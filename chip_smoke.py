@@ -150,15 +150,41 @@ def train_phase(cfg, batch: int, seq: int, steps: int) -> dict:
 
 
 # ---------------------------------------------------------------- flash
-# Why 2% of the reference's largest element: kernel and reference both
-# compute in f32 from the same bf16 inputs (the package pins
-# jax_default_matmul_precision to float32, inside the kernels too) and
-# both round their results to bf16, so they differ by summation order and
-# one bf16 rounding — an ulp is 2^-8 = 0.4% of the value it rounds. On the
-# v5e the largest difference measured 0.5% of the largest element (PR 21).
+# Why 2% of the reference's largest element: kernel and reference start
+# from the same bf16 inputs. The reference computes in f32 throughout. The
+# kernel's dots take the bf16 inputs as they are (products exact, summed in
+# f32) and round the probabilities p and the score gradients ds to bf16
+# for their dot, as XLA's attention does under seq 2048; statistics and
+# accumulators are f32. Both round their results to bf16, where an ulp is
+# 2^-8 = 0.4% of the value it rounds; p and ds carry the same relative
+# rounding into sums over thousands of keys, where it averages out. On the
+# v5e the largest difference measured 0.5% of the largest element with f32
+# operands (PR 21); PERF.md section 6 has the four shares on bf16 operands.
 # A wrong mask, block index or softmax statistic moves the result by its
 # own magnitude, fifty times the tolerance.
 KERNEL_TOL = 2e-2
+
+
+def _check_same_mask(label, q, k, v, w, result) -> None:
+    """Two identities of attention's vjp that hold whatever the keep-mask
+    is, as long as forward, dq kernel and dkv kernel all use the SAME one:
+    <out, w> = <v, dv> (both are sum_ij pd_ij <v_j, w_i>) and <q, dq> =
+    <k, dk> (both are scale * sum_ij ds_ij <q_i, k_j>). Kernels that drew
+    different masks miss them by tens of per cent; bf16 rounding of the
+    results moves them by a fraction of one."""
+    q, k, v, w, out, dq, dk, dv = (np.asarray(x, np.float32) for x in
+                                   (q, k, v, w, *result))
+    for name, left, right in (("<out,w> = <v,dv>", out * w, v * dv),
+                              ("<q,dq> = <k,dk>", q * dq, k * dk)):
+        lhs, rhs = (float(x.sum(dtype=np.float64)) for x in (left, right))
+        # the sums are random walks and may nearly cancel: judge by the
+        # walk's length (a wrong mask misses by about half of it)
+        size = float(np.sqrt(np.square(left).sum(dtype=np.float64)))
+        log(f"[flash] {label} {name}: {lhs:.4e} vs {rhs:.4e} "
+            f"(walk length {size:.3e})")
+        check(abs(lhs - rhs) <= KERNEL_TOL * size,
+              f"{label}: {name} fails ({lhs:.4e} vs {rhs:.4e}): forward and "
+              f"backward kernels disagree on the mask")
 
 
 def _kernel_vs_reference(shape, dtype: str, dropout_p: float) -> None:
@@ -197,6 +223,7 @@ def _kernel_vs_reference(shape, dtype: str, dropout_p: float) -> None:
         check(err <= KERNEL_TOL * scale,
               f"kernel {name} differs from the reference by {err:.3e} "
               f"(> {KERNEL_TOL} x {scale:.3e})")
+    _check_same_mask("causal", q, k, v, w, got)
 
     if dropout_p > 0.0:
         # the flagship runs dropout 0, so only this call compiles the
@@ -213,6 +240,7 @@ def _kernel_vs_reference(shape, dtype: str, dropout_p: float) -> None:
         check(all((x == y).all() for x, y in zip(a, b)),
               "dropout is not deterministic for a fixed seed")
         check((a[0] != c[0]).any(), "dropout ignores its seed")
+        _check_same_mask(f"dropout {dropout_p}", q, k, v, w, a)
         log(f"[flash] dropout {dropout_p}: deterministic per seed, "
             f"seed-sensitive, forward and backward finite")
 

@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -31,30 +30,50 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _operand_dtype(*refs):
-    """Dot-operand dtype policy, decided over ALL of a kernel body's
-    inputs at once: mixed-precision inputs (e.g. bf16 q/k with an f32
-    value cache) fall back to f32 — per-tensor decisions would hand
-    lax.dot_general unequal operand dtypes.
-
-    Experimental PT_FLASH_BF16=1 keeps all-bf16 bodies in native bf16
-    (Mosaic rejected bf16 operands for these transposed contractions when
-    the kernels were written, "Bad lhs type" — re-test on jax/Mosaic
-    upgrades; native-bf16 MXU issue would be a large win at L>=4096).
-    Softmax statistics and accumulators stay f32 regardless
-    (preferred_element_type). The env var is read at TRACE time, so
-    setting it after import still takes effect on the next compile.
-    """
-    if os.environ.get("PT_FLASH_BF16", "") == "1" and \
-            all(r.dtype == jnp.bfloat16 for r in refs):
+    """Dot-operand dtype, decided over ALL of a kernel body's inputs at
+    once: when every tensor the body reads is bf16 the MXU takes them as
+    they are; any f32 input (an f32 model, bf16 q/k against an f32 value
+    cache) makes every operand f32 — per-tensor decisions would hand
+    lax.dot_general unequal operand dtypes. Softmax statistics and
+    accumulators are f32 either way (``_dot``'s result type)."""
+    if all(r.dtype == jnp.bfloat16 for r in refs):
         return jnp.bfloat16
     return jnp.float32
 
 
-def _cast_like(a, ref):
-    """Match a derived f32 matrix (p/ds) to the other dot operand's dtype
-    — lax.dot_general requires equal operand dtypes."""
-    return a if a.dtype == ref.dtype else a.astype(ref.dtype)
+def _lanes_to(x, width):
+    """A row statistic (m, l, lse, delta), held lane-replicated as
+    [rows, _LANES], at ``width`` columns for use against a block of that
+    width: whole vregs reused or sliced, no lane broadcast. A [rows, 1]
+    column would fill one lane of each vreg and pay a broadcast at every
+    use, which cost the forward kernel a third of its time."""
+    if width == _LANES:
+        return x
+    if width < _LANES:
+        return x[:, :width]
+    return pltpu.repeat(x, width // _LANES, axis=1)
 
+
+def _dot(a, b, a_dim, b_dim):
+    """``a`` · ``b`` contracting ``a_dim`` of a with ``b_dim`` of b, f32 result.
+    A derived f32 matrix (p, ds) is rounded to the other operand's dtype
+    — lax.dot_general requires equal operand dtypes. bf16 operands state
+    their own single-pass precision: left to inherit the process-wide
+    ``jax_default_matmul_precision="float32"`` they would ask Mosaic for
+    an fp32 contract precision, which it refuses for bf16 ("Bad lhs
+    type"). f32 operands keep the process default."""
+    if a.dtype != b.dtype:
+        a = a.astype(b.dtype)
+    precision = jax.lax.Precision.DEFAULT if b.dtype == jnp.bfloat16 else None
+    return jax.lax.dot_general(a, b, (((a_dim,), (b_dim,)), ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+# 512-blocks, measured on the v5e (PERF.md section 6, PR 26): smaller ones
+# pay more grid steps (~0.35 us each) and a longer schedule per score
+# element; 1024-blocks need a raised VMEM limit that itself costs 5 %,
+# and then gain 3 % at L 2048 (9 % at 4096, none at head size 128)
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 _NEG_INF = -1e30
@@ -143,19 +162,18 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, has_bias, dropout_p):
         q = q_ref[0, 0].astype(od)
         k = k_ref[0, 0].astype(od)
         v = v_ref[0, 0].astype(od)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _dot(q, k, 1, 1) * scale
         if has_bias:
             s = s + bias_ref[0, 0].astype(jnp.float32)
         if causal:
             q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        m_prev = m_s[:]
+        m_prev = m_s[:]      # [block_q, _LANES], lanes equal
         l_prev = l_s[:]
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
+        p = jnp.exp(s - _lanes_to(m_new, block_k))
         alpha = jnp.exp(m_prev - m_new)
         # l accumulates the full softmax denominator (dropout applies to the
         # normalized probabilities, so only the numerator path is masked)
@@ -164,9 +182,7 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, has_bias, dropout_p):
             bid = _block_id(b, h, qi, ki, pl.num_programs(1),
                             pl.num_programs(2), pl.num_programs(3))
             p = p * _dropout_mask((block_q, block_k), dropout_p, seed_ref, bid)
-        acc_s[:] = acc_s[:] * alpha + jax.lax.dot_general(
-            _cast_like(p, v), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_s[:] = acc_s[:] * _lanes_to(alpha, acc_s.shape[1]) + _dot(p, v, 1, 0)
         m_s[:] = m_new
 
     if causal:
@@ -178,11 +194,8 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, has_bias, dropout_p):
     @pl.when(ki == pl.num_programs(3) - 1)
     def _finish():
         l = jnp.maximum(l_s[:], 1e-30)
-        o_ref[0, 0] = (acc_s[:] / l).astype(o_ref.dtype)
-        # row-stat layout: [block_q, LANES] broadcast over the lane dim
-        # (Mosaic requires the last two block dims tile to (8, 128))
-        lse_ref[0, 0] = jnp.broadcast_to(m_s[:] + jnp.log(l),
-                                         (l.shape[0], _LANES))
+        o_ref[0, 0] = (acc_s[:] / _lanes_to(l, acc_s.shape[1])).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_s[:] + jnp.log(l)
 
 
 def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, has_bias,
@@ -218,10 +231,9 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, has_bias,
         k = k_ref[0, 0].astype(od)
         v = v_ref[0, 0].astype(od)
         do = do_ref[0, 0].astype(od)
-        lse = lse_ref[0, 0][:, 0:1]
-        delta = delta_ref[0, 0][:, 0:1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        lse = _lanes_to(lse_ref[0, 0], block_k)
+        delta = _lanes_to(delta_ref[0, 0], block_k)
+        s = _dot(q, k, 1, 1) * scale
         if has_bias:
             s = s + bias_ref[0, 0].astype(jnp.float32)
         p = jnp.exp(s - lse)
@@ -229,8 +241,7 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, has_bias,
             q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             p = jnp.where(q_pos >= k_pos, p, 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dp = _dot(do, v, 1, 1)
         if dropout_p > 0.0:
             bid = _block_id(b, h, qi, ki, pl.num_programs(1),
                             pl.num_programs(2), pl.num_programs(3))
@@ -238,9 +249,7 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, has_bias,
         ds = p * (dp - delta)
         if ds_ref is not None:
             ds_ref[0, 0] = ds.astype(ds_ref.dtype)
-        dq_s[:] += jax.lax.dot_general(
-            _cast_like(ds, k), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        dq_s[:] += _dot(ds, k, 1, 0) * scale
 
     if causal:
         pl.when(k_start <= q_start + block_q - 1)(_body)
@@ -284,37 +293,33 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, has_bias, dropout_p)
         k = k_ref[0, 0].astype(od)
         v = v_ref[0, 0].astype(od)
         do = do_ref[0, 0].astype(od)
-        lse = lse_ref[0, 0][:, 0:1]
-        delta = delta_ref[0, 0][:, 0:1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        # the score block transposed to begin with, [block_k, block_q]:
+        # p^T and ds^T come out in the layout their dots want (contracting
+        # dim 0 of a [block_q, block_k] operand makes Mosaic transpose it,
+        # a quarter of this kernel's time); lse and delta arrive as rows
+        lse = lse_ref[0, 0]      # [1, block_q]
+        delta = delta_ref[0, 0]
+        s = _dot(k, q, 1, 1) * scale
         if has_bias:
-            s = s + bias_ref[0, 0].astype(jnp.float32)
+            s = s + bias_ref[0, 0].astype(jnp.float32).T
         p = jnp.exp(s - lse)
         if causal:
-            q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+            k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
+            q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
             p = jnp.where(q_pos >= k_pos, p, 0.0)
         if dropout_p > 0.0:
             bid = _block_id(b, h, qi, ki, pl.num_programs(1),
                             pl.num_programs(3), pl.num_programs(2))
-            drop = _dropout_mask((block_q, block_k), dropout_p, seed_ref, bid)
+            drop = _dropout_mask((block_q, block_k), dropout_p, seed_ref, bid).T
             pd = p * drop
         else:
             pd = p
-        # dv = pd^T do
-        dv_s[:] += jax.lax.dot_general(
-            _cast_like(pd, do), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dv_s[:] += _dot(pd, do, 1, 0)
+        dp = _dot(v, do, 1, 1)
         if dropout_p > 0.0:
             dp = dp * drop
         ds = p * (dp - delta)
-        # dk = ds^T q * scale
-        dk_s[:] += jax.lax.dot_general(
-            _cast_like(ds, q), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        dk_s[:] += _dot(ds, q, 1, 0) * scale
 
     if causal:
         # q block participates unless entirely above this k block's diagonal
@@ -384,8 +389,8 @@ def _flash_fwd_impl(q, k, v, bias, seed, causal, dropout_p,
             jax.ShapeDtypeStruct((B, H, Lq, _LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -413,10 +418,12 @@ def _flash_bwd_impl(q, k, v, bias, seed, o, lse, do, causal, dropout_p,
     has_bias = bias is not None
     want_dbias = has_bias and bias_grad
 
-    # delta_i = rowsum(dO_i * O_i) (cheap XLA reduction), broadcast into the
-    # [B, H, Lq, _LANES] row-stat layout the kernels block-index; lse arrives
-    # slim [B, H, Lq] (the residual saved by the fwd) and is re-broadcast here
+    # delta_i = rowsum(dO_i * O_i) (cheap XLA reduction); lse arrives slim
+    # [B, H, Lq] (the residual saved by the fwd). The dq kernel block-indexes
+    # both in the [B, H, Lq, _LANES] row-stat layout, re-broadcast here; the
+    # dkv kernel, whose score blocks are transposed, as [B, H, 1, Lq] rows
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta_row, lse_row = delta[:, :, None, :], lse[:, :, None, :]
     delta = jnp.broadcast_to(delta[..., None], (*delta.shape, _LANES))
     lse = jnp.broadcast_to(lse[..., None], (*lse.shape, _LANES))
 
@@ -495,10 +502,8 @@ def _flash_bwd_impl(q, k, v, bias, seed, o, lse, do, causal, dropout_p,
             lambda b, h, ki, qi: bidx(b, h, qi, ki))]
     kv_row_specs = [
         pl.BlockSpec((1, 1, block_q, D), lambda b, h, ki, qi: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, block_q, _LANES),
-                     lambda b, h, ki, qi: (b, h, qi, 0)),
-        pl.BlockSpec((1, 1, block_q, _LANES),
-                     lambda b, h, ki, qi: (b, h, qi, 0)),
+        pl.BlockSpec((1, 1, 1, block_q), lambda b, h, ki, qi: (b, h, 0, qi)),
+        pl.BlockSpec((1, 1, 1, block_q), lambda b, h, ki, qi: (b, h, 0, qi)),
     ]
     dk, dv = pl.pallas_call(
         dkv_kernel,
@@ -519,7 +524,7 @@ def _flash_bwd_impl(q, k, v, bias, seed, o, lse, do, causal, dropout_p,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
-    )(*seed_args, q, k, v, *bias_args, do, lse, delta)
+    )(*seed_args, q, k, v, *bias_args, do, lse_row, delta_row)
     return dq, dk, dv, dbias
 
 

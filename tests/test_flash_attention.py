@@ -5,6 +5,8 @@ Covers the full Pallas forward+backward (VERDICT r1 weak #3): causal, bias
 has no CPU lowering — exercised by chip_smoke.py's flash phase on the chip.
 The ``interpret_pallas`` fixture lives in conftest.py.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -107,3 +109,200 @@ def test_gate_logic_shapes(monkeypatch):
     assert fa.should_use_flash(mk(2048), mk(2048), bias, 0.0)  # bias ok
     bad = jnp.zeros((3, 1, 2048, 2048))
     assert not fa.should_use_flash(mk(2048), mk(2048), bad, 0.0)  # B mismatch
+
+
+# ------------------------------------------------------------------ bf16
+# All-bf16 inputs put every in-kernel dot on bf16 operands (f32 result,
+# single-pass precision stated on the dot); p/pd/ds are rounded to bf16
+# for their dot. Compared with the f32 reference on the same bf16 values,
+# as a share of the reference's largest element (chip_smoke.py's measure).
+
+def _block_keep_mask(shape, dropout_p, seed_ref, block_id):
+    """CPU stand-in for ``fa._dropout_mask`` (the TPU PRNG has no CPU
+    lowering): the same contract, a keep-mask that is a function of
+    (seed, block id) alone, scaled by 1/(1-p)."""
+    key = jax.random.fold_in(jax.random.PRNGKey(seed_ref[0]), block_id)
+    keep = jax.random.uniform(key, shape) >= dropout_p
+    return keep.astype(jnp.float32) / (1.0 - dropout_p)
+
+
+def _full_keep_mask(B, H, Lq, Lk, bq, bk, dropout_p, seed):
+    """The [B, H, Lq, Lk] mask the kernels' blocks add up to."""
+    nq, nk = Lq // bq, Lk // bk
+    seed_ref = jnp.asarray([seed], jnp.int32)
+    rows = []
+    for b in range(B):
+        for h in range(H):
+            blocks = [[_block_keep_mask((bq, bk), dropout_p, seed_ref,
+                                        fa._block_id(b, h, qi, ki, H, nq, nk))
+                       for ki in range(nk)] for qi in range(nq)]
+            rows.append(jnp.block(blocks))
+    return jnp.stack(rows).reshape(B, H, Lq, Lk)
+
+
+def _reference_with_dropout(q, k, v, causal, bias, keep):
+    q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    if bias is not None:
+        s = s + bias
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    if keep is not None:
+        p = p * keep
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+_BF16_CASES = {
+    # name: (causal, bias, dropout_p, D)
+    "causal": (True, False, 0.0, 64),
+    "full": (False, False, 0.0, 64),
+    "causal-bias": (True, True, 0.0, 64),
+    "full-bias": (False, True, 0.0, 64),
+    "causal-dropout": (True, False, 0.1, 64),
+    "full-dropout": (False, False, 0.1, 64),
+    "causal-d128": (True, False, 0.0, 128),
+}
+_bf16_results = {}
+
+
+def _bf16_case(name, monkeypatch):
+    """(kernel, reference) values of out, dq, dk, dv[, dbias] for one case,
+    computed once per case (every test of the case reads the same run)."""
+    if name in _bf16_results:
+        return _bf16_results[name]
+    causal, has_bias, dropout_p, D = _BF16_CASES[name]
+    B, H, L, bq = 1, 2, 256, 128
+    q, k, v, w = (_rand((B, H, L, D), i).astype(jnp.bfloat16)
+                  for i in range(4))
+    bias = 0.5 * _rand((1, 1, L, L), 4) if has_bias else None
+    keep = None
+    if dropout_p:
+        monkeypatch.setattr(fa, "_dropout_mask", _block_keep_mask)
+        keep = _full_keep_mask(B, H, L, L, bq, bq, dropout_p, seed=11)
+
+    def flash(q, k, v, bias):
+        return fa.flash_attention_bhld(
+            q, k, v, causal=causal, bias=bias, dropout_p=dropout_p, seed=11,
+            block_q=bq, block_k=bq).astype(jnp.float32)
+
+    def ref(q, k, v, bias):
+        return _reference_with_dropout(q, k, v, causal, bias, keep)
+
+    argnums = (0, 1, 2, 3) if has_bias else (0, 1, 2)
+    got, want = [], []
+    for f, res in ((flash, got), (ref, want)):
+        res.append(f(q, k, v, bias))
+        res.extend(jax.grad(
+            lambda *a: jnp.sum(f(*a) * w.astype(jnp.float32)),
+            argnums=argnums)(q, k, v, bias))
+    if dropout_p:
+        # deterministic per seed, and another seed drops other elements
+        again = flash(q, k, v, bias)
+        np.testing.assert_array_equal(np.asarray(again), np.asarray(got[0]))
+        other = fa.flash_attention_bhld(
+            q, k, v, causal=causal, dropout_p=dropout_p, seed=12,
+            block_q=bq, block_k=bq).astype(jnp.float32)
+        assert float(jnp.max(jnp.abs(other - got[0]))) > 0.0
+    names = ("out", "dq", "dk", "dv", "dbias")[:len(got)]
+    _bf16_results[name] = {
+        n: (np.asarray(g, np.float32), np.asarray(r, np.float32))
+        for n, g, r in zip(names, got, want)}
+    return _bf16_results[name]
+
+
+@pytest.mark.parametrize("case,which", [
+    (case, which) for case, (_, has_bias, _, _) in _BF16_CASES.items()
+    for which in ("out", "dq", "dk", "dv") + (("dbias",) if has_bias else ())])
+def test_flash_bf16_matches_reference(interpret_pallas, monkeypatch, case,
+                                      which):
+    got, want = _bf16_case(case, monkeypatch)[which]
+    assert got.shape == want.shape
+    assert np.isfinite(got).all()
+    # bf16 rounding of the inputs' products is exact; what is left is p/ds
+    # rounded to bf16 (2**-9 relative) and the bf16 result itself
+    assert np.max(np.abs(got - want)) <= 1e-2 * np.max(np.abs(want))
+
+
+def test_flash_mixed_dtype_forward(interpret_pallas):
+    """The serving preset: bf16 q/k against an f32 value cache stays on
+    f32 operands and matches the reference as an f32 kernel does."""
+    B, H, L, D = 1, 2, 256, 64
+    q, k = (_rand((B, H, L, D), i).astype(jnp.bfloat16) for i in range(2))
+    v = _rand((B, H, L, D), 2)
+    o = fa.flash_attention_bhld(q, k, v, causal=True, block_q=128, block_k=128)
+    ref = fa.reference_attention_bhld(q.astype(jnp.float32),
+                                      k.astype(jnp.float32), v, causal=True)
+    np.testing.assert_allclose(np.asarray(o, np.float32), np.asarray(ref),
+                               rtol=0, atol=2 ** -8 * float(jnp.max(jnp.abs(ref))))
+
+
+def _eqns(jaxpr, primitive):
+    """Every ``primitive`` equation under ``jaxpr``, branches and nested
+    calls included (a match is not searched further)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == primitive:
+            found.append(eqn)
+            continue
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (tuple, list)) else (val,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found.extend(_eqns(sub, primitive))
+    return found
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_bodies(dtypes):
+    """``{kernel name: its traced body}`` for inputs (q, k, v, do) of the
+    given dtypes."""
+    B, H, L, D = 1, 2, 256, 64
+    qd, kd, vd, dod = dtypes
+    q, k, v, do = (jnp.zeros((B, H, L, D), d) for d in (qd, kd, vd, dod))
+    lse = jnp.zeros((B, H, L), jnp.float32)
+    fwd = jax.make_jaxpr(lambda q, k, v: fa._flash_fwd_impl(
+        q, k, v, None, 0, True, 0.0, block_q=128, block_k=128))(q, k, v)
+    bwd = jax.make_jaxpr(lambda q, k, v, do: fa._flash_bwd_impl(
+        q, k, v, None, 0, q, lse, do, True, 0.0,
+        block_q=128, block_k=128))(q, k, v, do)
+    found = [eqn.params["jaxpr"] for traced in (fwd, bwd)
+             for eqn in _eqns(traced.jaxpr, "pallas_call")]
+    # one call in the forward wrapper; dq then dkv in the backward's
+    return dict(zip(_KERNEL_DOTS, found, strict=True))
+
+
+_BF16, _F32 = jnp.bfloat16, jnp.float32
+_KERNEL_DOTS = {"fwd_kernel": 2, "bwd_dq_kernel": 3, "bwd_dkv_kernel": 4}
+
+
+@pytest.mark.parametrize("kernel", list(_KERNEL_DOTS))
+@pytest.mark.parametrize("inputs,operand", [
+    ("bf16", _BF16), ("f32", _F32), ("bf16-qk-f32-v", _F32),
+    ("f32-do", _F32)])
+def test_kernel_dots_follow_input_dtype(kernel, inputs, operand):
+    dtypes = {"bf16": (_BF16,) * 4, "f32": (_F32,) * 4,
+              "bf16-qk-f32-v": (_BF16, _BF16, _F32, _BF16),
+              "f32-do": (_BF16, _BF16, _BF16, _F32)}[inputs]
+    if kernel == "fwd_kernel" and inputs == "f32-do":
+        dtypes = (_F32, _BF16, _BF16, _BF16)   # the forward reads no do
+    dots = _eqns(_kernel_bodies(dtypes)[kernel], "dot_general")
+    assert len(dots) == _KERNEL_DOTS[kernel]
+    for eqn in dots:
+        assert [v.aval.dtype for v in eqn.invars] == [operand, operand]
+        assert eqn.outvars[0].aval.dtype == jnp.float32
+        assert eqn.params["preferred_element_type"] == jnp.float32
+        if operand == _BF16:
+            # stated on the dot: one pass of the MXU, not the process-wide
+            # default (which Mosaic refuses for bf16 operands)
+            assert eqn.params["precision"] == (jax.lax.Precision.DEFAULT,) * 2
+        else:
+            # today's f32 dots: the process default, fp32 contract precision
+            assert eqn.params["precision"] == (jax.lax.Precision.HIGHEST,) * 2
+
+
+def test_kernel_file_reads_no_environment():
+    import inspect
+    src = inspect.getsource(fa)
+    assert "environ" not in src and "getenv" not in src
+    assert "import os" not in src
