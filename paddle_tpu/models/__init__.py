@@ -11,6 +11,7 @@ from . import ernie  # noqa: F401
 from . import generation  # noqa: F401
 from . import gpt  # noqa: F401
 from . import llama  # noqa: F401
+from . import ouro  # noqa: F401
 from . import ppyoloe  # noqa: F401
 from . import resnet  # noqa: F401
 from . import speculative  # noqa: F401
@@ -28,6 +29,7 @@ from .generation import (GenerationEngine, generate, init_cache,  # noqa: F401
 from .gpt import GPTConfig, GPTForCausalLM, GPTModel, gpt_1p3b, gpt_tiny  # noqa: F401
 from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel,  # noqa: F401
                     llama2_7b, llama_tiny)
+from .ouro import OuroConfig, OuroForCausalLM, OuroModel, ouro_tiny  # noqa: F401
 from .ppyoloe import PPYOLOE, ppyoloe_s, ppyoloe_tiny  # noqa: F401
 from .resnet import ResNet, resnet18, resnet34, resnet50, resnet101, resnet152  # noqa: F401
 from .speculative import SpeculativeEngine, build_draft_model  # noqa: F401

@@ -46,8 +46,8 @@ from ..nn.layer import buffer_state, functional_call, param_state
 from ..io.batching import bucket_for
 from ..observability import tracing as _tracing
 
-__all__ = ["GenerationEngine", "generate", "init_cache", "cache_nbytes",
-           "normalize_kv_dtype", "sample_logits", "filter_logits",
+__all__ = ["GenerationEngine", "generate", "init_cache", "cache_entries",
+           "cache_layout", "cache_nbytes", "normalize_kv_dtype", "sample_logits", "filter_logits",
            "sample_logits_rows", "per_row_keys", "slice_cache_rows",
            "scatter_cache_rows", "gather_cache_blocks",
            "scatter_cache_blocks", "cache_sharding_spec",
@@ -59,8 +59,33 @@ DEFAULT_PREFILL_BUCKETS = (32, 64, 128, 256, 512, 1024, 2048, 4096)
 
 
 # ----------------------------------------------------------------- cache
-def cache_sharding_spec(batch: int, n_kv_heads: int, mesh=None):
-    """GSPMD sharding for one cache leaf [B, S, Hkv, D]: batch over
+def cache_entries(spec: dict) -> int:
+    """How many ``(k, v)`` entries the cache of a model's ``cache_spec()``
+    holds: one per layer application that writes keys and values. That
+    is ``spec["cache_entries"]``; a spec without the key has one per
+    layer."""
+    return int(spec.get("cache_entries", spec["num_layers"]))
+
+
+def cache_layout(spec: dict):
+    """``(pairs, stack)`` of a model's ``cache_spec()``: the cache is a
+    tuple of ``pairs`` ``(k, v)`` pairs whose leaves are ``[B, *stack, S,
+    Hkv, D]``. ``spec["entry_stack"]`` of the :func:`cache_entries` share
+    a leaf pair on an axis after the batch's (a looped model's recurrent
+    steps; 1 and no axis when absent), so that a program can index them
+    by a traced step. Rows lead whatever the stack: a slot's cache is
+    ``leaf[slot]`` for every model."""
+    entries = cache_entries(spec)
+    stack = int(spec.get("entry_stack", 1))
+    if entries % stack:
+        raise ValueError(f"cache_entries {entries} is no multiple of "
+                         f"entry_stack {stack}")
+    return entries // stack, ((stack,) if stack > 1 else ())
+
+
+def cache_sharding_spec(batch: int, n_kv_heads: int, mesh=None, stack=0):
+    """GSPMD sharding for one cache leaf [B, S, Hkv, D] (``stack``
+    replicated axes of stacked entries after the batch's): batch over
     dp/sdp, kv heads over mp — matching the Column-parallel K/V
     projections, so tp decode reads/writes only local heads (no gathers).
     Axes that don't divide evenly stay replicated."""
@@ -77,7 +102,8 @@ def cache_sharding_spec(batch: int, n_kv_heads: int, mesh=None):
     head_axis = "mp" if (mp > 1 and n_kv_heads % mp == 0) else None
     if batch_axes is None and head_axis is None:
         return None
-    return sharding(batch_axes or None, None, head_axis, None, mesh=mesh)
+    return sharding(batch_axes or None, *(None,) * stack, None, head_axis,
+                    None, mesh=mesh)
 
 
 def normalize_kv_dtype(kv_dtype):
@@ -95,10 +121,11 @@ def normalize_kv_dtype(kv_dtype):
 
 def init_cache(model, batch: int, max_length: Optional[int] = None,
                dtype=None, kv_dtype=None):
-    """Preallocate the KV cache pytree for ``model``: a tuple (one entry
-    per layer) of ``(k, v)`` pairs, each ``[batch, max_length,
-    n_kv_heads, head_dim]`` zeros. Placed in its GSPMD layout when a mesh
-    is installed.
+    """Preallocate the KV cache pytree for ``model``: a tuple of ``(k,
+    v)`` pairs, each ``[batch, max_length, n_kv_heads, head_dim]`` zeros,
+    one pair per cache entry of ``model.cache_spec()`` (per layer, for a
+    model that applies each layer once; see :func:`cache_layout`).
+    Placed in its GSPMD layout when a mesh is installed.
 
     ``kv_dtype="int8"`` allocates the quantized layout instead: each
     ``k``/``v`` entry is a ``(int8 values, float32 scales [B, S, Hkv,
@@ -109,8 +136,10 @@ def init_cache(model, batch: int, max_length: Optional[int] = None,
     max_length = int(max_length or spec["max_length"])
     dtype = convert_dtype(dtype or spec["dtype"])
     kv_dtype = normalize_kv_dtype(kv_dtype)
-    shape = (batch, max_length, spec["num_kv_heads"], spec["head_dim"])
-    shd = cache_sharding_spec(batch, spec["num_kv_heads"])
+    pairs, stack = cache_layout(spec)
+    shape = (batch,) + stack + (max_length, spec["num_kv_heads"],
+                                spec["head_dim"])
+    shd = cache_sharding_spec(batch, spec["num_kv_heads"], stack=len(stack))
 
     def put(z):
         return jax.device_put(z, shd) if shd is not None else z
@@ -121,7 +150,7 @@ def init_cache(model, batch: int, max_length: Optional[int] = None,
                     put(jnp.zeros(shape[:-1] + (1,), jnp.float32)))
         return put(jnp.zeros(shape, dtype))
 
-    return tuple((leaf(), leaf()) for _ in range(spec["num_layers"]))
+    return tuple((leaf(), leaf()) for _ in range(pairs))
 
 
 def cache_nbytes(cache) -> int:
@@ -134,7 +163,8 @@ def cache_nbytes(cache) -> int:
 def _constrain_cache(cache, batch: int, n_kv_heads: int):
     """with_sharding_constraint on every cache leaf (inside jit), so the
     compiled steps keep the cache resident in its sharded layout."""
-    shd = cache_sharding_spec(batch, n_kv_heads)
+    stack = jax.tree.leaves(cache)[0].ndim - 4
+    shd = cache_sharding_spec(batch, n_kv_heads, stack=stack)
     if shd is None:
         return cache
     return jax.tree.map(
@@ -164,7 +194,8 @@ def scatter_cache_rows(cache, row_cache, index):
 
     def up(live, row):
         return jax.lax.dynamic_update_slice(
-            live, row.astype(live.dtype), (idx, zero, zero, zero))
+            live, row.astype(live.dtype),
+            (idx,) + (zero,) * (live.ndim - 1))
 
     return jax.tree.map(up, cache, row_cache)
 
@@ -185,13 +216,15 @@ def gather_cache_blocks(pool, block_indices, length: int):
     idx = jnp.asarray(block_indices, jnp.int32)
 
     def assemble(leaf):
-        n, bs = idx.shape[0], leaf.shape[1]
-        blocks = jnp.take(leaf, idx, axis=0)            # [n, bs, Hkv, D]
-        flat = blocks.reshape(1, n * bs, *leaf.shape[2:])
+        n, bs = idx.shape[0], leaf.shape[-3]
+        # [n, *stack, bs, Hkv, D] -> [*stack, n, bs, Hkv, D]
+        blocks = jnp.moveaxis(jnp.take(leaf, idx, axis=0), 0, -4)
+        flat = blocks.reshape(1, *leaf.shape[1:-3], n * bs, *leaf.shape[-2:])
         if n * bs < length:
-            pad = [(0, 0), (0, length - n * bs)] + [(0, 0)] * (flat.ndim - 2)
+            pad = [(0, 0)] * flat.ndim
+            pad[-3] = (0, length - n * bs)
             flat = jnp.pad(flat, pad)
-        return flat[:, :length]
+        return jax.lax.slice_in_dim(flat, 0, length, axis=flat.ndim - 3)
 
     return jax.tree.map(assemble, pool)
 
@@ -208,8 +241,10 @@ def scatter_cache_blocks(pool, row_cache, block_indices):
     idx = jnp.asarray(block_indices, jnp.int32)
 
     def store(leaf, row):
-        n, bs = idx.shape[0], leaf.shape[1]
-        blocks = row[0, :n * bs].reshape(n, bs, *leaf.shape[2:])
+        n, bs = idx.shape[0], leaf.shape[-3]
+        blocks = jax.lax.slice_in_dim(row[0], 0, n * bs, axis=row.ndim - 4)
+        blocks = jnp.moveaxis(
+            blocks.reshape(*leaf.shape[1:-3], n, bs, *leaf.shape[-2:]), -4, 0)
         return leaf.at[idx].set(blocks.astype(leaf.dtype))
 
     return jax.tree.map(store, pool, row_cache)

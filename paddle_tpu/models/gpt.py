@@ -254,6 +254,7 @@ class GPTForCausalLM(Layer):
     def cache_spec(self) -> dict:
         """Static KV-cache geometry for ``models.generation.init_cache``."""
         return {"num_layers": self.cfg.num_layers,
+                "cache_entries": self.cfg.num_layers,
                 "num_kv_heads": self.cfg.num_heads,
                 "head_dim": self.cfg.hidden_size // self.cfg.num_heads,
                 "max_length": self.cfg.max_position_embeddings,

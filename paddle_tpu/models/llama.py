@@ -9,10 +9,12 @@ with ZeRO (``distributed/shard.py`` stage 3), sequence parallel, flash
 attention, recompute, and the chunked LM loss — the exact knobs the
 GPT flagship uses.
 
-TPU-first notes: rotary embeddings are precomputed once per config and
-closed over as constants (XLA folds them); GQA repeats K/V heads to the
-query head count before attention so the Pallas flash kernel (equal-head
-layout) serves grouped queries unchanged.
+TPU-first notes: the attention layer computes its rotary angles in the
+program from the positions it is given (a table for every position a
+config allows would ride in each program as a constant: 2 x 33.5 MB at
+65536 positions of head 128); GQA repeats K/V heads to the query head
+count before attention so the Pallas flash kernel (equal-head layout)
+serves grouped queries unchanged.
 """
 from __future__ import annotations
 
@@ -22,6 +24,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..distributed.parallel.mp_layers import (
     ColumnParallelLinear,
@@ -37,7 +40,8 @@ from ..nn.layers.norm import RMSNorm
 from .lm_utils import (attend_with_cache, causal_attention,
                        constrain_seq as _constrain_seq, repeat_kv)
 
-__all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM", "llama_tiny",
+__all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM", "LlamaAttention",
+           "LlamaMLP", "rotary_embed", "llama_tiny",
            "llama2_7b", "llama_loss_fn", "llama_flops_per_token"]
 
 
@@ -88,52 +92,25 @@ def llama2_7b(**overrides) -> "LlamaConfig":
 
 
 # ------------------------------------------------------------------ rotary
-_ROPE_CACHE = {}
-
-
-def _rope_tables(head_dim: int, max_len: int, theta: float):
-    """Cos/sin tables, cached per (head_dim, max_len, theta): every layer
-    of every model instance shares ONE pair instead of each holding a
-    buffer copy (32 layers of llama2_7b would otherwise pin ~134 MB of
-    identical constants). As closure constants XLA folds them."""
-    key = (head_dim, max_len, float(theta))
-    if key not in _ROPE_CACHE:
-        # numpy on purpose: the first call may come from INSIDE a jit/remat
-        # trace, and caching jnp values there would cache tracers (leak)
-        import numpy as np
-
-        inv_freq = 1.0 / (theta ** (np.arange(0, head_dim, 2,
-                                              dtype=np.float32) / head_dim))
-        t = np.arange(max_len, dtype=np.float32)
-        freqs = np.outer(t, inv_freq)                  # [L, D/2]
-        emb = np.concatenate([freqs, freqs], axis=-1)  # [L, D]
-        _ROPE_CACHE[key] = (np.cos(emb), np.sin(emb))
-    return _ROPE_CACHE[key]
-
-
 def _rotate_half(x):
     half = x.shape[-1] // 2
     return jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
 
 
-def apply_rotary(q, k, cos, sin, position_offset=0):
+def rotary_embed(q, k, theta: float, position_offset=0):
     """Rotary position embedding on [B, L, H, D] (llama rotate-half
     convention). ``position_offset`` may be a scalar or a per-row ``[B]``
     vector (continuous-batching decode: each slot rotates at its own
-    position)."""
-    L = q.shape[1]
-    if getattr(position_offset, "ndim", 0) == 1:
-        idx = (jnp.asarray(position_offset, jnp.int32)[:, None]
-               + jnp.arange(L, dtype=jnp.int32)[None, :])
-        c = jnp.take(jnp.asarray(cos), idx, axis=0)  # [B, L, D]
-        s = jnp.take(jnp.asarray(sin), idx, axis=0)
-        c = c[:, :, None, :].astype(q.dtype)
-        s = s[:, :, None, :].astype(q.dtype)
-    else:
-        c = jax.lax.dynamic_slice_in_dim(cos, position_offset, L, axis=0)
-        s = jax.lax.dynamic_slice_in_dim(sin, position_offset, L, axis=0)
-        c = c[None, :, None, :].astype(q.dtype)
-        s = s[None, :, None, :].astype(q.dtype)
+    position), traced or not. The angles of the ``L`` positions are
+    computed here, in float32, so a program carries ``D/2`` constants
+    whatever the model's position limit."""
+    L, D = q.shape[1], q.shape[-1]
+    inv_freq = 1.0 / (theta ** (np.arange(0, D, 2, dtype=np.float32) / D))
+    pos = (jnp.asarray(position_offset, jnp.int32).reshape(-1, 1)
+           + jnp.arange(L, dtype=jnp.int32)[None, :])        # [1|B, L]
+    freqs = pos[..., None].astype(jnp.float32) * inv_freq    # [1|B, L, D/2]
+    emb = jnp.concatenate([freqs, freqs], axis=-1)[:, :, None, :]
+    c, s = jnp.cos(emb).astype(q.dtype), jnp.sin(emb).astype(q.dtype)
     return q * c + _rotate_half(q) * s, k * c + _rotate_half(k) * s
 
 
@@ -165,21 +142,20 @@ class LlamaAttention(Layer):
             cfg.hidden_size, cfg.hidden_size, weight_attr=out_init,
             has_bias=False, input_is_parallel=True)
 
-    def forward(self, x, cache=None, position_offset=0):
+    @jax.named_scope("attention")
+    def forward(self, x, cache=None, position_offset=0, cache_entry=None):
         B, L, _ = x.shape
         cfg = self.cfg
         q = self.q_proj(x).reshape(B, L, cfg.num_heads, self.head_dim)
         k = self.k_proj(x).reshape(B, L, cfg.num_kv_heads, self.head_dim)
         v = self.v_proj(x).reshape(B, L, cfg.num_kv_heads, self.head_dim)
-        cos, sin = _rope_tables(self.head_dim, cfg.max_position_embeddings,
-                                cfg.rope_theta)
-        # RoPE indexes its tables at position_offset (traced for cached
-        # decode steps), so the cache stores POST-rotation keys
-        q, k = apply_rotary(q, k, cos, sin, position_offset)
+        # RoPE turns by position_offset (traced for cached decode steps),
+        # so the cache stores POST-rotation keys
+        q, k = rotary_embed(q, k, cfg.rope_theta, position_offset)
         if cache is not None:
             out, cache = attend_with_cache(
                 q, k, v, cache, position_offset,
-                use_flash=cfg.use_flash_attention)
+                use_flash=cfg.use_flash_attention, entry=cache_entry)
             return self.o_proj(out.reshape(B, L, cfg.hidden_size)), cache
         groups = cfg.num_heads // cfg.num_kv_heads
         k, v = _repeat_kv(k, groups), _repeat_kv(v, groups)
@@ -207,6 +183,7 @@ class LlamaMLP(Layer):
             cfg.intermediate_size, cfg.hidden_size, weight_attr=out_init,
             has_bias=False, input_is_parallel=True)
 
+    @jax.named_scope("mlp")
     def forward(self, x):
         return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
 
@@ -262,10 +239,12 @@ class LlamaForCausalLM(Layer):
     the loss directly when labels are given, chunk-fused when
     ``cfg.loss_chunk > 0``)."""
 
+    backbone_cls = LlamaModel   # a family on these blocks sets its own
+
     def __init__(self, cfg: LlamaConfig):
         super().__init__()
         self.cfg = cfg
-        self.model = LlamaModel(cfg)
+        self.model = self.backbone_cls(cfg)
         if not cfg.tie_word_embeddings:
             self.lm_head = ColumnParallelLinear(
                 cfg.hidden_size, cfg.vocab_size,
@@ -273,6 +252,7 @@ class LlamaForCausalLM(Layer):
                 has_bias=False, gather_output=False)
         self.parallel_ce = ParallelCrossEntropy()
 
+    @jax.named_scope("lm_head")
     def _logits(self, h):
         if self.cfg.tie_word_embeddings:
             return parallel_matmul(h, self.model.embed_tokens.weight,
@@ -283,6 +263,7 @@ class LlamaForCausalLM(Layer):
         """Static KV-cache geometry for ``models.generation.init_cache``
         (GQA: the cache stores ``num_kv_heads``, not ``num_heads``)."""
         return {"num_layers": self.cfg.num_layers,
+                "cache_entries": self.cfg.num_layers,
                 "num_kv_heads": self.cfg.num_kv_heads,
                 "head_dim": self.cfg.hidden_size // self.cfg.num_heads,
                 "max_length": self.cfg.max_position_embeddings,

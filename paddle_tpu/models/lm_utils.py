@@ -20,7 +20,7 @@ from ..kernels import flash_attention as fa
 from ..nn import functional as F
 from ..nn.layer import Layer
 
-__all__ = ["chunked_lm_loss", "DecoderBlockList", "constrain_seq",
+__all__ = ["chunked_lm_loss", "DecoderBlockList", "constrain_seq", "CacheRow",
            "causal_attention", "repeat_kv", "update_kv_cache",
            "cached_attention", "attend_with_cache", "cached_lm_forward"]
 
@@ -74,24 +74,59 @@ def repeat_kv(x, groups: int):
     return jnp.repeat(x, groups, axis=2)
 
 
-def _write_window(buf, new, pos):
+@jax.tree_util.register_pytree_node_class
+class CacheRow:
+    """Row ``row`` (a traced index) of a live cache leaf ``buf`` ``[B, S,
+    Hkv, D]``, standing where a batch-1 cache leaf would: a prefill
+    given these writes its keys and values straight into the live batch
+    (:func:`update_kv_cache`), so that an admission builds no row of its
+    own beside it (1.6 GB at 1.5 MiB a token and 1024 positions). Write
+    only: the prefill shape attends over its own block."""
+
+    def __init__(self, buf, row):
+        self.buf, self.row = buf, row
+
+    def tree_flatten(self):
+        return (self.buf, self.row), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
+
+
+def _write_window(buf, new, pos, entry=None):
     """Write ``new`` into ``buf`` along the length axis at ``pos`` —
-    scalar offset (one dynamic_update_slice) or per-row [B] vector (the
-    vmapped windowed write)."""
+    scalar offset (one dynamic_update_slice, into that row alone where
+    ``buf`` is a :class:`CacheRow`) or per-row [B] vector (the vmapped
+    windowed write). With ``entry`` (a traced index) each row of ``buf``
+    stacks several cache entries, ``[B, E, S, ...]``, and the write lands
+    in that one."""
+    if isinstance(buf, CacheRow):
+        return CacheRow(_write(buf.buf, new, pos, entry, buf.row), buf.row)
+    return _write(buf, new, pos, entry, jnp.zeros((), jnp.int32))
+
+
+def _write(buf, new, pos, entry, row):
     zero = jnp.zeros((), jnp.int32)
+    stack = () if entry is None else (jnp.asarray(entry, jnp.int32),)
+    new = new.astype(buf.dtype)
+    if entry is not None:
+        new = new[:, None]
     if pos.ndim == 1:
         def write(c, n, p):
             return jax.lax.dynamic_update_slice(
-                c, n.astype(c.dtype), (p,) + (zero,) * (c.ndim - 1))
+                c, n, stack + (p,) + (zero,) * (c.ndim - 1 - len(stack)))
 
         return jax.vmap(write)(buf, new, pos)
-    start = (zero, pos) + (zero,) * (buf.ndim - 2)
-    return jax.lax.dynamic_update_slice(buf, new.astype(buf.dtype), start)
+    start = (row,) + stack + (pos,) + (zero,) * (buf.ndim - 2 - len(stack))
+    return jax.lax.dynamic_update_slice(buf, new, start)
 
 
-def update_kv_cache(cache, k_new, v_new, position_offset):
+def update_kv_cache(cache, k_new, v_new, position_offset, entry=None):
     """Write ``k_new``/``v_new`` [B, L, Hkv, D] into the preallocated
-    ``(k, v)`` cache pair at ``position_offset`` along the length axis.
+    ``(k, v)`` cache pair at ``position_offset`` along the length axis
+    (of entry ``entry`` where the pair's leaves stack several entries,
+    ``[B, E, S, Hkv, D]``: a looped model's recurrent steps).
 
     ``position_offset`` may be a traced scalar (the single-token decode
     step passes the running position as a device int32, so ONE compiled
@@ -112,17 +147,18 @@ def update_kv_cache(cache, k_new, v_new, position_offset):
     if is_quantized_kv(k_cache):
         kq, ks = kv_quantize(k_new)
         vq, vs = kv_quantize(v_new)
-        return ((_write_window(k_cache[0], kq, pos),
-                 _write_window(k_cache[1], ks, pos)),
-                (_write_window(v_cache[0], vq, pos),
-                 _write_window(v_cache[1], vs, pos)))
-    return (_write_window(k_cache, k_new, pos),
-            _write_window(v_cache, v_new, pos))
+        return ((_write_window(k_cache[0], kq, pos, entry),
+                 _write_window(k_cache[1], ks, pos, entry)),
+                (_write_window(v_cache[0], vq, pos, entry),
+                 _write_window(v_cache[1], vs, pos, entry)))
+    return (_write_window(k_cache, k_new, pos, entry),
+            _write_window(v_cache, v_new, pos, entry))
 
 
-def cached_attention(q, k_cache, v_cache, position_offset):
+def cached_attention(q, k_cache, v_cache, position_offset, entry=None):
     """Dot-product attention of ``q`` [B, L, H, D] against the FULL cache
-    [B, S, Hkv, D] with a position mask: query at absolute position
+    [B, S, Hkv, D] (entry ``entry`` of ``[B, E, S, Hkv, D]`` leaves where
+    given) with a position mask: query at absolute position
     ``position_offset + i`` sees keys at positions ``<= position_offset + i``
     only, so stale/unwritten cache slots beyond the current position never
     leak in. ``position_offset`` may be a scalar or a per-row ``[B]``
@@ -133,6 +169,11 @@ def cached_attention(q, k_cache, v_cache, position_offset):
     int8 in HBM and only this program's working set pays the upcast."""
     from ..quantization import is_quantized_kv, kv_dequantize
 
+    if entry is not None:
+        k_cache, v_cache = jax.tree.map(
+            lambda x: jax.lax.dynamic_index_in_dim(x, entry, 1,
+                                                   keepdims=False),
+            (k_cache, v_cache))
     # tpu-lint: disable=R2(is_quantized_kv reads pytree STRUCTURE — tuple pair vs bare array — fixed at trace time, one program per cache layout)
     if is_quantized_kv(k_cache):
         k_cache = kv_dequantize(*k_cache, dtype=q.dtype)
@@ -156,7 +197,7 @@ def cached_attention(q, k_cache, v_cache, position_offset):
 
 
 def attend_with_cache(q, k_new, v_new, cache, position_offset,
-                      use_flash=True):
+                      use_flash=True, entry=None):
     """The cached-decode attention dispatch shared by GPT and Llama.
 
     Always writes ``k_new``/``v_new`` into the cache. The PREFILL shape
@@ -164,9 +205,10 @@ def attend_with_cache(q, k_new, v_new, cache, position_offset,
     :func:`causal_attention` — flash-eligible, no O(S) mask work; every
     other shape (single-token decode, chunked continuation) runs
     :func:`cached_attention` against the full cache with the position
-    mask. Returns ``(out, (k_cache, v_cache))``.
+    mask. ``entry`` selects one of the entries a looped model stacks in
+    each row of its leaves. Returns ``(out, (k_cache, v_cache))``.
     """
-    cache = update_kv_cache(cache, k_new, v_new, position_offset)
+    cache = update_kv_cache(cache, k_new, v_new, position_offset, entry)
     is_prefill = (q.shape[1] > 1 and isinstance(position_offset, int)
                   and position_offset == 0)
     if is_prefill:
@@ -178,7 +220,8 @@ def attend_with_cache(q, k_new, v_new, cache, position_offset,
                                repeat_kv(v_new, groups), dropout_p=0.0,
                                training=False, use_flash=use_flash)
     else:
-        out = cached_attention(q, cache[0], cache[1], position_offset)
+        out = cached_attention(q, cache[0], cache[1], position_offset,
+                               entry)
     return out, cache
 
 
@@ -204,7 +247,8 @@ class DecoderBlockList(Layer):
     ``recompute_policy``; ``block_cls(cfg)`` builds one block. With
     ``caches`` (a per-layer tuple of ``(k, v)`` pairs) each block runs its
     cached-decode path and the updated caches ride back alongside the
-    activations."""
+    activations; ``cache_entry`` goes to blocks whose pair stacks several
+    entries (a looped model's step)."""
 
     def __init__(self, cfg, block_cls):
         super().__init__()
@@ -212,7 +256,7 @@ class DecoderBlockList(Layer):
         for i in range(cfg.num_layers):
             self.add_sublayer(str(i), block_cls(cfg))
 
-    def forward(self, x, caches=None, position_offset=0):
+    def forward(self, x, caches=None, position_offset=0, cache_entry=None):
         if caches is None:
             for blk in self._sub_layers.values():
                 fn = (recompute_wrap(blk, policy=self.cfg.recompute_policy)
@@ -220,8 +264,10 @@ class DecoderBlockList(Layer):
                 x = fn(x)
             return x
         new_caches = []
+        kw = {} if cache_entry is None else {"cache_entry": cache_entry}
         for blk, cache in zip(self._sub_layers.values(), caches):
-            x, cache = blk(x, cache=cache, position_offset=position_offset)
+            x, cache = blk(x, cache=cache, position_offset=position_offset,
+                           **kw)
             new_caches.append(cache)
         return x, tuple(new_caches)
 

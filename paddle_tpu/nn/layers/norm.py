@@ -162,10 +162,11 @@ class RMSNorm(Layer):
     """Llama-family norm; absent in the reference (see SURVEY §2.3 note on
     missing modern blocks) but required by BASELINE.md's Llama-2 target."""
 
-    def __init__(self, hidden_size, epsilon=1e-6):
+    def __init__(self, hidden_size, epsilon=1e-6, weight_attr=None):
         super().__init__()
         self.epsilon = epsilon
-        self.weight = self.create_parameter((hidden_size,), default_initializer=Constant(1.0))
+        self.weight = self.create_parameter((hidden_size,), attr=weight_attr,
+                                            default_initializer=Constant(1.0))
 
     def forward(self, x):
         return F.rms_norm(x, self.weight, self.epsilon)

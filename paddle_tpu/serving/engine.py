@@ -8,11 +8,12 @@ burn decode FLOPs as eos filler. This engine keeps the SAME fixed cache
 shape ``[B, max_length, n_kv_heads, head_dim]`` but treats the batch
 dimension as ``B`` independent *slots*:
 
-- **admit** runs the existing bucketed prefill at batch 1 against a fresh
-  zero single-slot cache and — inside the same compiled program —
-  scatters the resulting cache rows into the live batch at a *traced*
-  slot index (``generation.scatter_cache_rows``) and samples the
-  request's first token. One program per prefill bucket, for every slot.
+- **admit** runs the existing bucketed prefill at batch 1 and — inside
+  the same compiled program — writes its keys and values into the live
+  batch's row at a *traced* slot index (``lm_utils.CacheRow``) and samples
+  the request's first token. One program per prefill bucket, for every
+  slot. (With a prefix pool the slot's row is assembled from pool blocks
+  and scattered in, ``generation.scatter_cache_rows``.)
 - **step** advances ALL slots one token with a *vector* of per-slot
   positions (the ``[B]`` ``position_offset`` path through
   ``lm_utils.cached_attention`` / ``update_kv_cache`` and the models'
@@ -42,13 +43,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..framework import compile_cache
-from ..framework.dtype import convert_dtype
 from ..io.batching import bucket_for
 from ..models.generation import (DEFAULT_PREFILL_BUCKETS, _constrain_cache,
-                                 cache_nbytes, gather_cache_blocks,
+                                 cache_entries, cache_nbytes,
+                                 gather_cache_blocks,
                                  init_cache, normalize_kv_dtype,
                                  per_row_keys, sample_logits_rows,
                                  scatter_cache_blocks, scatter_cache_rows)
+from ..models.lm_utils import CacheRow
 from ..lora import adapter_rows as _adapter_rows_ctx
 from ..lora.store import AdapterStore, normalize_adapter_id
 from ..nn.layer import buffer_state, functional_call, param_state
@@ -250,6 +252,10 @@ class ContinuousBatchingEngine:
         self._temp = np.ones(B, np.float32)
         self._top_p = np.ones(B, np.float32)
         self._greedy = np.ones(B, bool)
+        #: what the last decode step had to serve: the slots live in it
+        #: and the cached positions their queries read (each slot's
+        #: position, the new token's own key included)
+        self.step_load = (0, 0)
         self.requests: List[Optional[object]] = [None] * B
 
     def sync_weights(self) -> None:
@@ -288,20 +294,6 @@ class ContinuousBatchingEngine:
 
         return guard()
 
-    def _slot_zero_cache(self):
-        shape = (1, self.max_length, self.spec["num_kv_heads"],
-                 self.spec["head_dim"])
-        dtype = convert_dtype(self.spec["dtype"])
-
-        def entry():
-            if self.kv_dtype == "int8":
-                return (jnp.zeros(shape, jnp.int8),
-                        jnp.zeros(shape[:-1] + (1,), jnp.float32))
-            return jnp.zeros(shape, dtype)
-
-        return tuple((entry(), entry())
-                     for _ in range(self.spec["num_layers"]))
-
     def cache_bytes_per_slot(self) -> int:
         """HBM bytes one slot's KV occupies in the live batch — the
         number the ``kv_dtype="int8"`` halving claim is asserted on."""
@@ -309,22 +301,26 @@ class ContinuousBatchingEngine:
 
     def _prefill_fn(self, params, buffers, live_cache, ids, slot,
                     last_index, key, eos_id, temperature, top_p, greedy):
-        """Bucketed batch-1 prefill FUSED with the slot scatter: the fresh
-        single-slot cache never exists outside this program, so admission
-        costs one compile per bucket — not per bucket per slot, and no
-        separate scatter program."""
-        slot_cache = self._slot_zero_cache()
+        """Bucketed batch-1 prefill that writes its keys and values
+        straight into row ``slot`` of the live batch (``CacheRow``): no
+        single-slot cache exists, inside this program or out of it, so
+        admission costs one compile per bucket — not per bucket per slot,
+        and no separate scatter program. Positions past the bucket keep
+        what the slot's last request left there, behind the position
+        mask like the rest of a reused slot."""
+        row = jax.tree.map(lambda x: CacheRow(x, slot), live_cache)
         with jax.named_scope("prefill"):
-            (logits, slot_cache), _ = functional_call(
-                self.model, params, buffers, ids, cache=slot_cache,
+            (logits, row), _ = functional_call(
+                self.model, params, buffers, ids, cache=row,
                 position_offset=0, gather_last=last_index)
+        live_cache = jax.tree.map(lambda r: r.buf, row,
+                                  is_leaf=lambda r: isinstance(r, CacheRow))
         logits = logits[:, 0, :]
         rows = per_row_keys(key, 1)
         next_tok = sample_logits_rows(
             logits, rows, temperature, self.top_k, top_p,
             use_top_p=self.allow_top_p,
             greedy_mask=jnp.asarray(greedy).reshape(1))
-        live_cache = scatter_cache_rows(live_cache, slot_cache, slot)
         live_cache = _constrain_cache(live_cache, self.slots,
                                       self.spec["num_kv_heads"])
         done = next_tok[0] == eos_id
@@ -664,6 +660,7 @@ class ContinuousBatchingEngine:
         toks = np.array(tok_h)
         dns = np.array(done_h)
         events: List[SlotEvent] = []
+        keys_read = 0
         for i, req in enumerate(self.requests):
             if req is None:
                 continue
@@ -673,6 +670,8 @@ class ContinuousBatchingEngine:
                 continue
             events.append(SlotEvent(i, int(toks[i]), bool(dns[i])))
             self._positions[i] += 1
+            keys_read += int(self._positions[i])
+        self.step_load = (len(events), keys_read)
         self._tokens = toks
         self._done = dns | ~np.asarray(
             [r is not None for r in self.requests])
@@ -693,6 +692,12 @@ class ContinuousBatchingEngine:
 
     def cache_stats(self) -> dict:
         """Compile/call counters of the two serving programs — steady
-        state must hold at ``#buckets_used`` prefill + 1 decode."""
+        state must hold at ``#buckets_used`` prefill + 1 decode — and the
+        live cache's geometry: its entries (one per layer application
+        that writes keys and values) and the bytes a token holds in all
+        of them."""
         return {"prefill": compile_cache.cache_stats(self._cc_prefill),
-                "decode": compile_cache.cache_stats(self._cc_decode)}
+                "decode": compile_cache.cache_stats(self._cc_decode),
+                "cache_entries": cache_entries(self.spec),
+                "cache_bytes_per_token":
+                    self.cache_bytes_per_slot() // self.max_length}

@@ -234,8 +234,22 @@ class ServingMetrics:
             # loop, and no lock on its side: a reset swaps the table, so
             # a booking that races it lands in the old one
             self._loop = {p: [0, 0, 0] for p in LOOP_PHASES}
+            # [steps, live slots, cached positions read] summed over
+            # decode steps; the same writer, and no lock for the same
+            # reason
+            self._decode = [0, 0, 0]
 
     # ------------------------------------------------------------ events
+    def decode_step(self, live_slots: int, live_positions: int) -> None:
+        """Book one decode step's load (``engine.step_load``): the slots
+        live in it and the cached positions their queries read. What a
+        step must move from memory follows from these and the model's
+        shapes, whatever program ran it."""
+        d = self._decode
+        d[0] += 1
+        d[1] += live_slots
+        d[2] += live_positions
+
     def loop_phase(self, phase: str, wall_ns: int, cpu_ns: int) -> None:
         """Book one ended instance of a serve-loop phase (the loop
         thread's :class:`LoopClock` calls this at every boundary). A
@@ -355,6 +369,8 @@ class ServingMetrics:
                 "loop": {p: {"count": c[0], "wall_s": c[1] * 1e-9,
                              "cpu_s": c[2] * 1e-9}
                          for p, c in self._loop.items()},
+                "decode": dict(zip(("steps", "live_slot_steps",
+                                    "live_position_steps"), self._decode)),
                 **({"compile_stats": compile_stats}
                    if compile_stats is not None else {}),
                 **({"prefix_cache": prefix_cache}

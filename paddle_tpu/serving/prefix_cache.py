@@ -43,6 +43,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..models.generation import cache_entries, cache_layout, normalize_kv_dtype
+
 __all__ = ["BlockPool", "PrefixHit", "StorePlan", "chain_digests",
            "KV_WIRE_VERSION", "DEFAULT_MIGRATE_CHUNK_BYTES",
            "last_migrate_stats"]
@@ -157,7 +159,6 @@ class BlockPool:
                  max_length: Optional[int] = None,
                  max_blocks: int = 4096, kv_dtype=None):
         from ..framework.dtype import convert_dtype
-        from ..models.generation import normalize_kv_dtype
 
         spec = model.cache_spec()
         self.spec = spec
@@ -180,7 +181,9 @@ class BlockPool:
             per_pos_head = spec["head_dim"] + 4
         else:
             per_pos_head = spec["head_dim"] * itemsize
-        self.block_bytes = (2 * spec["num_layers"] * self.block_tokens
+        # one block holds its tokens' keys and values in EVERY cache
+        # entry (a looped model has more entries than layers)
+        self.block_bytes = (2 * cache_entries(spec) * self.block_tokens
                             * spec["num_kv_heads"] * per_pos_head)
         budget_blocks = max(1, int(max_bytes) // max(self.block_bytes, 1))
         # +1: row 0 is the reserved dump block, never allocated
@@ -211,8 +214,10 @@ class BlockPool:
     def _alloc_tensors(self):
         import jax.numpy as jnp
 
-        shape = (self.num_blocks, self.block_tokens,
-                 self.spec["num_kv_heads"], self.spec["head_dim"])
+        pairs, stack = cache_layout(self.spec)
+        shape = (self.num_blocks,) + stack + (
+            self.block_tokens, self.spec["num_kv_heads"],
+            self.spec["head_dim"])
 
         def entry():
             if self.kv_dtype == "int8":
@@ -220,19 +225,18 @@ class BlockPool:
                         jnp.zeros(shape[:-1] + (1,), jnp.float32))
             return jnp.zeros(shape, self._dtype)
 
-        return tuple((entry(), entry())
-                     for _ in range(self.spec["num_layers"]))
+        return tuple((entry(), entry()) for _ in range(pairs))
 
     def compatible_with(self, spec: dict, max_length: int,
                         kv_dtype=None) -> None:
         """Raise when this pool cannot serve an engine's geometry."""
-        from ..models.generation import normalize_kv_dtype
-
-        for k in ("num_layers", "num_kv_heads", "head_dim"):
-            if self.spec[k] != spec[k]:
+        mine = dict(self.spec, cache_layout=cache_layout(self.spec))
+        theirs = dict(spec, cache_layout=cache_layout(spec))
+        for k in ("cache_layout", "num_kv_heads", "head_dim"):
+            if mine[k] != theirs[k]:
                 raise ValueError(
-                    f"prefix cache built for {k}={self.spec[k]} cannot "
-                    f"serve a model with {k}={spec[k]}")
+                    f"prefix cache built for {k}={mine[k]} cannot "
+                    f"serve a model with {k}={theirs[k]}")
         if normalize_kv_dtype(kv_dtype) != self.kv_dtype:
             # gather_cache_blocks copies pool leaves into the slot cache
             # verbatim — a dtype mismatch would either fail at trace time
@@ -480,8 +484,8 @@ class BlockPool:
             rows = hit.read_idx[:n].astype(np.int32)
             chunk_rows = self._chunk_rows(max_chunk_bytes)
             # [layer][kv] -> list of host chunks, concatenated at the end
-            n_layers = self.spec["num_layers"]
-            parts = [[[], []] for _ in range(n_layers)]
+            n_pairs = len(self.tensors)
+            parts = [[[], []] for _ in range(n_pairs)]
             chunks = 0
             with self.device_lock:
                 tensors = self.tensors
@@ -516,7 +520,7 @@ class BlockPool:
                 return np.concatenate(chunk_list)
 
             leaves = [(cat(parts[li][0]), cat(parts[li][1]))
-                      for li in range(n_layers)]
+                      for li in range(n_pairs)]
 
             def nbytes(leaf):
                 return (sum(x.nbytes for x in leaf)
@@ -531,7 +535,8 @@ class BlockPool:
                 "version": KV_WIRE_VERSION,
                 "block_tokens": self.block_tokens,
                 "kv_dtype": self.kv_dtype or "full",
-                "num_layers": n_layers,
+                "num_layers": self.spec["num_layers"],
+                "cache_entries": cache_entries(self.spec),
                 "num_kv_heads": self.spec["num_kv_heads"],
                 "head_dim": self.spec["head_dim"],
                 "salt": salt.hex() if salt else "",
@@ -562,6 +567,7 @@ class BlockPool:
         for k, want in (("block_tokens", self.block_tokens),
                         ("kv_dtype", self.kv_dtype or "full"),
                         ("num_layers", self.spec["num_layers"]),
+                        ("cache_entries", cache_entries(self.spec)),
                         ("num_kv_heads", self.spec["num_kv_heads"]),
                         ("head_dim", self.spec["head_dim"])):
             if payload.get(k) != want:
@@ -628,8 +634,7 @@ class BlockPool:
                             out[j] = src[bi]
                         return out
 
-                    for li in range(self.spec["num_layers"]):
-                        k, v = tensors[li]
+                    for li, (k, v) in enumerate(tensors):
                         new_kv = []
                         for t, leaf in zip((k, v), payload["leaves"][li]):
                             if isinstance(t, tuple):

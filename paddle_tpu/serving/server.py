@@ -529,6 +529,7 @@ class InferenceServer:
                           "live": live})
         events = self.engine.step()     # leaves the clock in "emit"
         self.metrics.inc("decode_steps")
+        self.metrics.decode_step(*self.engine.step_load)
         per_adapter = self.engine.store is not None
         now = time.monotonic()
         for ev in events:

@@ -272,19 +272,18 @@ def test_llama_forward_shapes_and_gqa():
 
 
 def test_llama_rope_properties():
-    from paddle_tpu.models.llama import _rope_tables, apply_rotary
+    from paddle_tpu.models.llama import rotary_embed
 
-    cos, sin = _rope_tables(16, 64, 10000.0)
     q = jnp.asarray(np.random.default_rng(1).normal(size=(1, 8, 2, 16)),
                     jnp.float32)
     k = q + 0.0
-    qr, kr = apply_rotary(q, k, cos, sin)
+    qr, kr = rotary_embed(q, k, 10000.0)
     # rotation preserves per-head norms
     np.testing.assert_allclose(np.linalg.norm(np.asarray(q), axis=-1),
                                np.linalg.norm(np.asarray(qr), axis=-1),
                                rtol=1e-5)
     # relative-position property: dot(q_i, k_j) depends only on i - j
-    qr2, kr2 = apply_rotary(q, k, cos, sin, position_offset=7)
+    qr2, kr2 = rotary_embed(q, k, 10000.0, position_offset=7)
     d1 = np.einsum("blhd,bmhd->bhlm", np.asarray(qr), np.asarray(kr))
     d2 = np.einsum("blhd,bmhd->bhlm", np.asarray(qr2), np.asarray(kr2))
     np.testing.assert_allclose(d1, d2, rtol=1e-4, atol=1e-4)
