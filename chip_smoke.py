@@ -14,10 +14,13 @@ and the example use (hidden 1024, 16 heads, vocab 50304, 24 layers):
   dropout call), and the same widths at seq 4096 through ``TrainStep``
   (depth cut to 4 layers for time), whose lowered program must hold the
   Mosaic custom calls;
-- **serve**: ``InferenceServer(slots=8)`` with the default prefill
+- **serve**: the per-slot cache write's kernels against the scatter,
+  element for element, at the row geometries of the benchmark's three
+  serve cells; then ``InferenceServer(slots=8)`` with the default prefill
   buckets, warmed up, then 32 concurrent mixed-length requests, greedy
-  and sampled, with zero compiles allowed and one greedy stream compared
-  with ``model.generate()``;
+  and sampled, with zero compiles allowed, the decode program's cache
+  write on the kernel, and one greedy stream compared with
+  ``model.generate()``;
 - **four_chip**: the train model through ``DistributedTrainStep`` on
   dp=2 x mp=2 and on sdp=4 with ZeRO-2, when the host has four chips.
 
@@ -295,11 +298,56 @@ def _report_divergence(model, prompt, served, solo) -> str:
             f"{logits[top[0]] - logits[top[1]]:.3e}")
 
 
+#: the serve cells' cache rows (gpt3-medium 16 x 64, gpt3-xl 16 x 128,
+#: ouro-2.6b 16 x 128 in leaves of stacked entries), in shorter leaves
+CELL_LEAVES = ((8, 1024, 16, 64), (8, 1024, 16, 128), (4, 3, 512, 16, 128))
+
+
+def cache_write_check(leaves=CELL_LEAVES, dtype="bfloat16") -> None:
+    """``kernels.cache_write.write_rows`` against ``lm_utils._write``, the
+    scatter it stands in for, on random leaves with every slot at a
+    position of its own: not one element may differ."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.kernels import cache_write
+    from paddle_tpu.models import lm_utils
+
+    zero = jnp.zeros((), jnp.int32)
+    for n, shape in enumerate(leaves):
+        slots, length = shape[0], shape[-3]
+        keys = jax.random.split(jax.random.PRNGKey(n), 5)
+        row = (slots, 1) + tuple(shape[-2:])
+        k, v = (jax.random.normal(key, shape, dtype) for key in keys[:2])
+        nk, nv = (jax.random.normal(key, row, dtype) for key in keys[2:4])
+        pos = jax.random.randint(keys[4], (slots,), 0, length)
+        pos = pos.at[0].set(0).at[-1].set(length - 1)
+        entry = jnp.int32(shape[1] - 1) if len(shape) == 5 else None
+        check(cache_write.rows_fit(k, nk),
+              f"cache write: the gate refuses a leaf {list(shape)} {dtype}")
+        got = jax.jit(lambda *a: cache_write.write_rows(*a))(
+            k, v, nk, nv, pos, entry)
+        want = jax.jit(lambda *a: tuple(
+            lm_utils._write(buf, new, a[4], a[5], zero)
+            for buf, new in zip(a[:2], a[2:4])))(k, v, nk, nv, pos, entry)
+        for name, g, w, old in zip(("key", "value"), got, want, (k, v)):
+            differ = int(jnp.sum(g != w))
+            written = int(jnp.sum(g != old))
+            log(f"[serve] cache write {list(shape)} {name}: kernel vs "
+                f"scatter {differ} of {g.size} elements differ "
+                f"({written} written)")
+            check(differ == 0 and written > 0,
+                  f"cache write {list(shape)} {name}: {differ} elements "
+                  f"differ from the scatter's, {written} written")
+
+
 def serve_phase(cfg, slots: int, prompt_lens, n_requests: int,
                 new_tokens=(8, 24), expect_donation: bool = True,
+                expect_cache_write: str = "dma",
                 timeout: float = 600.0) -> dict:
     """``expect_donation``: the engine donates its KV cache to the decode
-    program on an accelerator and, by its own branch, not on the CPU."""
+    program on an accelerator and, by its own branch, not on the CPU.
+    ``expect_cache_write``: the same for the kernel behind the decode
+    program's per-slot cache write, ``"scatter"`` on the CPU."""
     import jax
     import paddle_tpu as pt
     from paddle_tpu.framework import compile_cache
@@ -373,6 +421,11 @@ def serve_phase(cfg, slots: int, prompt_lens, n_requests: int,
         f"pre-traffic KV buffer deleted (donated): {donated}")
     check(donated == expect_donation,
           f"KV cache donation is {donated}, want {expect_donation}")
+    log(f"[serve] the decode program's per-slot cache write: "
+        f"{cc['cache_write']}")
+    check(cc["cache_write"] == expect_cache_write,
+          f"the decode program writes its cache by {cc['cache_write']}, "
+          f"want {expect_cache_write}")
 
     # greedy parity with the offline engine (own programs, own cache)
     i0 = next(i for i, r in enumerate(requests) if not r[2])
@@ -385,7 +438,8 @@ def serve_phase(cfg, slots: int, prompt_lens, n_requests: int,
             + _report_divergence(model, prompt, served, solo))
     log(f"[serve] greedy request {i0} ({len(prompt)}-token prompt, {n} new "
         f"tokens) equals model.generate()")
-    return {"programs": traced, "donated": donated}
+    return {"programs": traced, "donated": donated,
+            "cache_write": cc["cache_write"]}
 
 
 # ------------------------------------------------------------ four chips
@@ -549,9 +603,13 @@ def main(argv=None) -> int:
     if "serve" in phases:
         # the serving preset of tools/decode_bench.py: f32 weights, bf16
         # KV cache, every default prefill bucket up to 1024
-        _run_phase("serve", lambda: serve_phase(
-            gpt_config(24, 1024, loss_chunk=0), slots=8,
-            prompt_lens=(20, 50, 100, 200, 400, 900), n_requests=32))
+        def serve():
+            cache_write_check()
+            return serve_phase(
+                gpt_config(24, 1024, loss_chunk=0), slots=8,
+                prompt_lens=(20, 50, 100, 200, 400, 900), n_requests=32)
+
+        _run_phase("serve", serve)
         done["serve"] = "passed"
     if "four_chip" in phases:
         if len(devices) < 4:

@@ -9,20 +9,24 @@ fuses softmax+CE but still materializes full logits.
 """
 from __future__ import annotations
 
+import contextlib
 import math
+import threading
 
 import jax
 import jax.numpy as jnp
 
 from ..distributed.mesh import get_mesh, sharding
 from ..distributed.parallel.recompute import recompute_wrap
+from ..kernels import cache_write
 from ..kernels import flash_attention as fa
 from ..nn import functional as F
 from ..nn.layer import Layer
 
 __all__ = ["chunked_lm_loss", "DecoderBlockList", "constrain_seq", "CacheRow",
            "causal_attention", "repeat_kv", "update_kv_cache",
-           "cached_attention", "attend_with_cache", "cached_lm_forward"]
+           "cache_write_paths", "cached_attention", "attend_with_cache",
+           "cached_lm_forward"]
 
 
 def constrain_seq(x, cfg):
@@ -113,6 +117,8 @@ def _write(buf, new, pos, entry, row):
     if entry is not None:
         new = new[:, None]
     if pos.ndim == 1:
+        _note_write("scatter")
+
         def write(c, n, p):
             return jax.lax.dynamic_update_slice(
                 c, n, stack + (p,) + (zero,) * (c.ndim - 1 - len(stack)))
@@ -120,6 +126,42 @@ def _write(buf, new, pos, entry, row):
         return jax.vmap(write)(buf, new, pos)
     start = (row,) + stack + (pos,) + (zero,) * (buf.ndim - 2 - len(stack))
     return jax.lax.dynamic_update_slice(buf, new, start)
+
+
+# Trace-time state, thread-local as the adapter context of lora.layers
+# is: the serving engine opens it around the trace of its decode program.
+_WRITES = threading.local()
+
+
+@contextlib.contextmanager
+def cache_write_paths():
+    """The set of ways the program traced under this context issues its
+    per-slot cache writes: ``"dma"`` (:mod:`..kernels.cache_write`) or
+    ``"scatter"`` (the vmapped ``dynamic_update_slice``)."""
+    outer = getattr(_WRITES, "paths", None)
+    paths = _WRITES.paths = set()
+    try:
+        yield paths
+    finally:
+        _WRITES.paths = outer
+
+
+def _note_write(path: str) -> None:
+    paths = getattr(_WRITES, "paths", None)
+    if paths is not None:
+        paths.add(path)
+
+
+def _rows_by_dma(k_cache, v_cache, new, pos) -> bool:
+    """One operation, two ways to issue it, told apart by what the trace
+    shows: the kernel takes a TPU's per-slot (``[B]``-position) write of
+    one token into plain leaves on one device whose rows are whole tiles
+    (:func:`cache_write.rows_fit`); the scatter takes everything else."""
+    mesh = get_mesh()
+    return (pos.ndim == 1 and jax.default_backend() == "tpu"
+            and (mesh is None or mesh.size == 1)
+            and cache_write.rows_fit(k_cache, new)
+            and cache_write.rows_fit(v_cache, new))
 
 
 def update_kv_cache(cache, k_new, v_new, position_offset, entry=None):
@@ -151,6 +193,11 @@ def update_kv_cache(cache, k_new, v_new, position_offset, entry=None):
                  _write_window(k_cache[1], ks, pos, entry)),
                 (_write_window(v_cache[0], vq, pos, entry),
                  _write_window(v_cache[1], vs, pos, entry)))
+    # tpu-lint: disable=R2(the gate reads the backend and the leaves' static type, shape and dtype — one program per cache layout)
+    if _rows_by_dma(k_cache, v_cache, k_new, pos):
+        _note_write("dma")
+        return cache_write.write_rows(k_cache, v_cache, k_new, v_new, pos,
+                                      entry)
     return (_write_window(k_cache, k_new, pos, entry),
             _write_window(v_cache, v_new, pos, entry))
 

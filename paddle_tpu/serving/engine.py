@@ -50,7 +50,7 @@ from ..models.generation import (DEFAULT_PREFILL_BUCKETS, _constrain_cache,
                                  init_cache, normalize_kv_dtype,
                                  per_row_keys, sample_logits_rows,
                                  scatter_cache_blocks, scatter_cache_rows)
-from ..models.lm_utils import CacheRow
+from ..models.lm_utils import CacheRow, cache_write_paths
 from ..lora import adapter_rows as _adapter_rows_ctx
 from ..lora.store import AdapterStore, normalize_adapter_id
 from ..nn.layer import buffer_state, functional_call, param_state
@@ -132,6 +132,9 @@ class ContinuousBatchingEngine:
             f"serve:prefill:{model_name}")
         self._cc_decode = compile_cache.register_name(
             f"serve:decode:{model_name}")
+        #: how the decode program writes a step's keys and values, "dma"
+        #: or "scatter": known once it has been traced
+        self._cache_write: Optional[str] = None
         on_device = jax.default_backend() != "cpu"
         lora = self.store is not None
         if self.pool is not None:
@@ -383,10 +386,11 @@ class ContinuousBatchingEngine:
 
     def _decode_fn(self, params, buffers, live_cache, tokens, positions,
                    keys, done, eos, temperature, top_p, greedy_mask):
-        with jax.named_scope("decode"):
+        with jax.named_scope("decode"), cache_write_paths() as paths:
             (logits, live_cache), _ = functional_call(
                 self.model, params, buffers, tokens, cache=live_cache,
                 position_offset=positions)
+        self._cache_write = "dma" if paths == {"dma"} else "scatter"
         live_cache = _constrain_cache(live_cache, self.slots,
                                       self.spec["num_kv_heads"])
         logits = logits[:, -1, :]
@@ -695,9 +699,12 @@ class ContinuousBatchingEngine:
         state must hold at ``#buckets_used`` prefill + 1 decode — and the
         live cache's geometry: its entries (one per layer application
         that writes keys and values) and the bytes a token holds in all
-        of them."""
+        of them. ``cache_write`` is the way the decode program lands a
+        step's keys and values (``lm_utils.update_kv_cache``), None
+        until it has been traced."""
         return {"prefill": compile_cache.cache_stats(self._cc_prefill),
                 "decode": compile_cache.cache_stats(self._cc_decode),
+                "cache_write": self._cache_write,
                 "cache_entries": cache_entries(self.spec),
                 "cache_bytes_per_token":
                     self.cache_bytes_per_slot() // self.max_length}

@@ -31,9 +31,10 @@ import pytest  # noqa: E402
 
 @pytest.fixture()
 def interpret_pallas():
-    """Run the flash-attention Pallas kernels in interpret mode (the CPU
-    has no Mosaic); yields the list of pallas_call invocations so a test
-    can see that the kernels were really traced."""
+    """Run the Pallas kernels (flash attention, the cache write: both
+    reach ``pallas_call`` through the one ``pallas`` module) in interpret
+    mode (the CPU has no Mosaic); yields the list of pallas_call
+    invocations so a test can see that the kernels were really traced."""
     from unittest import mock
 
     from paddle_tpu.kernels import flash_attention as fa

@@ -83,8 +83,26 @@ def test_flash_phase_notices_the_xla_fallback(interpret_pallas):
 def test_serve_phase_tiny():
     out = chip_smoke.serve_phase(
         _tiny(64, loss_chunk=0), slots=4, prompt_lens=(10, 40),
-        n_requests=8, new_tokens=(4, 8), expect_donation=False)
+        n_requests=8, new_tokens=(4, 8), expect_donation=False,
+        expect_cache_write="scatter")
     assert out["programs"] == 3           # buckets 32, 64 + decode
+    assert out["cache_write"] == "scatter"
+
+
+def test_serve_phase_notices_the_scatter():
+    """On the CPU the decode program keeps the scatter: a phase that
+    expects the kernel, as the chip's does, must fail."""
+    with pytest.raises(chip_smoke.CheckFailed, match="by scatter"):
+        chip_smoke.serve_phase(
+            _tiny(64, loss_chunk=0), slots=4, prompt_lens=(10, 40),
+            n_requests=8, new_tokens=(4, 8), expect_donation=False)
+
+
+def test_cache_write_check_tiny(interpret_pallas):
+    chip_smoke.cache_write_check(
+        leaves=((3, 128, 16, 64), (3, 16, 16, 128), (2, 3, 16, 16, 128)))
+    assert sorted(set(interpret_pallas)) == [
+        "_copy_rows_kernel", "_merge_columns_kernel"]
 
 
 @pytest.fixture()
