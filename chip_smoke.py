@@ -304,13 +304,14 @@ CELL_LEAVES = ((8, 1024, 16, 64), (8, 1024, 16, 128), (4, 3, 512, 16, 128))
 
 
 def cache_write_check(leaves=CELL_LEAVES, dtype="bfloat16") -> None:
-    """``kernels.cache_write.write_rows`` against ``lm_utils._write``, the
+    """``kernels.cache_write.write_rows`` against ``kv_cache._write``, the
     scatter it stands in for, on random leaves with every slot at a
-    position of its own: not one element may differ."""
+    position of its own: not one element may differ. The gate is
+    ``update_kv_cache``'s own (``kv_cache._rows_by_dma``)."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.kernels import cache_write
-    from paddle_tpu.models import lm_utils
+    from paddle_tpu.models import kv_cache
 
     zero = jnp.zeros((), jnp.int32)
     for n, shape in enumerate(leaves):
@@ -322,12 +323,12 @@ def cache_write_check(leaves=CELL_LEAVES, dtype="bfloat16") -> None:
         pos = jax.random.randint(keys[4], (slots,), 0, length)
         pos = pos.at[0].set(0).at[-1].set(length - 1)
         entry = jnp.int32(shape[1] - 1) if len(shape) == 5 else None
-        check(cache_write.rows_fit(k, nk),
+        check(kv_cache._rows_by_dma(k, v, nk, pos),
               f"cache write: the gate refuses a leaf {list(shape)} {dtype}")
         got = jax.jit(lambda *a: cache_write.write_rows(*a))(
             k, v, nk, nv, pos, entry)
         want = jax.jit(lambda *a: tuple(
-            lm_utils._write(buf, new, a[4], a[5], zero)
+            kv_cache._write(buf, new, a[4], a[5], zero)
             for buf, new in zip(a[:2], a[2:4])))(k, v, nk, nv, pos, entry)
         for name, g, w, old in zip(("key", "value"), got, want, (k, v)):
             differ = int(jnp.sum(g != w))

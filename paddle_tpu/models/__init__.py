@@ -10,6 +10,7 @@ from . import bert  # noqa: F401
 from . import ernie  # noqa: F401
 from . import generation  # noqa: F401
 from . import gpt  # noqa: F401
+from . import kv_cache  # noqa: F401
 from . import llama  # noqa: F401
 from . import ouro  # noqa: F401
 from . import ppyoloe  # noqa: F401
@@ -22,11 +23,11 @@ from .bert import (BertConfig, BertForPretraining,  # noqa: F401
 from .ernie import (ErnieConfig, ErnieForPretraining,  # noqa: F401
                     ErnieForSequenceClassification, ErnieModel,
                     ernie_3_base, ernie_tiny)
-from .generation import (GenerationEngine, generate, init_cache,  # noqa: F401
-                         cache_nbytes, filter_logits, per_row_keys,
-                         sample_logits, sample_logits_rows,
-                         scatter_cache_rows, slice_cache_rows)
+from .generation import (GenerationEngine, generate,  # noqa: F401
+                         filter_logits, per_row_keys, sample_logits,
+                         sample_logits_rows)
 from .gpt import GPTConfig, GPTForCausalLM, GPTModel, gpt_1p3b, gpt_tiny  # noqa: F401
+from .kv_cache import cache_nbytes, init_cache, scatter_cache_rows  # noqa: F401
 from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel,  # noqa: F401
                     llama2_7b, llama_tiny)
 from .ouro import OuroConfig, OuroForCausalLM, OuroModel, ouro_tiny  # noqa: F401

@@ -6,9 +6,8 @@ every grown sequence length is a novel shape, so N tokens trace N programs
 — the exact recompile storm ``framework.compile_cache.retrace_guard`` was
 built to catch. This module fixes both with a strict shape discipline:
 
-- the KV cache is a PREALLOCATED pytree of per-layer ``(k, v)`` pairs,
-  each ``[B, max_length, n_kv_heads, head_dim]`` — its shape never changes
-  while decoding, only a position scalar advances;
+- the KV cache (:mod:`.kv_cache`) is PREALLOCATED — its shape never
+  changes while decoding, only a position scalar advances;
 - **prefill** runs the prompt (right-padded up to the smallest PR-2 style
   length bucket) through the flash-eligible block-local attention path and
   writes the prompt's K/V into the cache: one compile per *bucket*, not
@@ -23,231 +22,34 @@ of O(N). Sampling (greedy / temperature / top-k / top-p, per-sequence EOS
 early-stop via a done-mask — no shape change) runs inside the compiled
 steps; the driver is a plain Python loop (no ``lax.while_loop``: the two
 jitted steps with donated cache buffers are the whole program, and the
-loop stays debuggable/interruptible). On a GSPMD mesh the cache lands
-batch-sharded over dp/sdp and kv-head-sharded over mp, so tensor-parallel
-decode needs no gathers. Both steps are ``compile_cache``-instrumented
-(``generate:prefill:*`` / ``generate:decode:*`` keys) and the loop runs
-under a ``decode`` RecordEvent span.
+loop stays debuggable/interruptible). Both steps are
+``compile_cache``-instrumented (``generate:prefill:*`` /
+``generate:decode:*`` keys) and the loop runs under a ``decode``
+RecordEvent span.
 """
 from __future__ import annotations
 
 import time
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..distributed.mesh import get_mesh, sharding
 from ..framework import compile_cache
 from ..framework import random as framework_random
-from ..framework.dtype import convert_dtype
 from ..nn.layer import buffer_state, functional_call, param_state
 from ..io.batching import bucket_for
 from ..observability import tracing as _tracing
+from .kv_cache import (cache_geometry, constrain_cache, init_cache,
+                       normalize_kv_dtype)
 
-__all__ = ["GenerationEngine", "generate", "init_cache", "cache_entries",
-           "cache_layout", "cache_nbytes", "normalize_kv_dtype", "sample_logits", "filter_logits",
-           "sample_logits_rows", "per_row_keys", "slice_cache_rows",
-           "scatter_cache_rows", "gather_cache_blocks",
-           "scatter_cache_blocks", "cache_sharding_spec",
-           "DEFAULT_PREFILL_BUCKETS"]
+__all__ = ["GenerationEngine", "generate", "sample_logits", "filter_logits",
+           "sample_logits_rows", "per_row_keys", "DEFAULT_PREFILL_BUCKETS"]
 
 # prompt lengths round up to the smallest of these (clipped to the
 # model's max_length) — the serving analogue of DataLoader length_buckets
 DEFAULT_PREFILL_BUCKETS = (32, 64, 128, 256, 512, 1024, 2048, 4096)
-
-
-# ----------------------------------------------------------------- cache
-def cache_entries(spec: dict) -> int:
-    """How many ``(k, v)`` entries the cache of a model's ``cache_spec()``
-    holds: one per layer application that writes keys and values. That
-    is ``spec["cache_entries"]``; a spec without the key has one per
-    layer."""
-    return int(spec.get("cache_entries", spec["num_layers"]))
-
-
-def cache_layout(spec: dict):
-    """``(pairs, stack)`` of a model's ``cache_spec()``: the cache is a
-    tuple of ``pairs`` ``(k, v)`` pairs whose leaves are ``[B, *stack, S,
-    Hkv, D]``. ``spec["entry_stack"]`` of the :func:`cache_entries` share
-    a leaf pair on an axis after the batch's (a looped model's recurrent
-    steps; 1 and no axis when absent), so that a program can index them
-    by a traced step. Rows lead whatever the stack: a slot's cache is
-    ``leaf[slot]`` for every model."""
-    entries = cache_entries(spec)
-    stack = int(spec.get("entry_stack", 1))
-    if entries % stack:
-        raise ValueError(f"cache_entries {entries} is no multiple of "
-                         f"entry_stack {stack}")
-    return entries // stack, ((stack,) if stack > 1 else ())
-
-
-def cache_sharding_spec(batch: int, n_kv_heads: int, mesh=None, stack=0):
-    """GSPMD sharding for one cache leaf [B, S, Hkv, D] (``stack``
-    replicated axes of stacked entries after the batch's): batch over
-    dp/sdp, kv heads over mp — matching the Column-parallel K/V
-    projections, so tp decode reads/writes only local heads (no gathers).
-    Axes that don't divide evenly stay replicated."""
-    mesh = mesh if mesh is not None else get_mesh()
-    if mesh is None:
-        return None
-    batch_axes = tuple(a for a in ("dp", "sdp") if a in mesh.shape)
-    bsz = 1
-    for a in batch_axes:
-        bsz *= mesh.shape[a]
-    if bsz <= 1 or batch % bsz != 0:
-        batch_axes = None
-    mp = mesh.shape.get("mp", 1)
-    head_axis = "mp" if (mp > 1 and n_kv_heads % mp == 0) else None
-    if batch_axes is None and head_axis is None:
-        return None
-    return sharding(batch_axes or None, *(None,) * stack, None, head_axis,
-                    None, mesh=mesh)
-
-
-def normalize_kv_dtype(kv_dtype):
-    """Canonicalize a ``kv_dtype`` knob: ``None``/``"none"`` -> None
-    (full-precision cache, the PR 9-bit-identical default), ``"int8"`` ->
-    ``"int8"``. Anything else is an error at construction time, not a
-    silent full-precision fallback."""
-    if kv_dtype is None or kv_dtype in ("none", "fp", "full"):
-        return None
-    if str(kv_dtype) == "int8":
-        return "int8"
-    raise ValueError(f"unsupported kv_dtype {kv_dtype!r}; expected None "
-                     f"or 'int8'")
-
-
-def init_cache(model, batch: int, max_length: Optional[int] = None,
-               dtype=None, kv_dtype=None):
-    """Preallocate the KV cache pytree for ``model``: a tuple of ``(k,
-    v)`` pairs, each ``[batch, max_length, n_kv_heads, head_dim]`` zeros,
-    one pair per cache entry of ``model.cache_spec()`` (per layer, for a
-    model that applies each layer once; see :func:`cache_layout`).
-    Placed in its GSPMD layout when a mesh is installed.
-
-    ``kv_dtype="int8"`` allocates the quantized layout instead: each
-    ``k``/``v`` entry is a ``(int8 values, float32 scales [B, S, Hkv,
-    1])`` pair (see :mod:`paddle_tpu.quantization`), roughly halving the
-    cache's HBM footprint at head_dim 64+. The scale leaf shares the
-    value leaf's sharding spec (batch over dp/sdp, kv heads over mp)."""
-    spec = model.cache_spec()
-    max_length = int(max_length or spec["max_length"])
-    dtype = convert_dtype(dtype or spec["dtype"])
-    kv_dtype = normalize_kv_dtype(kv_dtype)
-    pairs, stack = cache_layout(spec)
-    shape = (batch,) + stack + (max_length, spec["num_kv_heads"],
-                                spec["head_dim"])
-    shd = cache_sharding_spec(batch, spec["num_kv_heads"], stack=len(stack))
-
-    def put(z):
-        return jax.device_put(z, shd) if shd is not None else z
-
-    def leaf():
-        if kv_dtype == "int8":
-            return (put(jnp.zeros(shape, jnp.int8)),
-                    put(jnp.zeros(shape[:-1] + (1,), jnp.float32)))
-        return put(jnp.zeros(shape, dtype))
-
-    return tuple((leaf(), leaf()) for _ in range(pairs))
-
-
-def cache_nbytes(cache) -> int:
-    """Total bytes of a cache pytree (quantized scale leaves included) —
-    the number the HBM-per-slot accounting asserts on."""
-    return int(jax.tree.reduce(
-        lambda acc, x: acc + x.nbytes, cache, 0))
-
-
-def _constrain_cache(cache, batch: int, n_kv_heads: int):
-    """with_sharding_constraint on every cache leaf (inside jit), so the
-    compiled steps keep the cache resident in its sharded layout."""
-    stack = jax.tree.leaves(cache)[0].ndim - 4
-    shd = cache_sharding_spec(batch, n_kv_heads, stack=stack)
-    if shd is None:
-        return cache
-    return jax.tree.map(
-        lambda x: jax.lax.with_sharding_constraint(x, shd), cache)
-
-
-def slice_cache_rows(cache, index, rows: int = 1):
-    """Slice ``rows`` batch rows starting at (possibly traced) ``index``
-    out of a cache pytree: ``[B, S, Hkv, D]`` leaves -> ``[rows, ...]``.
-    Jit-safe — the continuous-batching engine uses it to lift one slot's
-    cache out of the live batch."""
-    idx = jnp.asarray(index, jnp.int32)
-    return jax.tree.map(
-        lambda x: jax.lax.dynamic_slice_in_dim(x, idx, rows, axis=0), cache)
-
-
-def scatter_cache_rows(cache, row_cache, index):
-    """Write ``row_cache`` (``[r, S, Hkv, D]`` leaves) into ``cache``
-    (``[B, ...]`` leaves) at batch row ``index`` (may be traced).
-
-    This is the slot-scatter primitive of continuous batching: a freshly
-    prefilled single-slot cache lands in the live B-slot decode batch
-    without the batch's shape ever changing — same program for every slot
-    index."""
-    zero = jnp.zeros((), jnp.int32)
-    idx = jnp.asarray(index, jnp.int32)
-
-    def up(live, row):
-        return jax.lax.dynamic_update_slice(
-            live, row.astype(live.dtype),
-            (idx,) + (zero,) * (live.ndim - 1))
-
-    return jax.tree.map(up, cache, row_cache)
-
-
-def gather_cache_blocks(pool, block_indices, length: int):
-    """Assemble a cache row from a paged block pool: gather ``pool``
-    leaves ``[N, bs, Hkv, D]`` at (possibly traced) ``block_indices``
-    ``[n]`` and lay the blocks out contiguously as ``[1, length, Hkv,
-    D]`` (zero-padded past ``n*bs``).
-
-    The prefix-cache read primitive: matched prompt blocks land in a
-    slot's cache rows in-program, so a cache hit never re-prefills the
-    shared prefix. Indices past the matched chain point at the pool's
-    reserved dump block (row 0) — those positions hold garbage, which is
-    safe under the same invariant as slot reuse: the position mask never
-    lets a query see beyond its request's frontier, and every position
-    is rewritten before it first becomes visible."""
-    idx = jnp.asarray(block_indices, jnp.int32)
-
-    def assemble(leaf):
-        n, bs = idx.shape[0], leaf.shape[-3]
-        # [n, *stack, bs, Hkv, D] -> [*stack, n, bs, Hkv, D]
-        blocks = jnp.moveaxis(jnp.take(leaf, idx, axis=0), 0, -4)
-        flat = blocks.reshape(1, *leaf.shape[1:-3], n * bs, *leaf.shape[-2:])
-        if n * bs < length:
-            pad = [(0, 0)] * flat.ndim
-            pad[-3] = (0, length - n * bs)
-            flat = jnp.pad(flat, pad)
-        return jax.lax.slice_in_dim(flat, 0, length, axis=flat.ndim - 3)
-
-    return jax.tree.map(assemble, pool)
-
-
-def scatter_cache_blocks(pool, row_cache, block_indices):
-    """Write a cache row back into a paged block pool: split ``row_cache``
-    leaves ``[1, S, Hkv, D]`` into ``n`` blocks of the pool's block size
-    and scatter them at (possibly traced) ``block_indices`` ``[n]``.
-
-    The prefix-cache store primitive (inverse of
-    :func:`gather_cache_blocks`). Blocks the host chose not to cache
-    point their index at the reserved dump row 0 — duplicate writes to
-    the dump are harmless because its content is never read as valid."""
-    idx = jnp.asarray(block_indices, jnp.int32)
-
-    def store(leaf, row):
-        n, bs = idx.shape[0], leaf.shape[-3]
-        blocks = jax.lax.slice_in_dim(row[0], 0, n * bs, axis=row.ndim - 4)
-        blocks = jnp.moveaxis(
-            blocks.reshape(*leaf.shape[1:-3], n, bs, *leaf.shape[-2:]), -4, 0)
-        return leaf.at[idx].set(blocks.astype(leaf.dtype))
-
-    return jax.tree.map(store, pool, row_cache)
 
 
 # -------------------------------------------------------------- sampling
@@ -373,20 +175,10 @@ class GenerationEngine:
                  prefill_buckets: Optional[Sequence[int]] = None,
                  kv_dtype=None):
         self.model = model
-        spec = model.cache_spec()
-        self.spec = spec
+        self.spec = spec = model.cache_spec()
         self.kv_dtype = normalize_kv_dtype(kv_dtype)
-        self.max_length = int(max_length or spec["max_length"])
-        if self.max_length > spec["max_length"]:
-            # position tables slice with CLAMPED dynamic_slice: positions
-            # past the table would silently reuse its last row
-            raise ValueError(
-                f"max_length {self.max_length} exceeds the model's position "
-                f"table ({spec['max_length']} positions)")
-        buckets = tuple(sorted(int(b) for b in
-                               (prefill_buckets or DEFAULT_PREFILL_BUCKETS)
-                               if int(b) <= self.max_length))
-        self.prefill_buckets = buckets or (self.max_length,)
+        self.max_length, self.prefill_buckets = cache_geometry(
+            spec, max_length, prefill_buckets or DEFAULT_PREFILL_BUCKETS)
         model_name = type(model).__name__
         self._cc_prefill = compile_cache.register_name(
             f"generate:prefill:{model_name}")
@@ -412,8 +204,7 @@ class GenerationEngine:
             (logits, cache), _ = functional_call(
                 self.model, params, buffers, ids, cache=cache,
                 position_offset=0, gather_last=last_index)
-        cache = _constrain_cache(cache, ids.shape[0],
-                                 self.spec["num_kv_heads"])
+        cache = constrain_cache(cache)
         logits = logits[:, 0, :]
         if greedy:
             next_tok = sample_logits(logits, None, greedy=True)
@@ -433,8 +224,7 @@ class GenerationEngine:
             (logits, cache), _ = functional_call(
                 self.model, params, buffers, token, cache=cache,
                 position_offset=pos)
-        cache = _constrain_cache(cache, token.shape[0],
-                                 self.spec["num_kv_heads"])
+        cache = constrain_cache(cache)
         logits = logits[:, -1, :]
         if greedy:
             next_tok = sample_logits(logits, None, greedy=True)

@@ -252,7 +252,7 @@ class GPTForCausalLM(Layer):
         return self.lm_head(h)
 
     def cache_spec(self) -> dict:
-        """Static KV-cache geometry for ``models.generation.init_cache``."""
+        """Static KV-cache geometry for ``models.kv_cache.init_cache``."""
         return {"num_layers": self.cfg.num_layers,
                 "cache_entries": self.cfg.num_layers,
                 "num_kv_heads": self.cfg.num_heads,
@@ -275,7 +275,7 @@ class GPTForCausalLM(Layer):
         ``chunked_lm_loss``).
 
         With ``cache`` (per-layer ``(k, v)`` pairs from
-        ``models.generation.init_cache``) runs the cached-decode path and
+        ``models.kv_cache.init_cache``) runs the cached-decode path and
         returns ``(logits, new_cache)``. ``gather_last`` (a traced scalar
         index) slices the hidden states to that single position BEFORE the
         head projection, so serving never materializes [B, L, vocab]."""

@@ -260,7 +260,7 @@ class LlamaForCausalLM(Layer):
         return self.lm_head(h)
 
     def cache_spec(self) -> dict:
-        """Static KV-cache geometry for ``models.generation.init_cache``
+        """Static KV-cache geometry for ``models.kv_cache.init_cache``
         (GQA: the cache stores ``num_kv_heads``, not ``num_heads``)."""
         return {"num_layers": self.cfg.num_layers,
                 "cache_entries": self.cfg.num_layers,

@@ -1,6 +1,6 @@
-"""Shared language-model loss plumbing (GPT/Llama/ERNIE families).
-
-The memory-fused chunked LM loss: head projection + softmax-CE computed
+"""Shared decoder plumbing (GPT/Llama/ERNIE families): the block stack,
+causal and cached-decode attention (the cache itself is :mod:`.kv_cache`'s)
+and the memory-fused chunked LM loss: head projection + softmax-CE computed
 over sequence chunks inside ``jax.checkpoint`` regions, so the
 [B, L, vocab] logits tensor — the single largest HBM allocation in LM
 pretrain — never materializes. Reference contrast:
@@ -9,23 +9,20 @@ fuses softmax+CE but still materializes full logits.
 """
 from __future__ import annotations
 
-import contextlib
 import math
-import threading
 
 import jax
 import jax.numpy as jnp
 
 from ..distributed.mesh import get_mesh, sharding
 from ..distributed.parallel.recompute import recompute_wrap
-from ..kernels import cache_write
 from ..kernels import flash_attention as fa
 from ..nn import functional as F
 from ..nn.layer import Layer
+from .kv_cache import cached_attention, update_kv_cache
 
-__all__ = ["chunked_lm_loss", "DecoderBlockList", "constrain_seq", "CacheRow",
-           "causal_attention", "repeat_kv", "update_kv_cache",
-           "cache_write_paths", "cached_attention", "attend_with_cache",
+__all__ = ["chunked_lm_loss", "DecoderBlockList", "constrain_seq",
+           "causal_attention", "repeat_kv", "attend_with_cache",
            "cached_lm_forward"]
 
 
@@ -69,178 +66,13 @@ def causal_attention(q, k, v, dropout_p=0.0, training=True, use_flash=True):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-# ------------------------------------------------------------- KV cache
+# -------------------------------------------------------- cached decode
 def repeat_kv(x, groups: int):
     """[B, L, Hkv, D] -> [B, L, Hkv*groups, D] for GQA (each kv head
     serves ``groups`` query heads)."""
     if groups == 1:
         return x
     return jnp.repeat(x, groups, axis=2)
-
-
-@jax.tree_util.register_pytree_node_class
-class CacheRow:
-    """Row ``row`` (a traced index) of a live cache leaf ``buf`` ``[B, S,
-    Hkv, D]``, standing where a batch-1 cache leaf would: a prefill
-    given these writes its keys and values straight into the live batch
-    (:func:`update_kv_cache`), so that an admission builds no row of its
-    own beside it (1.6 GB at 1.5 MiB a token and 1024 positions). Write
-    only: the prefill shape attends over its own block."""
-
-    def __init__(self, buf, row):
-        self.buf, self.row = buf, row
-
-    def tree_flatten(self):
-        return (self.buf, self.row), None
-
-    @classmethod
-    def tree_unflatten(cls, aux, children):
-        return cls(*children)
-
-
-def _write_window(buf, new, pos, entry=None):
-    """Write ``new`` into ``buf`` along the length axis at ``pos`` —
-    scalar offset (one dynamic_update_slice, into that row alone where
-    ``buf`` is a :class:`CacheRow`) or per-row [B] vector (the vmapped
-    windowed write). With ``entry`` (a traced index) each row of ``buf``
-    stacks several cache entries, ``[B, E, S, ...]``, and the write lands
-    in that one."""
-    if isinstance(buf, CacheRow):
-        return CacheRow(_write(buf.buf, new, pos, entry, buf.row), buf.row)
-    return _write(buf, new, pos, entry, jnp.zeros((), jnp.int32))
-
-
-def _write(buf, new, pos, entry, row):
-    zero = jnp.zeros((), jnp.int32)
-    stack = () if entry is None else (jnp.asarray(entry, jnp.int32),)
-    new = new.astype(buf.dtype)
-    if entry is not None:
-        new = new[:, None]
-    if pos.ndim == 1:
-        _note_write("scatter")
-
-        def write(c, n, p):
-            return jax.lax.dynamic_update_slice(
-                c, n, stack + (p,) + (zero,) * (c.ndim - 1 - len(stack)))
-
-        return jax.vmap(write)(buf, new, pos)
-    start = (row,) + stack + (pos,) + (zero,) * (buf.ndim - 2 - len(stack))
-    return jax.lax.dynamic_update_slice(buf, new, start)
-
-
-# Trace-time state, thread-local as the adapter context of lora.layers
-# is: the serving engine opens it around the trace of its decode program.
-_WRITES = threading.local()
-
-
-@contextlib.contextmanager
-def cache_write_paths():
-    """The set of ways the program traced under this context issues its
-    per-slot cache writes: ``"dma"`` (:mod:`..kernels.cache_write`) or
-    ``"scatter"`` (the vmapped ``dynamic_update_slice``)."""
-    outer = getattr(_WRITES, "paths", None)
-    paths = _WRITES.paths = set()
-    try:
-        yield paths
-    finally:
-        _WRITES.paths = outer
-
-
-def _note_write(path: str) -> None:
-    paths = getattr(_WRITES, "paths", None)
-    if paths is not None:
-        paths.add(path)
-
-
-def _rows_by_dma(k_cache, v_cache, new, pos) -> bool:
-    """One operation, two ways to issue it, told apart by what the trace
-    shows: the kernel takes a TPU's per-slot (``[B]``-position) write of
-    one token into plain leaves on one device whose rows are whole tiles
-    (:func:`cache_write.rows_fit`); the scatter takes everything else."""
-    mesh = get_mesh()
-    return (pos.ndim == 1 and jax.default_backend() == "tpu"
-            and (mesh is None or mesh.size == 1)
-            and cache_write.rows_fit(k_cache, new)
-            and cache_write.rows_fit(v_cache, new))
-
-
-def update_kv_cache(cache, k_new, v_new, position_offset, entry=None):
-    """Write ``k_new``/``v_new`` [B, L, Hkv, D] into the preallocated
-    ``(k, v)`` cache pair at ``position_offset`` along the length axis
-    (of entry ``entry`` where the pair's leaves stack several entries,
-    ``[B, E, S, Hkv, D]``: a looped model's recurrent steps).
-
-    ``position_offset`` may be a traced scalar (the single-token decode
-    step passes the running position as a device int32, so ONE compiled
-    program serves every position) or a traced ``[B]`` vector — the
-    continuous-batching decode step, where every slot of the live batch
-    sits at its own position (one per-row windowed write, still one
-    program).
-
-    Quantized caches (``kv_dtype="int8"``: each entry a ``(values,
-    scales)`` pair, see :mod:`paddle_tpu.quantization`) quantize on
-    write — new keys/values are reduced to int8 + per-head scale here,
-    so the full-precision window never lands in the cache buffers."""
-    from ..quantization import is_quantized_kv, kv_quantize
-
-    k_cache, v_cache = cache
-    pos = jnp.asarray(position_offset, jnp.int32)
-    # tpu-lint: disable=R2(is_quantized_kv reads pytree STRUCTURE — tuple pair vs bare array — fixed at trace time, one program per cache layout)
-    if is_quantized_kv(k_cache):
-        kq, ks = kv_quantize(k_new)
-        vq, vs = kv_quantize(v_new)
-        return ((_write_window(k_cache[0], kq, pos, entry),
-                 _write_window(k_cache[1], ks, pos, entry)),
-                (_write_window(v_cache[0], vq, pos, entry),
-                 _write_window(v_cache[1], vs, pos, entry)))
-    # tpu-lint: disable=R2(the gate reads the backend and the leaves' static type, shape and dtype — one program per cache layout)
-    if _rows_by_dma(k_cache, v_cache, k_new, pos):
-        _note_write("dma")
-        return cache_write.write_rows(k_cache, v_cache, k_new, v_new, pos,
-                                      entry)
-    return (_write_window(k_cache, k_new, pos, entry),
-            _write_window(v_cache, v_new, pos, entry))
-
-
-def cached_attention(q, k_cache, v_cache, position_offset, entry=None):
-    """Dot-product attention of ``q`` [B, L, H, D] against the FULL cache
-    [B, S, Hkv, D] (entry ``entry`` of ``[B, E, S, Hkv, D]`` leaves where
-    given) with a position mask: query at absolute position
-    ``position_offset + i`` sees keys at positions ``<= position_offset + i``
-    only, so stale/unwritten cache slots beyond the current position never
-    leak in. ``position_offset`` may be a scalar or a per-row ``[B]``
-    vector (continuous-batching decode: each slot masks at its own
-    position). GQA is a grouped einsum — the kv heads are never repeated
-    into [B, S, H, D]. int8-quantized caches (``(values, scales)``
-    entries) dequantize here, on read — the [B, S, Hkv, D] buffers stay
-    int8 in HBM and only this program's working set pays the upcast."""
-    from ..quantization import is_quantized_kv, kv_dequantize
-
-    if entry is not None:
-        k_cache, v_cache = jax.tree.map(
-            lambda x: jax.lax.dynamic_index_in_dim(x, entry, 1,
-                                                   keepdims=False),
-            (k_cache, v_cache))
-    # tpu-lint: disable=R2(is_quantized_kv reads pytree STRUCTURE — tuple pair vs bare array — fixed at trace time, one program per cache layout)
-    if is_quantized_kv(k_cache):
-        k_cache = kv_dequantize(*k_cache, dtype=q.dtype)
-        v_cache = kv_dequantize(*v_cache, dtype=q.dtype)
-    B, L, H, D = q.shape
-    S, Hkv = k_cache.shape[1], k_cache.shape[2]
-    groups = H // Hkv
-    qg = q.reshape(B, L, Hkv, groups, D)
-    s = jnp.einsum("blhgd,bshd->bhgls", qg, k_cache.astype(q.dtype))
-    s = s * (1.0 / math.sqrt(D))
-    # qpos [B|1, L]: scalar offsets broadcast over the batch, vector
-    # offsets give every row its own mask frontier
-    off = jnp.asarray(position_offset, jnp.int32).reshape(-1, 1)
-    qpos = off + jnp.arange(L, dtype=jnp.int32)[None, :]
-    allowed = (jnp.arange(S, dtype=jnp.int32)[None, None, :]
-               <= qpos[:, :, None])                      # [B|1, L, S]
-    s = jnp.where(allowed[:, None, None], s, jnp.finfo(s.dtype).min)
-    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
-    out = jnp.einsum("bhgls,bshd->blhgd", p, v_cache.astype(q.dtype))
-    return out.reshape(B, L, H, D)
 
 
 def attend_with_cache(q, k_new, v_new, cache, position_offset,

@@ -191,7 +191,7 @@ class OuroForCausalLM(LlamaForCausalLM):
         return super()._logits(h.astype(self.model.embed_tokens.weight.dtype))
 
     def cache_spec(self) -> dict:
-        """KV-cache geometry for ``models.generation.init_cache``: one
+        """KV-cache geometry for ``models.kv_cache.init_cache``: one
         entry per (step, layer), a layer's steps stacked in each row of
         its leaves."""
         T = self.cfg.total_ut_steps

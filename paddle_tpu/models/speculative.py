@@ -68,9 +68,10 @@ from ..framework import compile_cache
 from ..framework import random as framework_random
 from ..nn.layer import buffer_state, functional_call, param_state
 from ..io.batching import bucket_for
-from .generation import (_constrain_cache, filter_logits, init_cache,
-                         normalize_kv_dtype, per_row_keys, sample_logits,
+from .generation import (filter_logits, per_row_keys, sample_logits,
                          sample_logits_rows, DEFAULT_PREFILL_BUCKETS)
+from .kv_cache import (cache_geometry, constrain_cache, init_cache,
+                       normalize_kv_dtype)
 
 __all__ = ["SpeculativeEngine", "build_draft_model"]
 
@@ -128,26 +129,15 @@ class SpeculativeEngine:
         self.model = model
         self.draft_model = draft_model
         self.k = int(k)
-        spec = model.cache_spec()
-        dspec = draft_model.cache_spec()
-        self.spec = spec
-        self.dspec = dspec
+        self.spec = spec = model.cache_spec()
+        self.dspec = dspec = draft_model.cache_spec()
         self.kv_dtype = normalize_kv_dtype(kv_dtype)
         self.draft_kv_dtype = normalize_kv_dtype(
             kv_dtype if draft_kv_dtype is None else draft_kv_dtype)
-        self.max_length = int(max_length or spec["max_length"])
-        if self.max_length > spec["max_length"]:
-            raise ValueError(
-                f"max_length {self.max_length} exceeds the target's "
-                f"position table ({spec['max_length']} positions)")
-        if self.max_length > dspec["max_length"]:
-            raise ValueError(
-                f"max_length {self.max_length} exceeds the DRAFT's "
-                f"position table ({dspec['max_length']} positions)")
-        buckets = tuple(sorted(int(b) for b in
-                               (prefill_buckets or DEFAULT_PREFILL_BUCKETS)
-                               if int(b) <= self.max_length))
-        self.prefill_buckets = buckets or (self.max_length,)
+        buckets = prefill_buckets or DEFAULT_PREFILL_BUCKETS
+        self.max_length, self.prefill_buckets = cache_geometry(
+            spec, max_length, buckets, "target's")
+        cache_geometry(dspec, self.max_length, buckets, "DRAFT's")
         name = f"{type(model).__name__}+{type(draft_model).__name__}"
         self._cc = {
             kind: compile_cache.register_name(f"speculative:{kind}:{name}")
@@ -183,8 +173,7 @@ class SpeculativeEngine:
         (logits, cache), _ = functional_call(
             self.model, params, buffers, ids, cache=cache,
             position_offset=0, gather_last=last_index)
-        cache = _constrain_cache(cache, ids.shape[0],
-                                 self.spec["num_kv_heads"])
+        cache = constrain_cache(cache)
         logits = logits[:, 0, :]
         if greedy:
             tok = sample_logits(logits, None, greedy=True)
@@ -201,8 +190,7 @@ class SpeculativeEngine:
         (_, dcache), _ = functional_call(
             self.draft_model, dparams, dbuffers, ids, cache=dcache,
             position_offset=0, gather_last=last_index)
-        return _constrain_cache(dcache, ids.shape[0],
-                                self.dspec["num_kv_heads"])
+        return constrain_cache(dcache)
 
     def _draft_chain_fn(self, dparams, dbuffers, dcache, prev, pend, pos,
                         key, temperature, top_p, *, top_k, greedy,
@@ -231,8 +219,7 @@ class SpeculativeEngine:
             (logits, dcache), _ = functional_call(
                 self.draft_model, dparams, dbuffers, toks, cache=dcache,
                 position_offset=offset)
-            dcache = _constrain_cache(dcache, toks.shape[0],
-                                      self.dspec["num_kv_heads"])
+            dcache = constrain_cache(dcache)
             logits = logits[:, -1, :]
             if greedy:
                 d = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -270,8 +257,7 @@ class SpeculativeEngine:
         (logits, cache), _ = functional_call(
             self.model, params, buffers, toks, cache=cache,
             position_offset=pos)
-        cache = _constrain_cache(cache, toks.shape[0],
-                                 self.spec["num_kv_heads"])
+        cache = constrain_cache(cache)
         B = D.shape[0]
         cols = jnp.arange(K + 1, dtype=jnp.int32)
         if greedy:

@@ -66,7 +66,7 @@ fake_quant = quant_dequant
 # pair: int8 values plus per-(row, position, head) float32 abs-max scales
 # [B, S, Hkv, 1]. Keeping the scale 4-D (trailing axis 1 instead of a
 # squeezed [B, S, Hkv]) means every cache pytree primitive in
-# ``models/generation.py`` — row slice/scatter, block gather/scatter,
+# ``models/kv_cache.py`` — row scatter, block gather/scatter,
 # sharding constraints — works on both leaves unchanged via jax.tree
 # maps. Symmetric quantization to ±127 so dequant is a single multiply.
 

@@ -10,13 +10,13 @@ dimension as ``B`` independent *slots*:
 
 - **admit** runs the existing bucketed prefill at batch 1 and — inside
   the same compiled program — writes its keys and values into the live
-  batch's row at a *traced* slot index (``lm_utils.CacheRow``) and samples
-  the request's first token. One program per prefill bucket, for every
-  slot. (With a prefix pool the slot's row is assembled from pool blocks
-  and scattered in, ``generation.scatter_cache_rows``.)
+  batch's row at a *traced* slot index (``kv_cache.cache_row_view``) and
+  samples the request's first token. One program per prefill bucket, for
+  every slot. (With a prefix pool the slot's row is assembled from pool blocks
+  and scattered in, ``kv_cache.scatter_cache_rows``.)
 - **step** advances ALL slots one token with a *vector* of per-slot
   positions (the ``[B]`` ``position_offset`` path through
-  ``lm_utils.cached_attention`` / ``update_kv_cache`` and the models'
+  ``kv_cache.cached_attention`` / ``update_kv_cache`` and the models'
   position tables), per-slot PRNG keys / eos ids / sampling params, and a
   traced greedy mask. Exactly ONE compiled program, regardless of which
   requests currently share the batch.
@@ -35,6 +35,7 @@ the batch.
 """
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
@@ -44,13 +45,14 @@ import numpy as np
 
 from ..framework import compile_cache
 from ..io.batching import bucket_for
-from ..models.generation import (DEFAULT_PREFILL_BUCKETS, _constrain_cache,
-                                 cache_entries, cache_nbytes,
-                                 gather_cache_blocks,
-                                 init_cache, normalize_kv_dtype,
-                                 per_row_keys, sample_logits_rows,
-                                 scatter_cache_blocks, scatter_cache_rows)
-from ..models.lm_utils import CacheRow, cache_write_paths
+from ..models.generation import (DEFAULT_PREFILL_BUCKETS, per_row_keys,
+                                 sample_logits_rows)
+from ..models.kv_cache import (cache_entries, cache_geometry, cache_nbytes,
+                               cache_row_buffers, cache_row_view,
+                               cache_write_paths, constrain_cache,
+                               gather_cache_blocks, init_cache,
+                               normalize_kv_dtype, scatter_cache_blocks,
+                               scatter_cache_rows)
 from ..lora import adapter_rows as _adapter_rows_ctx
 from ..lora.store import AdapterStore, normalize_adapter_id
 from ..nn.layer import buffer_state, functional_call, param_state
@@ -106,19 +108,11 @@ class ContinuousBatchingEngine:
         if slots < 1:
             raise ValueError(f"need at least one slot, got {slots}")
         self.model = model
-        spec = model.cache_spec()
-        self.spec = spec
+        self.spec = spec = model.cache_spec()
         self.kv_dtype = normalize_kv_dtype(kv_dtype)
         self.slots = int(slots)
-        self.max_length = int(max_length or spec["max_length"])
-        if self.max_length > spec["max_length"]:
-            raise ValueError(
-                f"max_length {self.max_length} exceeds the model's position "
-                f"table ({spec['max_length']} positions)")
-        buckets = tuple(sorted(int(b) for b in
-                               (prefill_buckets or DEFAULT_PREFILL_BUCKETS)
-                               if int(b) <= self.max_length))
-        self.prefill_buckets = buckets or (self.max_length,)
+        self.max_length, self.prefill_buckets = cache_geometry(
+            spec, max_length, prefill_buckets or DEFAULT_PREFILL_BUCKETS)
         self.top_k = int(top_k)
         self.allow_top_p = bool(allow_top_p)
         self.pool = self._normalize_pool(prefix_cache)
@@ -278,24 +272,19 @@ class ContinuousBatchingEngine:
         return self.active_count / self.slots
 
     # ----------------------------------------------------- compiled fns
+    @contextlib.contextmanager
     def _eval_mode(self):
         """Serving must trace the EVAL graph (dropout off) even if the
         model is mid-fit; the flag is read at trace time only, so every
         dispatch site (a novel bucket may trace at any admit) flips it
         and restores — same discipline as GenerationEngine.generate."""
-        import contextlib
-
-        @contextlib.contextmanager
-        def guard():
-            was_training = self.model.training
-            self.model.eval()
-            try:
-                yield
-            finally:
-                if was_training:
-                    self.model.train()
-
-        return guard()
+        was_training = self.model.training
+        self.model.eval()
+        try:
+            yield
+        finally:
+            if was_training:
+                self.model.train()
 
     def cache_bytes_per_slot(self) -> int:
         """HBM bytes one slot's KV occupies in the live batch — the
@@ -305,27 +294,25 @@ class ContinuousBatchingEngine:
     def _prefill_fn(self, params, buffers, live_cache, ids, slot,
                     last_index, key, eos_id, temperature, top_p, greedy):
         """Bucketed batch-1 prefill that writes its keys and values
-        straight into row ``slot`` of the live batch (``CacheRow``): no
-        single-slot cache exists, inside this program or out of it, so
+        straight into row ``slot`` of the live batch (``cache_row_view``):
+        no single-slot cache exists, inside this program or out of it, so
         admission costs one compile per bucket — not per bucket per slot,
         and no separate scatter program. Positions past the bucket keep
         what the slot's last request left there, behind the position
         mask like the rest of a reused slot."""
-        row = jax.tree.map(lambda x: CacheRow(x, slot), live_cache)
+        row = cache_row_view(live_cache, slot)
         with jax.named_scope("prefill"):
             (logits, row), _ = functional_call(
                 self.model, params, buffers, ids, cache=row,
                 position_offset=0, gather_last=last_index)
-        live_cache = jax.tree.map(lambda r: r.buf, row,
-                                  is_leaf=lambda r: isinstance(r, CacheRow))
+        live_cache = cache_row_buffers(row)
         logits = logits[:, 0, :]
         rows = per_row_keys(key, 1)
         next_tok = sample_logits_rows(
             logits, rows, temperature, self.top_k, top_p,
             use_top_p=self.allow_top_p,
             greedy_mask=jnp.asarray(greedy).reshape(1))
-        live_cache = _constrain_cache(live_cache, self.slots,
-                                      self.spec["num_kv_heads"])
+        live_cache = constrain_cache(live_cache)
         done = next_tok[0] == eos_id
         return next_tok[0], done, live_cache
 
@@ -359,8 +346,7 @@ class ContinuousBatchingEngine:
             greedy_mask=jnp.asarray(greedy).reshape(1))
         pool = scatter_cache_blocks(pool, slot_cache, write_idx)
         live_cache = scatter_cache_rows(live_cache, slot_cache, slot)
-        live_cache = _constrain_cache(live_cache, self.slots,
-                                      self.spec["num_kv_heads"])
+        live_cache = constrain_cache(live_cache)
         done = next_tok[0] == eos_id
         return next_tok[0], done, live_cache, pool
 
@@ -391,8 +377,7 @@ class ContinuousBatchingEngine:
                 self.model, params, buffers, tokens, cache=live_cache,
                 position_offset=positions)
         self._cache_write = "dma" if paths == {"dma"} else "scatter"
-        live_cache = _constrain_cache(live_cache, self.slots,
-                                      self.spec["num_kv_heads"])
+        live_cache = constrain_cache(live_cache)
         logits = logits[:, -1, :]
         # per-slot streams: each slot replays the batch-1 generate() key
         # derivation (per_row_keys at batch=1 — ONE shared definition), so
@@ -512,7 +497,6 @@ class ContinuousBatchingEngine:
             self.pool.abort(hit)
             raise
         return hit, plan
-
 
     def admit(self, request, slot: int) -> Tuple[int, bool, int]:
         """Prefill ``request`` into free ``slot``; returns the first
@@ -700,7 +684,7 @@ class ContinuousBatchingEngine:
         live cache's geometry: its entries (one per layer application
         that writes keys and values) and the bytes a token holds in all
         of them. ``cache_write`` is the way the decode program lands a
-        step's keys and values (``lm_utils.update_kv_cache``), None
+        step's keys and values (``kv_cache.update_kv_cache``), None
         until it has been traced."""
         return {"prefill": compile_cache.cache_stats(self._cc_prefill),
                 "decode": compile_cache.cache_stats(self._cc_decode),

@@ -29,7 +29,7 @@ around in a *paged pool*:
 
 The pool owns only metadata + the tensors; the fused admit program in
 ``serving.engine`` does the actual block copies in-program via
-``models.generation.gather_cache_blocks`` / ``scatter_cache_blocks``.
+``models.kv_cache.gather_cache_blocks`` / ``scatter_cache_blocks``.
 All metadata methods are thread-safe (the router's affinity scoring
 calls :meth:`match` from client threads while the serving worker
 admits).
@@ -43,7 +43,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..models.generation import cache_entries, cache_layout, normalize_kv_dtype
+from ..models.kv_cache import (alloc_cache, cache_entries, cache_layout,
+                               cache_token_nbytes, normalize_kv_dtype)
 
 __all__ = ["BlockPool", "PrefixHit", "StorePlan", "chain_digests",
            "KV_WIRE_VERSION", "DEFAULT_MIGRATE_CHUNK_BYTES",
@@ -158,8 +159,6 @@ class BlockPool:
                  max_bytes: int = 64 << 20,
                  max_length: Optional[int] = None,
                  max_blocks: int = 4096, kv_dtype=None):
-        from ..framework.dtype import convert_dtype
-
         spec = model.cache_spec()
         self.spec = spec
         self.block_tokens = int(block_tokens)
@@ -171,20 +170,11 @@ class BlockPool:
             raise ValueError(
                 f"block_tokens {block_tokens} exceeds max_length "
                 f"{self.max_length}: no prompt could ever cache a block")
-        self._dtype = convert_dtype(spec["dtype"])
         self.kv_dtype = normalize_kv_dtype(kv_dtype)
-        itemsize = (2 if "bfloat16" in str(self._dtype)
-                    else np.dtype(self._dtype).itemsize)
-        if self.kv_dtype == "int8":
-            # int8 value + one float32 per-(position, head) scale: the
-            # byte budget buys ~itemsize*D/(D+4) times more blocks
-            per_pos_head = spec["head_dim"] + 4
-        else:
-            per_pos_head = spec["head_dim"] * itemsize
         # one block holds its tokens' keys and values in EVERY cache
         # entry (a looped model has more entries than layers)
-        self.block_bytes = (2 * cache_entries(spec) * self.block_tokens
-                            * spec["num_kv_heads"] * per_pos_head)
+        self.block_bytes = self.block_tokens * cache_token_nbytes(
+            spec, kv_dtype=self.kv_dtype)
         budget_blocks = max(1, int(max_bytes) // max(self.block_bytes, 1))
         # +1: row 0 is the reserved dump block, never allocated
         self.num_blocks = 1 + min(budget_blocks, int(max_blocks))
@@ -212,20 +202,8 @@ class BlockPool:
 
     # ---------------------------------------------------------- storage
     def _alloc_tensors(self):
-        import jax.numpy as jnp
-
-        pairs, stack = cache_layout(self.spec)
-        shape = (self.num_blocks,) + stack + (
-            self.block_tokens, self.spec["num_kv_heads"],
-            self.spec["head_dim"])
-
-        def entry():
-            if self.kv_dtype == "int8":
-                return (jnp.zeros(shape, jnp.int8),
-                        jnp.zeros(shape[:-1] + (1,), jnp.float32))
-            return jnp.zeros(shape, self._dtype)
-
-        return tuple((entry(), entry()) for _ in range(pairs))
+        return alloc_cache(self.spec, self.num_blocks, self.block_tokens,
+                           kv_dtype=self.kv_dtype)
 
     def compatible_with(self, spec: dict, max_length: int,
                         kv_dtype=None) -> None:

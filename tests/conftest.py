@@ -51,6 +51,36 @@ def interpret_pallas():
         yield calls
 
 
+@pytest.fixture()
+def show_the_gate_a_tpu(monkeypatch):
+    """A callable after which the cache write's gate
+    (``kv_cache._rows_by_dma``) sees a TPU backend, for the length of
+    the gate's own call only: nothing else in the process takes the CPU
+    for a TPU."""
+    from unittest import mock
+
+    from paddle_tpu.models import kv_cache
+
+    def show():
+        real = kv_cache._rows_by_dma
+
+        def gate(*args):
+            with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+                return real(*args)
+
+        monkeypatch.setattr(kv_cache, "_rows_by_dma", gate)
+
+    return show
+
+
+@pytest.fixture()
+def as_on_tpu(show_the_gate_a_tpu, interpret_pallas):
+    """The gate sees a TPU backend; the kernels it then chooses run
+    interpreted, and the fixture's value lists them."""
+    show_the_gate_a_tpu()
+    return interpret_pallas
+
+
 def pytest_configure(config):
     # tier-1 runs `-m 'not slow'` (ROADMAP): long decode/bench subprocess
     # tests opt out of the 870 s budget with this marker

@@ -1,5 +1,5 @@
 """The decode step's per-slot cache write (``kernels/cache_write.py``)
-against the scatter it stands in for (``lm_utils._write``): the kernels in
+against the scatter it stands in for (``kv_cache._write``): the kernels in
 Pallas interpret mode, bit for bit over the whole leaf; the gate that
 chooses between the two; and engines decoding the same tokens either way.
 On the CPU the programs themselves always take the scatter."""
@@ -12,7 +12,7 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu.kernels import cache_write
-from paddle_tpu.models import lm_utils
+from paddle_tpu.models import kv_cache, lm_utils
 from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny
 from paddle_tpu.models.ouro import OuroForCausalLM, ouro_tiny
 from paddle_tpu.quantization import kv_quantize
@@ -50,8 +50,8 @@ def _operands(shape, dtype, seed=0):
 
 def _scatter(k, v, nk, nv, pos, entry):
     zero = jnp.zeros((), jnp.int32)
-    return (lm_utils._write(k, nk, pos, entry, zero),
-            lm_utils._write(v, nv, pos, entry, zero))
+    return (kv_cache._write(k, nk, pos, entry, zero),
+            kv_cache._write(v, nv, pos, entry, zero))
 
 
 def _same(got, want):
@@ -116,26 +116,6 @@ def test_positions_past_the_leaf_are_clamped_as_the_scatter_clamps(
 
 
 # --------------------------------------------------------------- the gate
-def _show_the_gate_a_tpu(monkeypatch):
-    """For the length of the gate's own call only: nothing else in the
-    process takes the CPU for a TPU."""
-    real = lm_utils._rows_by_dma
-
-    def gate(*args):
-        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
-            return real(*args)
-
-    monkeypatch.setattr(lm_utils, "_rows_by_dma", gate)
-
-
-@pytest.fixture()
-def as_on_tpu(monkeypatch, interpret_pallas):
-    """The gate sees a TPU backend; the kernels it then chooses run
-    interpreted, and the fixture's value lists them."""
-    _show_the_gate_a_tpu(monkeypatch)
-    return interpret_pallas
-
-
 def _update(case):
     """``update_kv_cache`` on a Medium-shaped pair, varied by ``case``;
     returns (result, the paths it noted, what the scatter alone gives)."""
@@ -155,12 +135,12 @@ def _update(case):
     elif case == "int8-pair":
         k, v = kv_quantize(k), kv_quantize(v)
     elif case == "cache-row":
-        k, v = (lm_utils.CacheRow(x, jnp.int32(2)) for x in (k, v))
+        k, v = (kv_cache.CacheRow(x, jnp.int32(2)) for x in (k, v))
         nk, nv, pos = nk[:1], nv[:1], jnp.int32(9)
-    with lm_utils.cache_write_paths() as paths:
-        got = lm_utils.update_kv_cache((k, v), nk, nv, pos)
-    with mock.patch.object(lm_utils, "_rows_by_dma", lambda *a: False):
-        want = lm_utils.update_kv_cache((k, v), nk, nv, pos)
+    with kv_cache.cache_write_paths() as paths:
+        got = kv_cache.update_kv_cache((k, v), nk, nv, pos)
+    with mock.patch.object(kv_cache, "_rows_by_dma", lambda *a: False):
+        want = kv_cache.update_kv_cache((k, v), nk, nv, pos)
     return got, paths, want
 
 
@@ -225,13 +205,13 @@ def one_chip():
     ("gpt3-xl.serve-batch", (24, 2048, 16, 128)),
     ("ouro-2.6b.serve-longgen", (5, 4, 1024, 16, 128))])
 def test_a_layers_write_and_read_compile_for_the_chip_in_place(
-        one_chip, monkeypatch, cell, shape):
+        one_chip, show_the_gate_a_tpu, cell, shape):
     """The cell's cache pair through ``attend_with_cache`` as the decode
     program runs it (donated; the stacked leaves under a ``scan`` over
     their entries): the kernel is in the program, the scatter's ``while``
     is not, and no copy of a leaf is (the leaves alias their outputs and
     the program needs no temporary the size of one)."""
-    _show_the_gate_a_tpu(monkeypatch)
+    show_the_gate_a_tpu()
     slots, (heads, dim) = shape[0], shape[-2:]
     stacked = len(shape) == 5
 
@@ -254,7 +234,7 @@ def test_a_layers_write_and_read_compile_for_the_chip_in_place(
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     row = arg((slots, 1, heads, dim))
-    with lm_utils.cache_write_paths() as paths:
+    with kv_cache.cache_write_paths() as paths:
         compiled = jax.jit(program, donate_argnums=(0, 1)).lower(
             arg(shape), arg(shape), row, row, row,
             arg((slots,), jnp.int32)).compile()
@@ -302,14 +282,14 @@ def _decode(model, cfg, steps=5):
 
 @pytest.mark.parametrize("build", [_tiny_gpt, _tiny_looped])
 def test_engine_decodes_the_same_tokens_on_either_path(
-        build, monkeypatch, interpret_pallas):
+        build, show_the_gate_a_tpu, interpret_pallas):
     pt.seed(3)
     model, cfg, kernel = build()
     model.eval()
     plain, eng = _decode(model, cfg)
     assert eng.cache_stats()["cache_write"] == "scatter"
     assert interpret_pallas == []
-    _show_the_gate_a_tpu(monkeypatch)
+    show_the_gate_a_tpu()
     direct, eng = _decode(model, cfg)
     assert eng.cache_stats()["cache_write"] == "dma"
     assert set(interpret_pallas) == {kernel}

@@ -98,10 +98,10 @@ def test_serve_phase_notices_the_scatter():
             n_requests=8, new_tokens=(4, 8), expect_donation=False)
 
 
-def test_cache_write_check_tiny(interpret_pallas):
+def test_cache_write_check_tiny(as_on_tpu):
     chip_smoke.cache_write_check(
         leaves=((3, 128, 16, 64), (3, 16, 16, 128), (2, 3, 16, 16, 128)))
-    assert sorted(set(interpret_pallas)) == [
+    assert sorted(set(as_on_tpu)) == [
         "_copy_rows_kernel", "_merge_columns_kernel"]
 
 

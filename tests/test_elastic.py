@@ -197,6 +197,9 @@ def test_elastic_kill_node_resumes_smaller_world(tmp_path):
             pa.kill(); pb.kill()
             raise AssertionError("two-node world never started training")
         step_at_kill = json.load(open(ckpt))["step"]
+        # with --nnodes 1:2 node A may have trained alone (world=1, from
+        # step 1) until B joined: those traces are not the resize's
+        before_kill = set(state.glob("trace.*.log"))
         # SIGKILL node B's whole process group (launcher + its worker):
         # lease expires with no goodbye, exactly like a host loss
         os.killpg(pb.pid, signal.SIGKILL)
@@ -209,7 +212,7 @@ def test_elastic_kill_node_resumes_smaller_world(tmp_path):
     # resumed, not restarted: every post-resize (world=1) trace must begin
     # at or after the checkpointed kill-time step, never back at 1
     resumed_starts = []
-    for trace in state.glob("trace.*.log"):
+    for trace in set(state.glob("trace.*.log")) - before_kill:
         w1_steps = [int(line.split()[0]) for line in
                     trace.read_text().splitlines() if line.endswith(" 1")]
         if w1_steps:

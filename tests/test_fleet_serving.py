@@ -532,8 +532,8 @@ def test_gather_scatter_cache_blocks_roundtrip():
     into pool blocks, gather it back at the same indices — identical;
     dump-row writes never corrupt real blocks. Eager: no compile."""
     import jax.numpy as jnp
-    from paddle_tpu.models.generation import (gather_cache_blocks,
-                                              scatter_cache_blocks)
+    from paddle_tpu.models.kv_cache import (gather_cache_blocks,
+                                            scatter_cache_blocks)
 
     rng = np.random.default_rng(0)
     pool = tuple((jnp.asarray(rng.normal(size=(6, 4, 2, 3)), jnp.float32),

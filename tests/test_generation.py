@@ -54,7 +54,7 @@ def llama_model():
 def _assert_cached_matches_full(model, cfg, prefill_len=3, total_len=9):
     """Prefill ``prefill_len`` tokens, decode the rest one-by-one, and
     compare every position's logits against the full-sequence forward."""
-    from paddle_tpu.models.generation import init_cache
+    from paddle_tpu.models.kv_cache import init_cache
 
     ids = np.random.default_rng(0).integers(
         0, cfg.vocab_size, (2, total_len)).astype(np.int32)
@@ -328,7 +328,7 @@ def test_vector_position_offset_matches_scalar_decode(llama_model):
     per-row (staggered) positions reproduces the full-forward logits —
     RoPE tables, the causal mask frontier, and the GQA cache write all
     index per row. Eager (no jit), so tier-1 pays no extra compiles."""
-    from paddle_tpu.models.generation import init_cache
+    from paddle_tpu.models.kv_cache import init_cache
 
     model, cfg = llama_model
     ids = np.random.default_rng(13).integers(
@@ -354,7 +354,7 @@ def test_cache_sharding_spec_on_mesh():
     """On a dp×mp mesh the cache shards batch over dp and kv heads over
     mp; indivisible kv heads stay replicated rather than erroring."""
     from paddle_tpu.distributed.mesh import init_mesh
-    from paddle_tpu.models.generation import cache_sharding_spec
+    from paddle_tpu.models.kv_cache import cache_sharding_spec
 
     init_mesh(dp=2, mp=2)
     spec = cache_sharding_spec(batch=4, n_kv_heads=4)

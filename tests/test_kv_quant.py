@@ -71,7 +71,7 @@ def test_is_quantized_kv_predicate():
 
 
 def test_cache_pytree_bytes_halved(gpt_model):
-    from paddle_tpu.models.generation import cache_nbytes, init_cache
+    from paddle_tpu.models.kv_cache import cache_nbytes, init_cache
 
     model, _ = gpt_model
     full = cache_nbytes(init_cache(model, 4, 64))
@@ -152,7 +152,7 @@ def test_zero_adapter_noop_on_quantized_base():
 
 
 def test_quantized_cache_logit_drift_bounded(gpt_model):
-    from paddle_tpu.models.generation import init_cache
+    from paddle_tpu.models.kv_cache import init_cache
     from paddle_tpu.nn.layer import (buffer_state, functional_call,
                                      param_state)
 

@@ -73,7 +73,7 @@ def test_generate_programs_name_prefill_decode_and_sample(cfg):
     m = GPTForCausalLM(cfg)
     m.eval()
     eng = GenerationEngine(m, max_length=32, prefill_buckets=(16,))
-    from paddle_tpu.models.generation import init_cache
+    from paddle_tpu.models.kv_cache import init_cache
     from paddle_tpu.nn.layer import buffer_state, param_state
 
     args = (param_state(m), buffer_state(m), init_cache(m, 1, 32))

@@ -22,7 +22,7 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu.framework import compile_cache
 from paddle_tpu.framework.jit import param_state
-from paddle_tpu.models.generation import cache_nbytes, init_cache
+from paddle_tpu.models.kv_cache import cache_nbytes, init_cache
 from paddle_tpu.models.ouro import OuroConfig, OuroForCausalLM, ouro_tiny
 from paddle_tpu.serving.engine import ContinuousBatchingEngine
 from paddle_tpu.serving.scheduler import Request

@@ -119,8 +119,9 @@ def main(argv=None) -> int:
     import jax
 
     from paddle_tpu.framework import compile_cache
-    from paddle_tpu.models.generation import (GenerationEngine, cache_nbytes,
-                                              init_cache, normalize_kv_dtype)
+    from paddle_tpu.models.generation import GenerationEngine
+    from paddle_tpu.models.kv_cache import (cache_nbytes, init_cache,
+                                            normalize_kv_dtype)
     from paddle_tpu.observability import default_registry, tracing
 
     model, cfg = build_model(args.model, args.preset)

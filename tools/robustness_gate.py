@@ -376,6 +376,7 @@ def _run_decode_gate() -> bool:
                  os.path.join(REPO, ".tpu_lint_baseline.json"),
                  os.path.join(REPO, "paddle_tpu/models/speculative.py"),
                  os.path.join(REPO, "paddle_tpu/models/generation.py"),
+                 os.path.join(REPO, "paddle_tpu/models/kv_cache.py"),
                  os.path.join(REPO, "paddle_tpu/models/lm_utils.py"),
                  os.path.join(REPO, "paddle_tpu/quantization/__init__.py"),
                  os.path.join(REPO, "tools/decode_bench.py")])

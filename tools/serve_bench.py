@@ -96,7 +96,7 @@ def _kv_logit_error(model, prompt, steps, max_length):
     the decode steps."""
     import jax.numpy as jnp
 
-    from paddle_tpu.models.generation import init_cache
+    from paddle_tpu.models.kv_cache import init_cache
     from paddle_tpu.nn.layer import (buffer_state, functional_call,
                                      param_state)
 
