@@ -8,6 +8,14 @@ wire format and ``DecoderBlockList`` index the tuple, so it stays a plain
 pytree under free functions. The models reach them through
 ``lm_utils.attend_with_cache`` alone, the engines and the prefix pool
 directly; this module imports nothing of theirs.
+
+The continuous-batching decode step (one token a slot, each slot at its
+own position) has a kernel for each half, and this module is their one
+importer: the write as direct copies (:mod:`..kernels.cache_write`, gate
+:func:`_rows_by_dma`) and the read by position, over the blocks that a
+slot's positions fill and no others (:mod:`..kernels.cache_read`, gate
+:func:`_reads_by_position`). A gate reads what the trace shows (backend,
+mesh, shapes, dtypes); every other shape keeps XLA's path.
 """
 from __future__ import annotations
 
@@ -21,14 +29,14 @@ import jax.numpy as jnp
 
 from ..distributed.mesh import get_mesh, sharding
 from ..framework.dtype import convert_dtype
-from ..kernels import cache_write
+from ..kernels import cache_read, cache_write
 from ..quantization import is_quantized_kv, kv_dequantize, kv_quantize
 
 __all__ = ["cache_entries", "cache_layout", "cache_sharding_spec",
            "normalize_kv_dtype", "alloc_cache", "init_cache", "cache_nbytes",
            "cache_token_nbytes", "constrain_cache", "cache_geometry",
            "CacheRow", "cache_row_view", "cache_row_buffers",
-           "update_kv_cache", "cache_write_paths", "cached_attention",
+           "update_kv_cache", "cache_paths", "cached_attention",
            "scatter_cache_rows", "gather_cache_blocks",
            "scatter_cache_blocks"]
 
@@ -235,7 +243,7 @@ def _write(buf, new, pos, entry, row):
     if entry is not None:
         new = new[:, None]
     if pos.ndim == 1:
-        _note_write("scatter")
+        _note("write", "scatter")
 
         def write(c, n, p):
             return jax.lax.dynamic_update_slice(
@@ -248,26 +256,37 @@ def _write(buf, new, pos, entry, row):
 
 # Trace-time state, thread-local as the adapter context of lora.layers
 # is: the serving engine opens it around the trace of its decode program.
-_WRITES = threading.local()
+_PATHS = threading.local()
 
 
 @contextlib.contextmanager
-def cache_write_paths():
-    """The set of ways the program traced under this context issues its
-    per-slot cache writes: ``"dma"`` (:mod:`..kernels.cache_write`) or
-    ``"scatter"`` (the vmapped ``dynamic_update_slice``)."""
-    outer = getattr(_WRITES, "paths", None)
-    paths = _WRITES.paths = set()
+def cache_paths():
+    """``{"write": set, "read": set}``: the ways the program traced under
+    this context issues its per-slot cache writes, ``"dma"``
+    (:mod:`..kernels.cache_write`) or ``"scatter"`` (the vmapped
+    ``dynamic_update_slice``), and its cache reads, ``"kernel"``
+    (:mod:`..kernels.cache_read`) or ``"xla"`` (the masked einsums)."""
+    outer = getattr(_PATHS, "noted", None)
+    noted = _PATHS.noted = {"write": set(), "read": set()}
     try:
-        yield paths
+        yield noted
     finally:
-        _WRITES.paths = outer
+        _PATHS.noted = outer
 
 
-def _note_write(path: str) -> None:
-    paths = getattr(_WRITES, "paths", None)
-    if paths is not None:
-        paths.add(path)
+def _note(kind: str, path: str) -> None:
+    noted = getattr(_PATHS, "noted", None)
+    if noted is not None:
+        noted[kind].add(path)
+
+
+def _per_slot_on_one_tpu(pos) -> bool:
+    """What both kernels' gates ask first: ``[B]`` positions (the
+    continuous-batching decode step), a TPU, and no mesh over more than
+    one device."""
+    mesh = get_mesh()
+    return (pos.ndim == 1 and jax.default_backend() == "tpu"
+            and (mesh is None or mesh.size == 1))
 
 
 def _rows_by_dma(k_cache, v_cache, new, pos) -> bool:
@@ -275,10 +294,7 @@ def _rows_by_dma(k_cache, v_cache, new, pos) -> bool:
     shows: the kernel takes a TPU's per-slot (``[B]``-position) write of
     one token into plain leaves on one device whose rows are whole tiles
     (:func:`cache_write.rows_fit`); the scatter takes everything else."""
-    mesh = get_mesh()
-    return (pos.ndim == 1 and jax.default_backend() == "tpu"
-            and (mesh is None or mesh.size == 1)
-            and cache_write.rows_fit(k_cache, new)
+    return (_per_slot_on_one_tpu(pos) and cache_write.rows_fit(k_cache, new)
             and cache_write.rows_fit(v_cache, new))
 
 
@@ -311,7 +327,7 @@ def update_kv_cache(cache, k_new, v_new, position_offset, entry=None):
                  _write_window(v_cache[1], vs, pos, entry)))
     # tpu-lint: disable=R2(the gate reads the backend and the leaves' static type, shape and dtype — one program per cache layout)
     if _rows_by_dma(k_cache, v_cache, k_new, pos):
-        _note_write("dma")
+        _note("write", "dma")
         return cache_write.write_rows(k_cache, v_cache, k_new, v_new, pos,
                                       entry)
     return (_write_window(k_cache, k_new, pos, entry),
@@ -319,6 +335,17 @@ def update_kv_cache(cache, k_new, v_new, position_offset, entry=None):
 
 
 # ------------------------------------------------------------------ read
+def _reads_by_position(q, k_cache, v_cache, pos) -> bool:
+    """One operation, two ways to issue it, told apart by what the trace
+    shows, as :func:`_rows_by_dma` tells the write's: the kernel takes a
+    TPU's per-slot (``[B]``-position) read for one query a slot from
+    plain leaves on one device whose blocks are whole tiles
+    (:func:`cache_read.reads_fit`); :func:`_read_whole` takes everything
+    else."""
+    return (_per_slot_on_one_tpu(pos) and cache_read.reads_fit(k_cache, q)
+            and cache_read.reads_fit(v_cache, q))
+
+
 def cached_attention(q, k_cache, v_cache, position_offset, entry=None):
     """Dot-product attention of ``q`` [B, L, H, D] against the FULL cache
     [B, S, Hkv, D] (entry ``entry`` of ``[B, E, S, Hkv, D]`` leaves where
@@ -330,7 +357,25 @@ def cached_attention(q, k_cache, v_cache, position_offset, entry=None):
     position). GQA is a grouped einsum — the kv heads are never repeated
     into [B, S, H, D]. int8-quantized caches (``(values, scales)``
     entries) dequantize here, on read — the [B, S, Hkv, D] buffers stay
-    int8 in HBM and only this program's working set pays the upcast."""
+    int8 in HBM and only this program's working set pays the upcast.
+
+    The continuous-batching decode step's shape (one query a slot, a
+    ``[B]`` vector of positions, plain leaves on one TPU) reads only the
+    blocks that positions ``0 ... position_offset[b]`` of slot b fill
+    (:func:`_reads_by_position`), with f32 scores and softmax."""
+    # tpu-lint: disable=R2(the gate reads the backend and the leaves' static type, shape and dtype — one program per cache layout)
+    if _reads_by_position(q, k_cache, v_cache,
+                          jnp.asarray(position_offset, jnp.int32)):
+        _note("read", "kernel")
+        return cache_read.read_by_position(q, k_cache, v_cache,
+                                           position_offset, entry)
+    _note("read", "xla")
+    return _read_whole(q, k_cache, v_cache, position_offset, entry)
+
+
+def _read_whole(q, k_cache, v_cache, position_offset, entry=None):
+    """:func:`cached_attention` as XLA issues it: two einsums over every
+    position of the leaf, under the mask."""
     if entry is not None:
         k_cache, v_cache = jax.tree.map(
             lambda x: jax.lax.dynamic_index_in_dim(x, entry, 1,

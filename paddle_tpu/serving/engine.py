@@ -16,8 +16,10 @@ dimension as ``B`` independent *slots*:
   and scattered in, ``kv_cache.scatter_cache_rows``.)
 - **step** advances ALL slots one token with a *vector* of per-slot
   positions (the ``[B]`` ``position_offset`` path through
-  ``kv_cache.cached_attention`` / ``update_kv_cache`` and the models'
-  position tables), per-slot PRNG keys / eos ids / sampling params, and a
+  ``kv_cache.cached_attention`` / ``update_kv_cache``, on a TPU both
+  through a kernel: a slot's new row lands by direct copy and only the
+  blocks its positions fill are read; and the models' position
+  tables), per-slot PRNG keys / eos ids / sampling params, and a
   traced greedy mask. Exactly ONE compiled program, regardless of which
   requests currently share the batch.
 
@@ -48,8 +50,8 @@ from ..io.batching import bucket_for
 from ..models.generation import (DEFAULT_PREFILL_BUCKETS, per_row_keys,
                                  sample_logits_rows)
 from ..models.kv_cache import (cache_entries, cache_geometry, cache_nbytes,
-                               cache_row_buffers, cache_row_view,
-                               cache_write_paths, constrain_cache,
+                               cache_paths, cache_row_buffers,
+                               cache_row_view, constrain_cache,
                                gather_cache_blocks, init_cache,
                                normalize_kv_dtype, scatter_cache_blocks,
                                scatter_cache_rows)
@@ -126,9 +128,11 @@ class ContinuousBatchingEngine:
             f"serve:prefill:{model_name}")
         self._cc_decode = compile_cache.register_name(
             f"serve:decode:{model_name}")
-        #: how the decode program writes a step's keys and values, "dma"
-        #: or "scatter": known once it has been traced
+        #: how the decode program writes a step's keys and values ("dma"
+        #: or "scatter") and reads the cache for attention ("kernel" or
+        #: "xla"): known once it has been traced
         self._cache_write: Optional[str] = None
+        self._cache_read: Optional[str] = None
         on_device = jax.default_backend() != "cpu"
         lora = self.store is not None
         if self.pool is not None:
@@ -372,11 +376,12 @@ class ContinuousBatchingEngine:
 
     def _decode_fn(self, params, buffers, live_cache, tokens, positions,
                    keys, done, eos, temperature, top_p, greedy_mask):
-        with jax.named_scope("decode"), cache_write_paths() as paths:
+        with jax.named_scope("decode"), cache_paths() as paths:
             (logits, live_cache), _ = functional_call(
                 self.model, params, buffers, tokens, cache=live_cache,
                 position_offset=positions)
-        self._cache_write = "dma" if paths == {"dma"} else "scatter"
+        self._cache_write = "dma" if paths["write"] == {"dma"} else "scatter"
+        self._cache_read = "kernel" if paths["read"] == {"kernel"} else "xla"
         live_cache = constrain_cache(live_cache)
         logits = logits[:, -1, :]
         # per-slot streams: each slot replays the batch-1 generate() key
@@ -684,11 +689,15 @@ class ContinuousBatchingEngine:
         live cache's geometry: its entries (one per layer application
         that writes keys and values) and the bytes a token holds in all
         of them. ``cache_write`` is the way the decode program lands a
-        step's keys and values (``kv_cache.update_kv_cache``), None
+        step's keys and values (``kv_cache.update_kv_cache``: ``"dma"``
+        or ``"scatter"``) and ``cache_read`` the way it reads the cache
+        for attention (``kv_cache.cached_attention``: ``"kernel"``, by
+        position, or ``"xla"``, the whole leaf under a mask), both None
         until it has been traced."""
         return {"prefill": compile_cache.cache_stats(self._cc_prefill),
                 "decode": compile_cache.cache_stats(self._cc_decode),
                 "cache_write": self._cache_write,
+                "cache_read": self._cache_read,
                 "cache_entries": cache_entries(self.spec),
                 "cache_bytes_per_token":
                     self.cache_bytes_per_slot() // self.max_length}

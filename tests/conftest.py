@@ -31,12 +31,13 @@ import pytest  # noqa: E402
 
 @pytest.fixture()
 def interpret_pallas():
-    """Run the Pallas kernels (flash attention, the cache write: both
-    reach ``pallas_call`` through the one ``pallas`` module) in interpret
+    """Run the Pallas kernels (flash attention, the cache write and read:
+    all reach ``pallas_call`` through the one ``pallas`` module) in interpret
     mode (the CPU has no Mosaic); yields the list of pallas_call
     invocations so a test can see that the kernels were really traced."""
     from unittest import mock
 
+    from paddle_tpu.kernels import cache_read
     from paddle_tpu.kernels import flash_attention as fa
 
     orig = fa.pl.pallas_call
@@ -47,28 +48,35 @@ def interpret_pallas():
         k["interpret"] = True
         return orig(*a, **k)
 
+    # the read is jitted on its own: no trace of it crosses this fixture
+    cache_read.read_by_position.clear_cache()
     with mock.patch.object(fa.pl, "pallas_call", interp):
         yield calls
+    cache_read.read_by_position.clear_cache()
 
 
 @pytest.fixture()
 def show_the_gate_a_tpu(monkeypatch):
-    """A callable after which the cache write's gate
-    (``kv_cache._rows_by_dma``) sees a TPU backend, for the length of
-    the gate's own call only: nothing else in the process takes the CPU
-    for a TPU."""
+    """A callable after which the gates of the decode step's cache
+    kernels (``kv_cache._rows_by_dma`` for the write,
+    ``kv_cache._reads_by_position`` for the read) see a TPU backend, for
+    the length of a gate's own call only: nothing else in the process
+    takes the CPU for a TPU."""
     from unittest import mock
 
     from paddle_tpu.models import kv_cache
 
-    def show():
-        real = kv_cache._rows_by_dma
-
+    def shown(real):
         def gate(*args):
             with mock.patch.object(jax, "default_backend", lambda: "tpu"):
                 return real(*args)
 
-        monkeypatch.setattr(kv_cache, "_rows_by_dma", gate)
+        return gate
+
+    def show():
+        for name in ("_rows_by_dma", "_reads_by_position"):
+            monkeypatch.setattr(kv_cache, name,
+                                shown(getattr(kv_cache, name)))
 
     return show
 

@@ -1,8 +1,9 @@
 """The decode step's per-slot cache write (``kernels/cache_write.py``)
 against the scatter it stands in for (``kv_cache._write``): the kernels in
 Pallas interpret mode, bit for bit over the whole leaf; the gate that
-chooses between the two; and engines decoding the same tokens either way.
-On the CPU the programs themselves always take the scatter."""
+chooses between the two; and engines decoding the same tokens either way
+(with the read's kernel, ``tests/test_cache_read.py``, beside the
+write's). On the CPU the programs themselves always take the scatter."""
 from unittest import mock
 
 import jax
@@ -137,11 +138,12 @@ def _update(case):
     elif case == "cache-row":
         k, v = (kv_cache.CacheRow(x, jnp.int32(2)) for x in (k, v))
         nk, nv, pos = nk[:1], nv[:1], jnp.int32(9)
-    with kv_cache.cache_write_paths() as paths:
+    with kv_cache.cache_paths() as paths:
         got = kv_cache.update_kv_cache((k, v), nk, nv, pos)
     with mock.patch.object(kv_cache, "_rows_by_dma", lambda *a: False):
         want = kv_cache.update_kv_cache((k, v), nk, nv, pos)
-    return got, paths, want
+    assert paths["read"] == set()
+    return got, paths["write"], want
 
 
 @pytest.mark.parametrize("case,path", [
@@ -208,9 +210,10 @@ def test_a_layers_write_and_read_compile_for_the_chip_in_place(
         one_chip, show_the_gate_a_tpu, cell, shape):
     """The cell's cache pair through ``attend_with_cache`` as the decode
     program runs it (donated; the stacked leaves under a ``scan`` over
-    their entries): the kernel is in the program, the scatter's ``while``
-    is not, and no copy of a leaf is (the leaves alias their outputs and
-    the program needs no temporary the size of one)."""
+    their entries): the write's kernel and the read's are in the program,
+    the scatter's ``while`` is not, and no copy of a leaf is (the leaves
+    alias their outputs and the program needs no temporary the size of
+    one, nor of one entry of a stacked leaf)."""
     show_the_gate_a_tpu()
     slots, (heads, dim) = shape[0], shape[-2:]
     stacked = len(shape) == 5
@@ -234,13 +237,14 @@ def test_a_layers_write_and_read_compile_for_the_chip_in_place(
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     row = arg((slots, 1, heads, dim))
-    with kv_cache.cache_write_paths() as paths:
+    with kv_cache.cache_paths() as paths:
         compiled = jax.jit(program, donate_argnums=(0, 1)).lower(
             arg(shape), arg(shape), row, row, row,
             arg((slots,), jnp.int32)).compile()
-    assert paths == {"dma"}
+    assert paths == {"write": {"dma"}, "read": {"kernel"}}
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 1
+    assert text.count("tpu_custom_call") == 2
+    assert "cache_write_rows" in text and "cache_read_by_position" in text
     assert text.count(" while(") == int(stacked)        # the scan alone
     memory = compiled.memory_analysis()
     leaf_bytes = 2 * int(np.prod(shape))
@@ -252,14 +256,15 @@ def test_a_layers_write_and_read_compile_for_the_chip_in_place(
 def _tiny_gpt():
     cfg = gpt_tiny(hidden_dropout_prob=0.0, attention_dropout_prob=0.0,
                    use_flash_attention=False)          # f32 [B, 128, 4, 32]
-    return GPTForCausalLM(cfg), cfg, "_merge_columns_kernel"
+    return GPTForCausalLM(cfg), cfg, {"_merge_columns_kernel",
+                                      "_columns_kernel"}
 
 
 def _tiny_looped():
     # f32 [B, 2, 128, 8, 128]: the copies want 8 heads of 128
     cfg = ouro_tiny(hidden_size=1024, num_heads=8, num_layers=1,
                     intermediate_size=128, total_ut_steps=2)
-    return OuroForCausalLM(cfg), cfg, "_copy_rows_kernel"
+    return OuroForCausalLM(cfg), cfg, {"_copy_rows_kernel", "_rows_kernel"}
 
 
 def _decode(model, cfg, steps=5):
@@ -284,15 +289,17 @@ def _decode(model, cfg, steps=5):
 def test_engine_decodes_the_same_tokens_on_either_path(
         build, show_the_gate_a_tpu, interpret_pallas):
     pt.seed(3)
-    model, cfg, kernel = build()
+    model, cfg, kernels = build()
     model.eval()
     plain, eng = _decode(model, cfg)
     assert eng.cache_stats()["cache_write"] == "scatter"
+    assert eng.cache_stats()["cache_read"] == "xla"
     assert interpret_pallas == []
     show_the_gate_a_tpu()
     direct, eng = _decode(model, cfg)
     assert eng.cache_stats()["cache_write"] == "dma"
-    assert set(interpret_pallas) == {kernel}
+    assert eng.cache_stats()["cache_read"] == "kernel"
+    assert set(interpret_pallas) == kernels
     assert direct == plain
     assert all(len(t) == 6 for t in direct)
 
@@ -308,4 +315,4 @@ def test_statusz_carries_the_path():
         srv.submit(np.arange(1, 9, dtype=np.int32),
                    max_new_tokens=3).result(timeout=240)
         stats = srv.statusz()["snapshot"]["compile_stats"]
-    assert stats["cache_write"] == "scatter"
+    assert stats["cache_write"] == "scatter" and stats["cache_read"] == "xla"

@@ -5,6 +5,7 @@ import subprocess
 import sys
 
 import jax
+import numpy as np
 import pytest
 
 import paddle_tpu as pt
@@ -84,9 +85,9 @@ def test_serve_phase_tiny():
     out = chip_smoke.serve_phase(
         _tiny(64, loss_chunk=0), slots=4, prompt_lens=(10, 40),
         n_requests=8, new_tokens=(4, 8), expect_donation=False,
-        expect_cache_write="scatter")
+        expect_cache_write="scatter", expect_cache_read="xla")
     assert out["programs"] == 3           # buckets 32, 64 + decode
-    assert out["cache_write"] == "scatter"
+    assert out["cache_write"] == "scatter" and out["cache_read"] == "xla"
 
 
 def test_serve_phase_notices_the_scatter():
@@ -96,6 +97,68 @@ def test_serve_phase_notices_the_scatter():
         chip_smoke.serve_phase(
             _tiny(64, loss_chunk=0), slots=4, prompt_lens=(10, 40),
             n_requests=8, new_tokens=(4, 8), expect_donation=False)
+
+
+def test_serve_phase_notices_the_whole_leaf_read():
+    """The same for the read: XLA's einsums on the CPU."""
+    with pytest.raises(chip_smoke.CheckFailed, match="reads its cache by xla"):
+        chip_smoke.serve_phase(
+            _tiny(64, loss_chunk=0), slots=4, prompt_lens=(10, 40),
+            n_requests=8, new_tokens=(4, 8), expect_donation=False,
+            expect_cache_write="scatter")
+
+
+def test_cache_read_check_tiny(as_on_tpu):
+    chip_smoke.cache_read_check(
+        leaves=((3, 256, 4, 64), (3, 128, 16, 128), (2, 3, 128, 16, 128)))
+    assert sorted(set(as_on_tpu)) == ["_columns_kernel", "_rows_kernel"]
+
+
+def test_cache_read_check_holds_the_kernel_to_its_bound(as_on_tpu,
+                                                        monkeypatch):
+    monkeypatch.setattr(chip_smoke, "_bf16_step", lambda x: 1e-9)
+    with pytest.raises(chip_smoke.CheckFailed, match="more than a bf16 step"):
+        chip_smoke.cache_read_check(leaves=((3, 128, 16, 128),))
+
+
+@pytest.fixture(scope="module")
+def tiny_model_and_stream():
+    from paddle_tpu.models.gpt import GPTForCausalLM
+
+    pt.seed(0)
+    model = GPTForCausalLM(_tiny(64, loss_chunk=0))
+    model.eval()
+    prompt = np.arange(1, 11, dtype=np.int32)
+    solo = np.asarray(model.generate(prompt[None], max_new_tokens=6))[0]
+    return model, prompt, solo
+
+
+def test_greedy_parity_of_equal_streams(tiny_model_and_stream):
+    model, prompt, solo = tiny_model_and_stream
+    assert chip_smoke.greedy_parity(
+        model, prompt, solo.copy(), solo) == "equals model.generate()"
+
+
+@pytest.mark.parametrize("tie", [False, True])
+def test_greedy_parity_parts_only_at_a_tie(tiny_model_and_stream,
+                                           monkeypatch, tie):
+    """Two programs that round differently may part where the reference's
+    top two logits lie under a bf16 step apart, onto the runner-up and
+    nowhere else."""
+    model, prompt, solo = tiny_model_and_stream
+    ids = np.concatenate([prompt, solo[:3]]).astype(np.int32)[None]
+    logits = np.asarray(pt.EvalStep(model)(ids), np.float32)[0, -1]
+    order = np.argsort(logits)[::-1]
+    assert order[0] == solo[3]
+    served = solo.copy()
+    served[3:] = order[1]                 # parts onto the runner-up
+    if tie:     # a tiny model's top two are no tie: call them one
+        monkeypatch.setattr(chip_smoke, "_bf16_step", lambda x: np.inf)
+        assert "up to a tie" in chip_smoke.greedy_parity(
+            model, prompt, served, solo)
+        served[3:] = order[2]             # the third is no runner-up
+    with pytest.raises(chip_smoke.CheckFailed, match="position 3"):
+        chip_smoke.greedy_parity(model, prompt, served, solo)
 
 
 def test_cache_write_check_tiny(as_on_tpu):

@@ -57,12 +57,13 @@ def test_the_owner_imports_none_of_its_clients():
                             "serving"}, (module, names)
 
 
-def test_the_kernel_has_one_importer():
+@pytest.mark.parametrize("kernel", ["cache_write", "cache_read"])
+def test_the_kernel_has_one_importer(kernel):
     """Outside its own package, which lists its modules."""
     importers = [
         rel for rel in _modules(".")
         if rel != "kernels/__init__.py" and any(
-            module.endswith("cache_write") or "cache_write" in names
+            module.endswith(kernel) or kernel in names
             for module, names in _imports(_tree(rel)))]
     assert importers == ["models/kv_cache.py"]
 
@@ -88,7 +89,7 @@ def test_no_private_name_crosses_a_module():
     """No ``from … import _name`` out of the modules that hold cache
     code, anywhere in the package."""
     holders = ("kv_cache", "generation", "lm_utils", "cache_write",
-               "prefix_cache", "speculative")
+               "cache_read", "prefix_cache", "speculative")
     for rel in _modules("."):
         for module, names in _imports(_tree(rel)):
             if module.split(".")[-1] in holders:
