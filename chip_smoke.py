@@ -23,7 +23,17 @@ and the example use (hidden 1024, 16 heads, vocab 50304, 24 layers):
   cache write and read on their kernels, and one greedy stream compared
   with ``model.generate()`` (equal up to a first position where the top
   two logits lie closer than a bf16 step: the two programs round
-  differently);
+  differently); then the latent geometry, the benchmark's sparse
+  latent-attention configuration at its published widths in bfloat16:
+  one expert FFN with every expert drawn apart against the reference's
+  on equal inputs, a decode step's 32 rows and a prefill's 2048
+  (``expert_ffn_check``: picks, routing weights, rows), one stream mixer
+  against the reference's on equal streams (``mixer_check``), and the
+  model built whole (11 GB: born in float32 it would not fit), two
+  prompts prefilled into rows of a 32 x 8192 latent cache, 48 decode
+  steps with each row at its own position, the LOGITS held to the plain
+  float32 reference's full pass, positions within rounding of a routing
+  tie to a bound of their own (``latent_logits_check``);
 - **four_chip**: the train model through ``DistributedTrainStep`` on
   dp=2 x mp=2 and on sdp=4 with ZeRO-2, when the host has four chips.
 
@@ -512,6 +522,325 @@ def serve_phase(cfg, slots: int, prompt_lens, n_requests: int,
             "cache_write": cc["cache_write"], "cache_read": cc["cache_read"]}
 
 
+# ------------------------------------------------- the latent geometry
+#: Readings are the chip's (one v5e; PERF.md section 6, PR 32, where the
+#: planted faults' and the lower precisions' readings are too).
+#: A routing weight of the float32 reference and the system's, same
+#: input: both float32 at full precision (the chip read 0.0, bit for
+#: bit); scores rounded to bfloat16 are 2e-3 off.
+ROUTER_WEIGHT_BOUND = 2e-5
+#: A row of the expert FFN's output against the reference's, same input,
+#: same picks: rms of the difference over the rms of the reference's row.
+#: bfloat16 projections with float32 accumulation read 0.0037-0.0040 at
+#: most (median 0.0035) at 32 and at 2048 rows; rows through the next
+#: expert's weights read 1.14.
+EXPERT_ROW_BOUND = 0.01
+#: The mixers' outputs (the normed input, H_post, M, the mixed streams)
+#: against the reference's, same float32 streams, largest difference
+#: over the largest value: float32 on both sides read 2.8e-7; Sinkhorn
+#: steps in bfloat16 5.9e-3, streams rounded to bfloat16 2e-3.
+MIXER_BOUND = 5e-5
+#: Where some expert layer decides a position's routing by less than
+#: this (the last picked expert's selecting score over the next one's,
+#: as the reference reads it) bfloat16 rounding upstream of the router
+#: may pick the other expert, in a correct program too: the chip read
+#: such picks up to a margin of 4.1e-3 and none over it (six models, 98
+#: positions each). Such a position is held to LATENT_TIE_BOUND, every
+#: other one (10 to 18 of the 98) to LATENT_CLEAN_BOUND.
+LATENT_TIE_MARGIN = 6e-3
+#: The largest |logit - reference| of a position over the standard
+#: deviation of the reference's logits there. Where every pick is
+#: decided: 0.066 to 0.073 over six models (experts akin by 1/32 and by
+#: 1/16, and drawn apart), so that bound does not lean on how the
+#: experts start; every row through the next expert's weights read 0.35
+#: there with experts akin by 1/32, the routed output zeroed 5.4, and a cache row, a position or a norm computed wrong moves every
+#: position alike. Where a pick is not decided: what the other expert
+#: adds, by how far apart the configuration's experts start: 0.094 to
+#: 0.114 at 1/32 over three models (0.17 at 1/16, 2.6 drawn apart).
+LATENT_CLEAN_BOUND = 0.1
+LATENT_TIE_BOUND = 0.2
+
+
+def _bench_harness():
+    bench = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import common
+    return common
+
+
+def expert_ffn_check(config: dict, seed: int, row_counts=(32, 2048),
+                     weight_bound: float = ROUTER_WEIGHT_BOUND,
+                     row_bound: float = EXPERT_ROW_BOUND) -> dict:
+    """One expert FFN at the configuration's widths and type, every
+    expert drawn APART, against the reference's expert layer on the same
+    rows: a decode step's row count and a prefill's. Equal inputs, so no
+    pick hangs on rounding upstream: every row's picks must be the
+    reference's (rows decided by less than ``weight_bound`` aside), its
+    routing weights within ``weight_bound`` and its output within
+    ``row_bound`` of the reference's row."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu as pt
+    from paddle_tpu.nn.layer import functional_call, param_state
+    from paddle_tpu.nn.layers.expert_ffn import ExpertFFN
+
+    common = _bench_harness()
+    reference = common.resolve(config["reference"])
+    cfg = config["config"]
+    pt.seed(common.fold_seed(seed))
+    layer = ExpertFFN(
+        cfg["hidden_size"], cfg["moe_intermediate_size"],
+        cfg["n_routed_experts"], cfg["num_experts_per_tok"],
+        shared_width=cfg["n_shared_experts"] * cfg["moe_intermediate_size"],
+        routed_scaling_factor=cfg["routed_scaling_factor"],
+        init_std=cfg["initializer_range"], dtype=config["run"]["dtype"])
+    params = param_state(layer)
+    apply = jax.jit(lambda p, x: functional_call(layer, p, {}, x)[0])
+    rng = np.random.default_rng(seed)
+    out = {}
+    for rows in row_counts:
+        x = jnp.asarray(rng.standard_normal((rows, cfg["hidden_size"])),
+                        layer.experts.gate_proj.dtype)
+        got = np.asarray(apply(params, x).astype(jnp.float32))
+        picked, w = (np.asarray(a) for a in jax.jit(layer.route)(x))
+        routing = []
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(reference.expert_layer(
+                params, cfg, x.astype(jnp.float32), routing))
+            _, want_w, _ = reference.route(
+                x.astype(jnp.float32), params["router.weight"],
+                params["router.e_score_correction_bias"],
+                k=cfg["num_experts_per_tok"],
+                factor=float(cfg["routed_scaling_factor"]))
+        (want_picked, margin), = routing
+        decided = margin > weight_bound
+        same = (np.sort(picked, -1) == np.sort(want_picked, -1)).all(-1)
+        order, want_order = np.argsort(picked, -1), np.argsort(want_picked, -1)
+        weights = np.abs(np.take_along_axis(w, order, -1)
+                         - np.take_along_axis(np.asarray(want_w),
+                                              want_order, -1)).max(-1)
+        err = (np.sqrt(((got - want) ** 2).mean(-1))
+               / np.sqrt((want ** 2).mean(-1)))
+        log(f"[serve] expert FFN, {rows} rows of {cfg['hidden_size']} "
+            f"through {cfg['n_routed_experts']} experts of "
+            f"{cfg['moe_intermediate_size']}: {int((~same).sum())} rows "
+            f"pick other experts than the reference ({int((~decided).sum())}"
+            f" decided by under {weight_bound}); routing weights within "
+            f"{weights.max(where=same, initial=0):.2e} (bound "
+            f"{weight_bound}); rows within "
+            f"{err.max(where=same, initial=0):.4f} of the reference's, median "
+            f"{np.median(err):.4f} (bound {row_bound})")
+        check(not (~same & decided).any(),
+              f"expert FFN, {rows} rows: {int((~same & decided).sum())} "
+              f"rows pick other experts than the reference on equal inputs")
+        check(weights[same].max() <= weight_bound,
+              f"expert FFN, {rows} rows: routing weights are "
+              f"{weights[same].max():.2e} from the reference's, bound "
+              f"{weight_bound}")
+        check(err[same].max() <= row_bound,
+              f"expert FFN, {rows} rows: a row is {err[same].max():.4f} of "
+              f"its size from the reference's, bound {row_bound}")
+        out[rows] = {"other_picks": int((~same).sum()),
+                     "weights": float(weights[same].max()),
+                     "row": float(err[same].max())}
+    return out
+
+
+def mixer_check(config: dict, seed: int, positions: int = 256,
+                bound: float = MIXER_BOUND) -> dict:
+    """One stream mixer at the configuration's widths against the
+    reference's, on the same float32 streams: what it reads (the mixed,
+    normed input), how it spreads a branch's output and how it mixes the
+    streams meanwhile (``H_post``, ``M`` after its Sinkhorn steps, the
+    streams after ``post``), largest difference over the largest value."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu as pt
+    from paddle_tpu.models.xing import StreamMixer
+    from paddle_tpu.nn.layer import param_state
+
+    common = _bench_harness()
+    reference = common.resolve(config["reference"])
+    raw = config["config"]
+    cfg = common.resolve(config["model"]["config_class"])(**raw)
+    pt.seed(common.fold_seed(seed))
+    mixer = StreamMixer(cfg)
+    n, C = cfg.hc_mult, cfg.hidden_size
+    rng = np.random.default_rng(seed)
+    # streams as a block leaves them: a common part and each its own
+    X = jnp.asarray(rng.standard_normal((positions, 1, C))
+                    + 0.5 * rng.standard_normal((positions, n, C)),
+                    jnp.float32)
+    y = jnp.asarray(rng.standard_normal((positions, C)), jnp.float32)
+
+    def system(X, y):
+        h, (h_post, M) = mixer.pre(X[None])
+        after = mixer.post(X[None], y[None], (h_post, M))
+        return (h[0], h_post[:, 0].T,
+                jnp.stack([jnp.stack(row, -1) for row in M], -2)[0], after[0])
+
+    got = jax.jit(system)(X, y)
+    p = param_state(mixer)
+    with jax.default_matmul_precision("highest"):
+        h, h_post, M = reference._mix_pre(
+            X, p["phi"], p["alpha"], p["bias"], jnp.ones((C,), jnp.float32),
+            n=n, iters=cfg.hc_sinkhorn_iters, eps=cfg.rms_norm_eps,
+            hc_eps=cfg.hc_eps, lo=cfg.mhc_h_res_clamp_min,
+            hi=cfg.mhc_h_res_clamp_max)
+        after = reference._mix_post(X, y, h_post, M)
+    # the reference hands back rms(h, g): norm the system's h alike
+    normed = got[0] * jax.lax.rsqrt(jnp.mean(jnp.square(got[0]), -1,
+                                             keepdims=True) + cfg.rms_norm_eps)
+    out = {}
+    for name, a, b in (("input", normed, h), ("H_post", got[1], h_post),
+                       ("M", got[2], M), ("streams", got[3], after)):
+        out[name] = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+    sums = np.asarray(got[2])
+    log(f"[serve] stream mixer, {positions} positions of {n} x {C}: largest "
+        f"difference from the reference over the largest value "
+        f"{ {k: float(f'{v:.2e}') for k, v in out.items()} } (bound {bound});"
+        f" M's rows sum within {np.abs(sums.sum(-1) - 1).max():.1e} and "
+        f"columns within {np.abs(sums.sum(-2) - 1).max():.1e} of 1; diagonal"
+        f" {np.mean(np.diagonal(sums, axis1=-2, axis2=-1)):.3f}")
+    worst = max(out, key=out.get)
+    check(out[worst] <= bound,
+          f"stream mixer: {worst} is {out[worst]:.2e} of its largest value "
+          f"from the reference's, bound {bound}")
+    return out
+
+
+def latent_logits_check(config: dict, seed: int, slots: int, length: int,
+                        bucket: int, prompt_lens, steps: int = 16,
+                        clean_bound: float = LATENT_CLEAN_BOUND,
+                        tie_bound: float = LATENT_TIE_BOUND,
+                        tie_margin: float = LATENT_TIE_MARGIN) -> dict:
+    """Prefill, then ``steps`` decode steps through the latent cache, at a
+    serve cell's geometry, against the plain reference's full pass:
+    ``config`` is a benchmark configuration file's content (its model
+    classes, its ``config`` and ``run`` blocks, its ``reference``). Two
+    seeded texts of ``prompt_lens`` tokens, padded to ``bucket``, go into
+    the first and the last row of a ``slots x length`` cache by the
+    engine's own admission path (``cache_row_view``); the decode steps
+    run the WHOLE batch with each row at its own position (the other
+    rows decode filler), teacher-forced on seeded tokens. Compared are
+    the logits at the last prompt position and at every decode step,
+    both rows: a position's error is the largest |logit - reference|
+    over the standard deviation of the reference's logits there (the
+    rms over the vocabulary is reported beside it). The reference also
+    says by how much every position's routing was decided: a position
+    under ``tie_margin`` in some layer is held to ``tie_bound``, the
+    others to ``clean_bound``, and a tenth of the positions at least
+    must be of those."""
+    import jax
+    import jax.numpy as jnp
+    common = _bench_harness()
+    from paddle_tpu.models.kv_cache import (cache_row_buffers,
+                                            cache_row_view, init_cache)
+    from paddle_tpu.nn.layer import (buffer_state, functional_call,
+                                     param_state)
+
+    t0 = time.perf_counter()
+    model = common.build_model(config, None, seed)
+    model.eval()
+    params, buffers = param_state(model), buffer_state(model)
+    nbytes = sum(p.size * p.dtype.itemsize for p in params.values())
+    log(f"[serve] latent: model built in {time.perf_counter() - t0:.1f} s, "
+        f"{sum(p.size for p in params.values()) / 1e9:.3f} B parameters, "
+        f"{nbytes / 1e9:.2f} GB, dtypes "
+        f"{sorted({str(p.dtype) for p in params.values()})}")
+    vocab = config["config"]["vocab_size"]
+    rng = np.random.default_rng(seed)
+    texts = [rng.integers(0, vocab, n + steps).astype(np.int32)
+             for n in prompt_lens]
+    rows = (0, slots - 1)
+
+    def prefill(params, buffers, cache, ids, row, last):
+        (logits, view), _ = functional_call(
+            model, params, buffers, ids, cache=cache_row_view(cache, row),
+            position_offset=0, gather_last=last)
+        return logits[0, 0].astype(jnp.float32), cache_row_buffers(view)
+
+    def decode(params, buffers, cache, tokens, positions):
+        (logits, cache), _ = functional_call(
+            model, params, buffers, tokens, cache=cache,
+            position_offset=positions)
+        return logits[:, 0].astype(jnp.float32), cache
+
+    prefill = jax.jit(prefill, donate_argnums=2)
+    decode = jax.jit(decode, donate_argnums=2)
+    cache = init_cache(model, slots, length)
+    got = {r: [] for r in rows}
+    for r, n, text in zip(rows, prompt_lens, texts):
+        ids = np.zeros((1, bucket), np.int32)
+        ids[0, :n] = text[:n]
+        logits, cache = prefill(params, buffers, cache, ids, np.int32(r),
+                                np.int32(n - 1))
+        got[r].append(np.asarray(logits))
+    for i in range(steps):
+        tokens = np.zeros((slots, 1), np.int32)
+        positions = np.zeros(slots, np.int32)
+        for r, n, text in zip(rows, prompt_lens, texts):
+            tokens[r, 0], positions[r] = text[n + i], n + i
+        logits, cache = decode(params, buffers, cache, tokens, positions)
+        logits = np.asarray(logits)
+        for r in rows:
+            got[r].append(logits[r])
+    del cache
+    log(f"[serve] latent: prefill of {list(prompt_lens)} tokens (bucket "
+        f"{bucket}) into rows {list(rows)} of {slots} x {length}, then "
+        f"{steps} decode steps, in {time.perf_counter() - t0:.1f} s")
+
+    reference = common.resolve(config["reference"])
+    worst, rms, margin, short, agree = [], [], [], [], 0
+    for r, n, text in zip(rows, prompt_lens, texts):
+        t1 = time.perf_counter()
+        margins = []
+        ref = reference.logits(params, config["config"], text[None],
+                               margins=margins)[0]
+        ref = ref[n - 1:n + steps]             # predicts n ... n + steps
+        diff = np.stack(got[r]) - ref
+        std = ref.std(axis=-1)
+        worst.extend((np.abs(diff).max(axis=-1) / std).tolist())
+        rms.extend((np.sqrt(np.mean(diff ** 2, axis=-1)) / std).tolist())
+        margin.extend(margins[0][:, n - 1:n + steps].min(axis=0).tolist())
+        token = np.stack(got[r]).argmax(-1)
+        agree += int((token == ref.argmax(-1)).sum())
+        # what a benchmark's serve cell holds a greedy token to
+        short.extend(((ref.max(-1) - ref[np.arange(len(ref)), token])
+                      / std).tolist())
+        log(f"[serve] latent row {r} ({n} + {steps} tokens): reference pass "
+            f"{time.perf_counter() - t1:.1f} s")
+    worst, rms, margin = (np.asarray(a) for a in (worst, rms, margin))
+    clean = margin > tie_margin
+    out = {"positions": len(worst), "clean": int(clean.sum()),
+           "clean_worst": float(worst[clean].max(initial=0.0)),
+           "tie_worst": float(worst[~clean].max(initial=0.0)),
+           "rms_median": float(np.median(rms)), "rms_worst": float(rms.max()),
+           "argmax_agree": agree, "shortfall_worst": float(max(short)),
+           "per_position": {"margin": margin.tolist(), "worst": worst.tolist(),
+                            "rms": rms.tolist(), "shortfall": short}}
+    log(f"[serve] latent: {len(worst)} positions, {out['clean']} with every "
+        f"layer's routing decided by over {tie_margin}: largest |logit - "
+        f"reference| over the logits' std {out['clean_worst']:.4f} there "
+        f"(bound {clean_bound}), {out['tie_worst']:.4f} at the others (bound "
+        f"{tie_bound}); rms median {out['rms_median']:.4f}, worst "
+        f"{out['rms_worst']:.4f}; {agree} argmaxes agree, the system's fall short "
+        f"of the reference's best logit by {out['shortfall_worst']:.4f} at "
+        f"most")
+    check(10 * out["clean"] >= len(worst),
+          f"latent geometry: only {out['clean']} of {len(worst)} positions "
+          f"are decided by over {tie_margin}: the comparison holds too few")
+    check(out["clean_worst"] <= clean_bound
+          and out["tie_worst"] <= tie_bound,
+          f"latent geometry: a logit is {out['clean_worst']:.4f} of the "
+          f"logits' std from the reference where every pick is decided "
+          f"(bound {clean_bound}) and {out['tie_worst']:.4f} where one is "
+          f"not (bound {tie_bound})")
+    return out
+
+
 # ------------------------------------------------------------ four chips
 def four_chip_phase(cfg, batch: int, seq: int, ref_first_loss: float,
                     n_devices: int = 4, loss_tol: float = 0.05,
@@ -676,9 +1005,21 @@ def main(argv=None) -> int:
         def serve():
             cache_write_check()
             cache_read_check()
-            return serve_phase(
+            serve_phase(
                 gpt_config(24, 1024, loss_chunk=0), slots=8,
                 prompt_lens=(20, 50, 100, 200, 400, 900), n_requests=32)
+            _release_device_memory()
+            # the latent entry at its cell's geometry (32 slots x 8192)
+            with open(os.path.join(os.path.dirname(os.path.abspath(
+                    __file__)), "benchmarks", "configs",
+                    "xing4.0-29b-a4b.json")) as f:
+                latent = json.load(f)
+            expert_ffn_check(latent, seed=2147483659)
+            _release_device_memory()
+            mixer_check(latent, seed=2147483659)
+            return latent_logits_check(latent, seed=2147483659, slots=32,
+                                       length=8192, bucket=1024,
+                                       prompt_lens=(1000, 300), steps=48)
 
         _run_phase("serve", serve)
         done["serve"] = "passed"

@@ -16,6 +16,7 @@ from . import ouro  # noqa: F401
 from . import ppyoloe  # noqa: F401
 from . import resnet  # noqa: F401
 from . import speculative  # noqa: F401
+from . import xing  # noqa: F401
 from . import yolo  # noqa: F401
 from .bert import (BertConfig, BertForPretraining,  # noqa: F401
                    BertForSequenceClassification, BertModel, bert_base,
@@ -34,4 +35,5 @@ from .ouro import OuroConfig, OuroForCausalLM, OuroModel, ouro_tiny  # noqa: F40
 from .ppyoloe import PPYOLOE, ppyoloe_s, ppyoloe_tiny  # noqa: F401
 from .resnet import ResNet, resnet18, resnet34, resnet50, resnet101, resnet152  # noqa: F401
 from .speculative import SpeculativeEngine, build_draft_model  # noqa: F401
+from .xing import XingConfig, XingForCausalLM, XingModel, xing_tiny  # noqa: F401
 from .yolo import YOLOv3  # noqa: F401

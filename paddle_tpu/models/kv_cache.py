@@ -3,7 +3,14 @@
 A PREALLOCATED pytree: a tuple of ``(k, v)`` pairs whose leaves are ``[B,
 *stack, S, Hkv, D]``, rows leading, shapes fixed while decoding (only
 positions advance); under ``kv_dtype="int8"`` each of ``k`` and ``v`` is a
-``(int8 values, float32 scales [..., 1])`` pair. Tests, ``serving.disagg``'s
+``(int8 values, float32 scales [..., 1])`` pair. A LATENT entry (multi-head
+latent attention: ``spec["latent"] = (rank, rope_dim)``) is the same pair
+with other widths and one head: ``(c [B, S, 1, rank], k_r [B, S, 1,
+rope_dim])``, the normed compressed vector that every head's keys AND
+values are decompressed from and the one rotated key all heads share;
+values are not stored. Two leaves and not one of ``rank + rope_dim``: 576
+is 4.5 tiles of 128 lanes, and the pair keeps the pytree every function
+here, ``BlockPool`` and ``serving.disagg`` index. Tests, ``serving.disagg``'s
 wire format and ``DecoderBlockList`` index the tuple, so it stays a plain
 pytree under free functions. The models reach them through
 ``lm_utils.attend_with_cache`` alone, the engines and the prefix pool
@@ -32,7 +39,8 @@ from ..framework.dtype import convert_dtype
 from ..kernels import cache_read, cache_write
 from ..quantization import is_quantized_kv, kv_dequantize, kv_quantize
 
-__all__ = ["cache_entries", "cache_layout", "cache_sharding_spec",
+__all__ = ["cache_entries", "cache_layout", "cache_entry_kind",
+           "cache_entry_widths", "latent_attention", "cache_sharding_spec",
            "normalize_kv_dtype", "alloc_cache", "init_cache", "cache_nbytes",
            "cache_token_nbytes", "constrain_cache", "cache_geometry",
            "CacheRow", "cache_row_view", "cache_row_buffers",
@@ -66,12 +74,28 @@ def cache_layout(spec: dict):
     return entries // stack, ((stack,) if stack > 1 else ())
 
 
+def cache_entry_kind(spec: dict) -> str:
+    """``"latent"`` where a model's ``cache_spec()`` names a latent entry
+    (``spec["latent"]``), else ``"kv"``."""
+    return "latent" if spec.get("latent") else "kv"
+
+
+def cache_entry_widths(spec: dict):
+    """Last-axis widths of an entry's two leaves: ``head_dim`` for keys
+    and for values, or a latent entry's ``(rank, rope_dim)``."""
+    if spec.get("latent"):
+        rank, rope_dim = spec["latent"]
+        return int(rank), int(rope_dim)
+    return (int(spec["head_dim"]),) * 2
+
+
 def cache_sharding_spec(batch: int, n_kv_heads: int, mesh=None, stack=0):
     """GSPMD sharding for one cache leaf [B, S, Hkv, D] (``stack``
     replicated axes of stacked entries after the batch's): batch over
     dp/sdp, kv heads over mp — matching the Column-parallel K/V
     projections, so tp decode reads/writes only local heads (no gathers).
-    Axes that don't divide evenly stay replicated."""
+    Axes that don't divide evenly stay replicated: a latent entry's one
+    head always is, as the compressed vector is what every head reads."""
     mesh = mesh if mesh is not None else get_mesh()
     if mesh is None:
         return None
@@ -111,25 +135,33 @@ def alloc_cache(spec: dict, rows: int, length: int, dtype=None,
     values, float32 scales [..., Hkv, 1])`` pair (see
     :mod:`paddle_tpu.quantization`), roughly halving the footprint at
     head_dim 64+; the scale keeps the value leaf's rank, so every
-    function here maps over both leaves alike. Each leaf is placed by
-    ``placement`` (a sharding) as it is made, where one is given."""
+    function here maps over both leaves alike. A latent entry
+    (:func:`cache_entry_widths`) is refused with it: its one compressed
+    vector stands for every head's keys and values, and a per-head scale
+    has no head to belong to. Each leaf is placed by ``placement`` (a
+    sharding) as it is made, where one is given."""
     dtype = convert_dtype(dtype or spec["dtype"])
     quantized = normalize_kv_dtype(kv_dtype) == "int8"
+    if quantized and cache_entry_kind(spec) == "latent":
+        raise ValueError(
+            "kv_dtype='int8' is not supported with a latent cache entry "
+            "(multi-head latent attention): the entry is already the "
+            "compressed form; use kv_dtype=None")
     pairs, stack = cache_layout(spec)
-    shape = (rows,) + stack + (length, spec["num_kv_heads"],
-                               spec["head_dim"])
+    lead = (rows,) + stack + (length, spec["num_kv_heads"])
 
     def zeros(shape, dtype):
         z = jnp.zeros(shape, dtype)
         return z if placement is None else jax.device_put(z, placement)
 
-    def entry():
+    def entry(width):
         if quantized:
-            return (zeros(shape, jnp.int8),
-                    zeros(shape[:-1] + (1,), jnp.float32))
-        return zeros(shape, dtype)
+            return (zeros(lead + (width,), jnp.int8),
+                    zeros(lead + (1,), jnp.float32))
+        return zeros(lead + (width,), dtype)
 
-    return tuple((entry(), entry()) for _ in range(pairs))
+    first, second = cache_entry_widths(spec)
+    return tuple((entry(first), entry(second)) for _ in range(pairs))
 
 
 def init_cache(model, batch: int, max_length: Optional[int] = None,
@@ -311,6 +343,10 @@ def update_kv_cache(cache, k_new, v_new, position_offset, entry=None):
     sits at its own position (one per-row windowed write, still one
     program).
 
+    A latent entry's pair goes through here too: ``k_new`` the compressed
+    vectors ``[B, L, 1, rank]``, ``v_new`` the shared rotated keys ``[B,
+    L, 1, rope_dim]``.
+
     Quantized caches (``kv_dtype="int8"``: each entry a ``(values,
     scales)`` pair, see :mod:`paddle_tpu.quantization`) quantize on
     write — new keys/values are reduced to int8 + per-head scale here,
@@ -373,6 +409,16 @@ def cached_attention(q, k_cache, v_cache, position_offset, entry=None):
     return _read_whole(q, k_cache, v_cache, position_offset, entry)
 
 
+def _visible(position_offset, L: int, S: int):
+    """The position mask ``[B|1, L, S]``: the query at ``position_offset
+    + i`` sees positions up to its own. Scalar offsets broadcast over
+    the batch, vector offsets give every row its own frontier."""
+    off = jnp.asarray(position_offset, jnp.int32).reshape(-1, 1)
+    qpos = off + jnp.arange(L, dtype=jnp.int32)[None, :]
+    return (jnp.arange(S, dtype=jnp.int32)[None, None, :]
+            <= qpos[:, :, None])
+
+
 def _read_whole(q, k_cache, v_cache, position_offset, entry=None):
     """:func:`cached_attention` as XLA issues it: two einsums over every
     position of the leaf, under the mask."""
@@ -391,16 +437,39 @@ def _read_whole(q, k_cache, v_cache, position_offset, entry=None):
     qg = q.reshape(B, L, Hkv, groups, D)
     s = jnp.einsum("blhgd,bshd->bhgls", qg, k_cache.astype(q.dtype))
     s = s * (1.0 / math.sqrt(D))
-    # qpos [B|1, L]: scalar offsets broadcast over the batch, vector
-    # offsets give every row its own mask frontier
-    off = jnp.asarray(position_offset, jnp.int32).reshape(-1, 1)
-    qpos = off + jnp.arange(L, dtype=jnp.int32)[None, :]
-    allowed = (jnp.arange(S, dtype=jnp.int32)[None, None, :]
-               <= qpos[:, :, None])                      # [B|1, L, S]
+    allowed = _visible(position_offset, L, S)
     s = jnp.where(allowed[:, None, None], s, jnp.finfo(s.dtype).min)
     p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
     out = jnp.einsum("bhgls,bshd->blhgd", p, v_cache.astype(q.dtype))
     return out.reshape(B, L, H, D)
+
+
+def latent_attention(q_c, q_r, c_cache, kr_cache, position_offset, scale):
+    """Attention IN THE LATENT SPACE against the full cache of a latent
+    entry, for single-token decode and chunked continuation: ``q_c`` [B,
+    L, H, rank] is the query with the keys' up-projection absorbed into
+    it, ``q_r`` [B, L, H, rope_dim] its rotated part; ``c_cache`` [B, S,
+    1, rank] serves as every head's keys and as every head's values,
+    ``kr_cache`` [B, S, 1, rope_dim] is the one rotated key they share.
+
+        score = (q_c . c + q_r . k_r) * scale;  out = softmax(score) c
+
+    under :func:`cached_attention`'s position mask (scalar or per-row
+    ``[B]`` offsets), float32 scores and softmax. Returns [B, L, H, rank];
+    the caller applies the values' up-projection. No position is ever
+    decompressed. XLA's path over every position of the leaf: the read
+    by position has no kernel for this entry yet."""
+    _note("read", "xla")
+    c, kr = c_cache[:, :, 0], kr_cache[:, :, 0]            # [B, S, width]
+    L, S = q_c.shape[1], c.shape[1]
+    s = (jnp.einsum("blhc,bsc->bhls", q_c, c.astype(q_c.dtype),
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("blhr,bsr->bhls", q_r, kr.astype(q_r.dtype),
+                      preferred_element_type=jnp.float32)) * scale
+    s = jnp.where(_visible(position_offset, L, S)[:, None], s,
+                  jnp.finfo(s.dtype).min)
+    p = jax.nn.softmax(s, axis=-1).astype(q_c.dtype)
+    return jnp.einsum("bhls,bsc->blhc", p, c.astype(q_c.dtype))
 
 
 # --------------------------------------------------- row and block copies

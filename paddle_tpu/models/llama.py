@@ -97,15 +97,18 @@ def _rotate_half(x):
     return jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
 
 
-def rotary_embed(q, k, theta: float, position_offset=0):
+def rotary_embed(q, k, theta: float, position_offset=0, inv_freq=None):
     """Rotary position embedding on [B, L, H, D] (llama rotate-half
-    convention). ``position_offset`` may be a scalar or a per-row ``[B]``
-    vector (continuous-batching decode: each slot rotates at its own
-    position), traced or not. The angles of the ``L`` positions are
-    computed here, in float32, so a program carries ``D/2`` constants
-    whatever the model's position limit."""
+    convention; ``q`` and ``k`` may differ in heads). ``position_offset``
+    may be a scalar or a per-row ``[B]`` vector (continuous-batching
+    decode: each slot rotates at its own position), traced or not. The
+    angles of the ``L`` positions are computed here, in float32, so a
+    program carries ``D/2`` constants whatever the model's position
+    limit: ``theta ** (-2 i / D)``, or ``inv_freq`` [D/2] where a model
+    scales its frequencies (YaRN)."""
     L, D = q.shape[1], q.shape[-1]
-    inv_freq = 1.0 / (theta ** (np.arange(0, D, 2, dtype=np.float32) / D))
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (np.arange(0, D, 2, dtype=np.float32) / D))
     pos = (jnp.asarray(position_offset, jnp.int32).reshape(-1, 1)
            + jnp.arange(L, dtype=jnp.int32)[None, :])        # [1|B, L]
     freqs = pos[..., None].astype(jnp.float32) * inv_freq    # [1|B, L, D/2]

@@ -19,10 +19,11 @@ from ..distributed.parallel.recompute import recompute_wrap
 from ..kernels import flash_attention as fa
 from ..nn import functional as F
 from ..nn.layer import Layer
-from .kv_cache import cached_attention, update_kv_cache
+from .kv_cache import cached_attention, latent_attention, update_kv_cache
 
 __all__ = ["chunked_lm_loss", "DecoderBlockList", "constrain_seq",
            "causal_attention", "repeat_kv", "attend_with_cache",
+           "latent_block_attention", "attend_with_latent_cache",
            "cached_lm_forward"]
 
 
@@ -39,9 +40,13 @@ def constrain_seq(x, cfg):
         x, sharding(batch_axes, seq_axis, None, mesh=mesh))
 
 
-def causal_attention(q, k, v, dropout_p=0.0, training=True, use_flash=True):
+def causal_attention(q, k, v, dropout_p=0.0, training=True, use_flash=True,
+                     scale=None):
     """Causal self-attention on [B, L, H, D]; Pallas flash path when the
-    gate allows, XLA-fused softmax otherwise."""
+    gate allows, XLA-fused softmax otherwise. ``scale`` multiplies the
+    scores in place of ``1 / sqrt(D)`` (XLA's path alone: a caller that
+    gives one turns ``use_flash`` off); ``v`` may be narrower than ``q``
+    and ``k`` there."""
     p_drop = dropout_p if training else 0.0
     # tpu-lint: disable=R2(flash gate reads only static shape/dtype/platform of q,k — per-shape program selection inside the bucketed compile budget, re-audited PR 12)
     if use_flash and fa.should_use_flash(q, k, None, p_drop):
@@ -56,7 +61,7 @@ def causal_attention(q, k, v, dropout_p=0.0, training=True, use_flash=True):
                                        dropout_p=p_drop, seed=seed)
     B, Lq, H, D = q.shape
     Lk = k.shape[1]
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     mask = jnp.tril(jnp.ones((Lq, Lk), dtype=bool), k=Lk - Lq)
     s = jnp.where(mask, s, jnp.finfo(s.dtype).min)
@@ -104,6 +109,57 @@ def attend_with_cache(q, k_new, v_new, cache, position_offset,
     return out, cache
 
 
+def latent_block_attention(q_nope, q_rope, c, k_rope, w_uk, w_uv, scale):
+    """Multi-head latent attention over a block's own positions, keys and
+    values DECOMPRESSED: ``k = [c W_uk | k_rope]`` per head (the rotated
+    part shared by all heads), ``v = c W_uv``. ``q_nope`` [B, L, H, N],
+    ``q_rope`` [B, L, H, R], ``c`` [B, L, rank], ``k_rope`` [B, L, 1, R],
+    ``w_uk`` [rank, H, N], ``w_uv`` [rank, H, V]. Returns [B, L, H, V].
+    The key is ``N + R`` wide and the value ``V``: none of the flash
+    kernel's shapes, so XLA's path."""
+    H = q_nope.shape[2]
+    k_nope = jnp.einsum("blc,chn->blhn", c, w_uk)
+    v = jnp.einsum("blc,chv->blhv", c, w_uv)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope, k_rope.shape[:2] + (H,)
+                                  + k_rope.shape[3:])], axis=-1)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    return causal_attention(q, k, v, training=False, use_flash=False,
+                            scale=scale)
+
+
+def attend_with_latent_cache(q_nope, q_rope, c_new, k_rope_new, w_uk, w_uv,
+                             cache, position_offset, scale):
+    """:func:`attend_with_cache` for a latent cache entry ``(c, k_r)``
+    (:mod:`.kv_cache`): always writes ``c_new`` [B, L, rank] and
+    ``k_rope_new`` [B, L, 1, R]; the PREFILL shape decompresses the
+    block's keys and values and attends block-locally
+    (:func:`latent_block_attention`); every other shape (single-token
+    decode, chunked continuation) attends IN THE LATENT SPACE against the
+    full cache, ``w_uk`` absorbed into the query and ``w_uv`` applied to
+    the result, so a step never decompresses ``S`` positions:
+
+        q_c = q_nope W_uk^h;  score = (q_c . c + q_r . k_r) * scale
+        o_c = softmax(score) c;  out = o_c W_uv^h
+
+    which equals the decompressed form in exact arithmetic. Returns
+    ``(out [B, L, H, V], (c_cache, k_r_cache))``."""
+    with jax.named_scope("mla"):
+        cache = update_kv_cache(cache, c_new[:, :, None, :], k_rope_new,
+                                position_offset)
+        is_prefill = (q_nope.shape[1] > 1 and isinstance(position_offset, int)
+                      and position_offset == 0)
+        if is_prefill:
+            return latent_block_attention(q_nope, q_rope, c_new, k_rope_new,
+                                          w_uk, w_uv, scale), cache
+        with jax.named_scope("absorb"):
+            q_c = jnp.einsum("blhn,chn->blhc", q_nope, w_uk)
+        with jax.named_scope("latent_read"):
+            o_c = latent_attention(q_c, q_rope, cache[0], cache[1],
+                                   position_offset, scale)
+        return jnp.einsum("blhc,chv->blhv", o_c, w_uv), cache
+
+
 def cached_lm_forward(backbone, logits_fn, input_ids, cache,
                       position_offset, gather_last):
     """The serving-side CausalLM forward shared by GPT and Llama: run the
@@ -123,7 +179,9 @@ def cached_lm_forward(backbone, logits_fn, input_ids, cache,
 class DecoderBlockList(Layer):
     """Shared N-block decoder stack with per-block recompute dispatch
     (GPT/Llama): ``cfg`` provides ``num_layers``/``use_recompute``/
-    ``recompute_policy``; ``block_cls(cfg)`` builds one block. With
+    ``recompute_policy``; ``block_cls(cfg)`` builds one block, called
+    once a layer in layer order (any callable: a model whose layers
+    differ hands in one that counts). With
     ``caches`` (a per-layer tuple of ``(k, v)`` pairs) each block runs its
     cached-decode path and the updated caches ride back alongside the
     activations; ``cache_entry`` goes to blocks whose pair stacks several

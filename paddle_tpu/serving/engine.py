@@ -49,7 +49,8 @@ from ..framework import compile_cache
 from ..io.batching import bucket_for
 from ..models.generation import (DEFAULT_PREFILL_BUCKETS, per_row_keys,
                                  sample_logits_rows)
-from ..models.kv_cache import (cache_entries, cache_geometry, cache_nbytes,
+from ..models.kv_cache import (cache_entries, cache_entry_kind,
+                               cache_geometry, cache_nbytes,
                                cache_paths, cache_row_buffers,
                                cache_row_view, constrain_cache,
                                gather_cache_blocks, init_cache,
@@ -58,6 +59,7 @@ from ..models.kv_cache import (cache_entries, cache_geometry, cache_nbytes,
 from ..lora import adapter_rows as _adapter_rows_ctx
 from ..lora.store import AdapterStore, normalize_adapter_id
 from ..nn.layer import buffer_state, functional_call, param_state
+from ..nn.layers.expert_ffn import expert_load
 from .metrics import LoopClock
 from .prefix_cache import BlockPool
 
@@ -133,6 +135,12 @@ class ContinuousBatchingEngine:
         #: "xla"): known once it has been traced
         self._cache_write: Optional[str] = None
         self._cache_read: Optional[str] = None
+        #: ``(expert layers, experts)`` as the model states them beside
+        #: its ``cache_spec()``, None for a model without expert FFNs:
+        #: the shape of the decode program's expert-load counters
+        #: (:meth:`expert_load`)
+        shape = getattr(model, "expert_load_shape", lambda: (0, 0))()
+        self._expert_shape = tuple(shape) if shape[0] else None
         on_device = jax.default_backend() != "cpu"
         lora = self.store is not None
         if self.pool is not None:
@@ -244,6 +252,15 @@ class ContinuousBatchingEngine:
             # themselves survive — the store is never donated)
             self.store.release_all()
         B = self.slots
+        # device-resident, carried through the decode program and read
+        # back only on request; never donated, so that a snapshot on
+        # another thread can fetch the array it holds while the loop
+        # dispatches the next step
+        self._expert_load = None if self._expert_shape is None else {
+            "steps": jnp.zeros((), jnp.int32),
+            "tokens_per_expert": jnp.zeros(self._expert_shape, jnp.int32),
+            "experts_touched_steps": jnp.zeros(self._expert_shape[:1],
+                                               jnp.int32)}
         self._adapter_slots = np.zeros(B, np.int32)
         self._positions = np.zeros(B, np.int32)
         self._tokens = np.zeros(B, np.int32)
@@ -375,13 +392,29 @@ class ContinuousBatchingEngine:
             return self._decode_fn(params, buffers, live_cache, *rest)
 
     def _decode_fn(self, params, buffers, live_cache, tokens, positions,
-                   keys, done, eos, temperature, top_p, greedy_mask):
-        with jax.named_scope("decode"), cache_paths() as paths:
+                   keys, done, eos, temperature, top_p, greedy_mask,
+                   load=None):
+        """``load`` (None for a model without experts: no leaf, the same
+        program as before it existed) is the running expert load, summed
+        here over the slots that are live in this step (``~done``: a free
+        slot decodes filler and is not counted) and handed back."""
+        with contextlib.ExitStack() as stack:
+            stack.enter_context(jax.named_scope("decode"))
+            paths = stack.enter_context(cache_paths())
+            tally = (None if load is None
+                     else stack.enter_context(expert_load(~done)))
             (logits, live_cache), _ = functional_call(
                 self.model, params, buffers, tokens, cache=live_cache,
                 position_offset=positions)
         self._cache_write = "dma" if paths["write"] == {"dma"} else "scatter"
         self._cache_read = "kernel" if paths["read"] == {"kernel"} else "xla"
+        if load is not None:
+            load = {
+                "steps": load["steps"] + 1,
+                "tokens_per_expert": load["tokens_per_expert"]
+                + jnp.stack([per_expert for per_expert, _ in tally]),
+                "experts_touched_steps": load["experts_touched_steps"]
+                + jnp.stack([touched for _, touched in tally])}
         live_cache = constrain_cache(live_cache)
         logits = logits[:, -1, :]
         # per-slot streams: each slot replays the batch-1 generate() key
@@ -396,7 +429,7 @@ class ContinuousBatchingEngine:
         fill = jnp.maximum(eos, 0)
         next_tok = jnp.where(done, fill, next_tok)
         done = done | (next_tok == eos)
-        return next_tok, done, live_cache
+        return next_tok, done, live_cache, load
 
     # -------------------------------------------------------- host API
     def bucket_for_prompt(self, prompt_len: int) -> int:
@@ -638,11 +671,12 @@ class ContinuousBatchingEngine:
             self.store.tensors, self._adapter_slots)
         with self._eval_mode():
             compile_cache.record_call(self._cc_decode)
-            tok, done, self.live_cache = self._decode_compiled(
-                self._params, self._buffers, self.live_cache, *lora_args,
-                self._tokens[:, None], self._positions, self._keys,
-                self._done, self._eos, self._temp, self._top_p,
-                self._greedy)
+            tok, done, self.live_cache, self._expert_load = (
+                self._decode_compiled(
+                    self._params, self._buffers, self.live_cache, *lora_args,
+                    self._tokens[:, None], self._positions, self._keys,
+                    self._done, self._eos, self._temp, self._top_p,
+                    self._greedy, self._expert_load))
         # one batched transfer for the whole [B] step readback (token +
         # done vectors) instead of two serialized np.array round-trips;
         # np.array then makes writable copies: admit() scribbles slots
@@ -683,6 +717,26 @@ class ContinuousBatchingEngine:
             self.store.release(int(self._adapter_slots[slot]))
             self._adapter_slots[slot] = 0
 
+    def expert_load(self) -> Optional[dict]:
+        """How the decode steps so far spread their live slots' tokens
+        over the experts, None for a model without an expert FFN:
+        ``steps`` counted, ``tokens_per_expert`` [expert layer][expert]
+        tokens routed there and ``experts_touched_steps`` [expert layer]
+        experts that at least one live token picked, both summed over the
+        steps. The counters live on the device, in int32 (a step adds at
+        most ``slots * top_k`` to one: years at any rate a chip decodes
+        at), and THIS read is their only trip to the host: it waits for
+        the step in flight, so call it for a snapshot, not a step."""
+        load = self._expert_load
+        if load is None:
+            return None
+        # tpu-lint: disable=R1(a snapshot's read, on the caller's thread; no step reads these back)
+        host = jax.device_get(load)
+        return {"steps": int(host["steps"]),
+                "tokens_per_expert": host["tokens_per_expert"].tolist(),
+                "experts_touched_steps":
+                    host["experts_touched_steps"].tolist()}
+
     def cache_stats(self) -> dict:
         """Compile/call counters of the two serving programs — steady
         state must hold at ``#buckets_used`` prefill + 1 decode — and the
@@ -693,11 +747,14 @@ class ContinuousBatchingEngine:
         or ``"scatter"``) and ``cache_read`` the way it reads the cache
         for attention (``kv_cache.cached_attention``: ``"kernel"``, by
         position, or ``"xla"``, the whole leaf under a mask), both None
-        until it has been traced."""
+        until it has been traced. ``cache_entry`` is what an entry holds:
+        ``"kv"`` (keys and values per head) or ``"latent"`` (one
+        compressed vector and one shared rotated key a position)."""
         return {"prefill": compile_cache.cache_stats(self._cc_prefill),
                 "decode": compile_cache.cache_stats(self._cc_decode),
                 "cache_write": self._cache_write,
                 "cache_read": self._cache_read,
                 "cache_entries": cache_entries(self.spec),
+                "cache_entry": cache_entry_kind(self.spec),
                 "cache_bytes_per_token":
                     self.cache_bytes_per_slot() // self.max_length}

@@ -322,8 +322,12 @@ class ServingMetrics:
     # ---------------------------------------------------------- snapshot
     def snapshot(self, compile_stats: Optional[dict] = None,
                  prefix_cache: Optional[dict] = None,
-                 adapter_store: Optional[dict] = None) -> dict:
+                 adapter_store: Optional[dict] = None,
+                 moe: Optional[dict] = None) -> dict:
         """One plain dict of everything — the serve_bench JSON shape.
+        ``moe`` (``ContinuousBatchingEngine.expert_load()``: the decode
+        steps' expert load, fetched from the device for this snapshot)
+        rides along for a model with an expert FFN.
         ``prefix_cache`` (a ``BlockPool.stats()`` dict) and
         ``adapter_store`` (an ``AdapterStore.stats()`` dict) ride along
         under their own keys when the engine has them attached; the
@@ -377,6 +381,7 @@ class ServingMetrics:
                    if prefix_cache is not None else {}),
                 **({"adapter_store": adapter_store}
                    if adapter_store is not None else {}),
+                **({"moe": moe} if moe is not None else {}),
                 **({"per_adapter": {
                     name: {"requests": e["requests"],
                            "tokens": e["tokens"],

@@ -43,8 +43,9 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..models.kv_cache import (alloc_cache, cache_entries, cache_layout,
-                               cache_token_nbytes, normalize_kv_dtype)
+from ..models.kv_cache import (alloc_cache, cache_entries, cache_entry_widths,
+                               cache_layout, cache_token_nbytes,
+                               normalize_kv_dtype)
 
 __all__ = ["BlockPool", "PrefixHit", "StorePlan", "chain_digests",
            "KV_WIRE_VERSION", "DEFAULT_MIGRATE_CHUNK_BYTES",
@@ -208,9 +209,12 @@ class BlockPool:
     def compatible_with(self, spec: dict, max_length: int,
                         kv_dtype=None) -> None:
         """Raise when this pool cannot serve an engine's geometry."""
-        mine = dict(self.spec, cache_layout=cache_layout(self.spec))
-        theirs = dict(spec, cache_layout=cache_layout(spec))
-        for k in ("cache_layout", "num_kv_heads", "head_dim"):
+        mine = dict(self.spec, cache_layout=cache_layout(self.spec),
+                    entry_widths=cache_entry_widths(self.spec))
+        theirs = dict(spec, cache_layout=cache_layout(spec),
+                      entry_widths=cache_entry_widths(spec))
+        for k in ("cache_layout", "num_kv_heads", "head_dim",
+                  "entry_widths"):
             if mine[k] != theirs[k]:
                 raise ValueError(
                     f"prefix cache built for {k}={mine[k]} cannot "

@@ -361,14 +361,17 @@ class InferenceServer:
     def snapshot(self) -> dict:
         """Metrics + compile-counter snapshot (see
         ``ServingMetrics.snapshot``), plus the block-pool occupancy/
-        eviction numbers when a prefix cache is attached and the adapter
-        registry residency/eviction numbers when an adapter store is."""
+        eviction numbers when a prefix cache is attached, the adapter
+        registry residency/eviction numbers when an adapter store is, and
+        the decode steps' expert load (``"moe"``: read from the device
+        here, and only here) when the model has an expert FFN."""
         pool = self.engine.pool
         store = self.engine.store
         return self.metrics.snapshot(
             self.engine.cache_stats(),
             prefix_cache=None if pool is None else pool.stats(),
-            adapter_store=None if store is None else store.stats())
+            adapter_store=None if store is None else store.stats(),
+            moe=self.engine.expert_load())
 
     def metrics_text(self) -> str:
         """Prometheus text exposition of the process metrics registry —

@@ -252,6 +252,87 @@ def test_a_layers_write_and_read_compile_for_the_chip_in_place(
     assert memory.temp_size_in_bytes < leaf_bytes // 64
 
 
+def test_a_latent_layers_write_and_read_compile_for_the_chip_in_place(
+        one_chip, show_the_gate_a_tpu):
+    """``xing4.0-29b-a4b.serve-reason``'s cache pair, ``(c [32, 8192, 1,
+    512], k_r [32, 8192, 1, 64])``, through ``attend_with_latent_cache``
+    as the decode program runs it: one head is no whole tile, so both
+    gates keep XLA's paths (the scatter's ``while`` a leaf, the read of
+    the whole leaf under the mask), the leaves alias their outputs, and
+    a leaf with one head is held without padding: the program's arguments
+    are the leaves' own bytes."""
+    show_the_gate_a_tpu()
+    slots, length, heads, rank, rope, nope = 32, 8192, 32, 512, 64, 128
+
+    def layer(c, kr, q_nope, q_rope, c_new, kr_new, w_uk, w_uv, pos):
+        out, (c, kr) = lm_utils.attend_with_latent_cache(
+            q_nope, q_rope, c_new, kr_new, w_uk, w_uv, (c, kr), pos, 0.1)
+        return c, kr, out
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    leaves = [(slots, length, 1, rank), (slots, length, 1, rope)]
+    with kv_cache.cache_paths() as paths:
+        compiled = jax.jit(layer, donate_argnums=(0, 1)).lower(
+            arg(leaves[0]), arg(leaves[1]), arg((slots, 1, heads, nope)),
+            arg((slots, 1, heads, rope)), arg((slots, 1, rank)),
+            arg((slots, 1, 1, rope)), arg((rank, heads, nope)),
+            arg((rank, heads, nope)), arg((slots,), jnp.int32)).compile()
+    assert paths == {"write": {"scatter"}, "read": {"xla"}}
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text and text.count(" while(") == 2
+    memory = compiled.memory_analysis()
+    leaf_bytes = sum(2 * int(np.prod(shape)) for shape in leaves)
+    assert leaf_bytes == 32 * 8192 * 1152
+    assert memory.alias_size_in_bytes == leaf_bytes
+    # the leaves, two 4 MB up-projections and the step's rows
+    assert memory.argument_size_in_bytes < leaf_bytes * 1.05
+    # float32 scores [32, 32, 1, 8192] and their softmax, not a leaf
+    assert memory.temp_size_in_bytes < leaf_bytes // 4
+
+
+@pytest.mark.parametrize("rows", [32, 2048])
+def test_the_expert_ffn_compiles_for_the_chip_as_grouped_matmuls(
+        one_chip, rows):
+    """64 experts of 3584 x 1024 in bfloat16, top 4, for a decode step's
+    32 tokens and a prefill's 2048: the three ``ragged_dot`` are Mosaic
+    kernels of the compiler's own (a bf16 operand left at the package's
+    float32 matmul precision is refused there: "Bad lhs type"), and the
+    program holds no temporary the size of the experts."""
+    from paddle_tpu.nn.layer import functional_call, param_state
+    from paddle_tpu.nn.layers.expert_ffn import ExpertFFN
+
+    holder = {}
+
+    def build():
+        holder["layer"] = ExpertFFN(3584, 1024, 64, 4, shared_width=1024,
+                                    routed_scaling_factor=2.0,
+                                    dtype="bfloat16")
+        return param_state(holder["layer"])
+
+    shapes = jax.eval_shape(build)
+    pt.seed(0)      # the abstract build left tracers in the generator
+    layer = holder["layer"]
+
+    def arg(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda p, x: functional_call(layer, p, {}, x)[0]).lower(
+        jax.tree.map(arg, shapes),
+        jax.ShapeDtypeStruct((rows, 3584), jnp.bfloat16,
+                             sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count('op_name="ragged-dot-none"') >= 3
+    expert_bytes = 3 * 64 * 3584 * 1024 * 2
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > expert_bytes
+    # the gathered rows (58 MB of 8192 picks at 2048 tokens) and what
+    # follows them, never a copy of the experts
+    assert memory.temp_size_in_bytes < expert_bytes // 4
+
+
 # ------------------------------------------------------------- the engine
 def _tiny_gpt():
     cfg = gpt_tiny(hidden_dropout_prob=0.0, attention_dropout_prob=0.0,

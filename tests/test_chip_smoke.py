@@ -90,6 +90,127 @@ def test_serve_phase_tiny():
     assert out["cache_write"] == "scatter" and out["cache_read"] == "xla"
 
 
+def _latent_tiny():
+    import json
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "xing4.0-29b-a4b.json")) as f:
+        config = json.load(f)
+    config["config"].update(
+        vocab_size=512, hidden_size=64, num_layers=3, num_heads=4,
+        intermediate_size=160, max_position_embeddings=256, q_lora_rank=24,
+        kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, n_routed_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=32)
+    config["run"]["dtype"] = "float32"
+    return config
+
+
+def test_latent_logits_check_tiny():
+    """float32 on both sides: the rehearsal's error is rounding alone,
+    and with 8 experts most positions are far from a tie."""
+    out = chip_smoke.latent_logits_check(
+        _latent_tiny(), seed=2 ** 31 + 11, slots=3, length=64, bucket=32,
+        prompt_lens=(30, 9), steps=4, clean_bound=1e-4, tie_bound=1e-4)
+    assert out["positions"] == 10 and out["argmax_agree"] == 10
+    assert out["clean"] >= 5 and out["rms_worst"] < 1e-5
+
+
+def test_latent_logits_check_notices_another_rows_cache(monkeypatch):
+    """A decode step that reads its neighbour's cache row: the decoded
+    positions are far past either bound."""
+    import jax.numpy as jnp
+    from paddle_tpu.models import lm_utils
+
+    read = lm_utils.latent_attention
+    monkeypatch.setattr(
+        lm_utils, "latent_attention", lambda q_c, q_r, c, kr, *rest:
+        read(q_c, q_r, jnp.roll(c, 1, axis=0), jnp.roll(kr, 1, axis=0),
+             *rest))
+    with pytest.raises(chip_smoke.CheckFailed, match="latent geometry"):
+        chip_smoke.latent_logits_check(
+            _latent_tiny(), seed=5, slots=2, length=64, bucket=32,
+            prompt_lens=(20, 7), steps=4)
+
+
+def test_latent_logits_check_holds_a_near_tie_to_its_own_bound():
+    """With every position called a near-tie (a margin no routing
+    reaches) nothing is left to hold to the clean bound, and the check
+    says so instead of passing on nothing."""
+    with pytest.raises(chip_smoke.CheckFailed, match="holds too few"):
+        chip_smoke.latent_logits_check(
+            _latent_tiny(), seed=5, slots=2, length=64, bucket=32,
+            prompt_lens=(20, 7), steps=4, tie_margin=2.0)
+
+
+def test_expert_ffn_check_tiny():
+    out = chip_smoke.expert_ffn_check(
+        _latent_tiny(), seed=2 ** 31 + 3, row_counts=(6, 80),
+        weight_bound=1e-6, row_bound=1e-5)
+    assert out[80]["other_picks"] == 0 and out[80]["row"] < 1e-5
+
+
+def _planted(monkeypatch, fault):
+    """Faults a correct-looking expert FFN could hide."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.nn.layers import expert_ffn
+
+    if fault == "the next expert's weights":
+        apply = expert_ffn._Stacked.forward
+        monkeypatch.setattr(
+            expert_ffn._Stacked, "forward", lambda self, rows, sizes=None:
+            apply(self, rows, None if sizes is None else jnp.roll(sizes, 1)))
+    elif fault == "a bfloat16 router":
+        def route(self, flat):
+            scores = jax.nn.sigmoid(jnp.dot(
+                flat.astype(jnp.bfloat16),
+                self.router.weight.astype(jnp.bfloat16)).astype(jnp.float32))
+            _, picked = jax.lax.top_k(scores, self.top_k)
+            w = jnp.take_along_axis(scores, picked, axis=-1)
+            return picked.astype(jnp.int32), (
+                w / jnp.sum(w, -1, keepdims=True) * self.routed_scaling_factor)
+        monkeypatch.setattr(expert_ffn.ExpertFFN, "route", route)
+    elif fault == "weights left unnormalised":
+        route = expert_ffn.ExpertFFN.route
+        monkeypatch.setattr(
+            expert_ffn.ExpertFFN, "route", lambda self, flat:
+            (lambda picked, w: (picked, w * 1.05))(*route(self, flat)))
+
+
+@pytest.mark.parametrize("fault, names", [
+    ("the next expert's weights", "a row is"),
+    ("a bfloat16 router", "pick other experts|routing weights"),
+    ("weights left unnormalised", "routing weights"),
+])
+def test_expert_ffn_check_notices(monkeypatch, fault, names):
+    """At the chip's bounds (bfloat16's), on float32 arithmetic."""
+    _planted(monkeypatch, fault)
+    with pytest.raises(chip_smoke.CheckFailed, match=names):
+        chip_smoke.expert_ffn_check(_latent_tiny(), seed=7,
+                                    row_counts=(80,))
+
+
+def test_mixer_check_tiny():
+    out = chip_smoke.mixer_check(_latent_tiny(), seed=2 ** 31 + 5,
+                                 positions=12, bound=1e-5)
+    assert set(out) == {"input", "H_post", "M", "streams"}
+
+
+def test_mixer_check_notices_bfloat16_sinkhorn(monkeypatch):
+    import jax.numpy as jnp
+    from paddle_tpu.models import xing
+
+    steps = xing.sinkhorn
+    monkeypatch.setattr(
+        xing, "sinkhorn", lambda m, iters, eps: [
+            [e.astype(jnp.float32) for e in row] for row in steps(
+                [[e.astype(jnp.bfloat16) for e in row] for row in m],
+                iters, eps)])
+    with pytest.raises(chip_smoke.CheckFailed, match="stream mixer"):
+        chip_smoke.mixer_check(_latent_tiny(), seed=7, positions=12)
+
+
 def test_serve_phase_notices_the_scatter():
     """On the CPU the decode program keeps the scatter: a phase that
     expects the kernel, as the chip's does, must fail."""

@@ -101,7 +101,8 @@ def test_no_private_name_crosses_a_module():
 def test_clients_take_cache_names_from_the_owner_alone(rel):
     """Whatever a client imports with ``cache`` or ``kv`` in its name
     (or ``CacheRow``) comes from ``models.kv_cache``; the models' one
-    call, ``attend_with_cache``, is ``lm_utils``'s own."""
+    call, ``attend_with_cache`` (``attend_with_latent_cache`` for a
+    latent entry), is ``lm_utils``'s own."""
     for module, names in _imports(_tree(rel)):
         cache_names = [n for n in names
                        if "cache" in n.lower() or "kv" in n.lower()]
@@ -115,6 +116,7 @@ def test_clients_take_cache_names_from_the_owner_alone(rel):
 def test_the_old_homes_define_no_cache_function():
     for rel, allowed in (("models/generation.py", set()),
                          ("models/lm_utils.py", {"attend_with_cache",
+                                                 "attend_with_latent_cache",
                                                  "cached_lm_forward"})):
         defined = {node.name for node in _tree(rel).body
                    if isinstance(node, (ast.FunctionDef, ast.ClassDef))}
