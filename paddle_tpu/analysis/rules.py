@@ -378,9 +378,12 @@ def build_taints(project: Project, cg: CallGraph) -> Dict[str, Taint]:
             if f.trace_root or f.qualname not in passed_any:
                 continue
             base = _default_seeds(f)
+            # a param no resolved caller passes holds its default, and a
+            # constant default (``top_k=0``, ``use_top_p=None``) is no tracer
             new = {p for p in base
                    if p in passed_tainted.get(f.qualname, set())
-                   or p not in passed_any[f.qualname]}
+                   or (p not in passed_any[f.qualname]
+                       and not isinstance(f.defaults.get(p), ast.Constant))}
             if new != seeds[f.qualname]:
                 seeds[f.qualname] = new
                 taints[f.qualname] = Taint(f, new)

@@ -45,7 +45,8 @@ from .kv_cache import (cache_geometry, constrain_cache, init_cache,
                        normalize_kv_dtype)
 
 __all__ = ["GenerationEngine", "generate", "sample_logits", "filter_logits",
-           "sample_logits_rows", "per_row_keys", "DEFAULT_PREFILL_BUCKETS"]
+           "sample_logits_rows", "sample_branch", "per_row_keys",
+           "DEFAULT_PREFILL_BUCKETS"]
 
 # prompt lengths round up to the smallest of these (clipped to the
 # model's max_length) — the serving analogue of DataLoader length_buckets
@@ -134,26 +135,76 @@ def per_row_keys(key, batch: int, position=None):
         k, jnp.arange(batch, dtype=jnp.uint32))
 
 
+def sample_branch(live, greedy_mask, top_p):
+    """What a batch's live rows ask of the sampler, as one number: 0 when
+    every live row is greedy (or none is live), 1 when some live row
+    samples and none of those with ``top_p < 1``, 2 when one does.
+    :func:`sample_logits_rows` switches on it inside the program and the
+    serving engine counts it on its host vectors (``[B]`` arrays of jax
+    or of numpy alike): one test, so the count says what the program
+    ran."""
+    samples = live & ~greedy_mask
+    return (samples.any().astype("int32")
+            + (samples & (top_p < 1.0)).any().astype("int32"))
+
+
 @jax.named_scope("sample")
 def sample_logits_rows(logits, row_keys, temperature=1.0, top_k: int = 0,
-                       top_p=1.0, *, use_top_p: bool = False,
-                       greedy_mask=None):
+                       top_p=1.0, *, use_top_p: Optional[bool] = None,
+                       greedy_mask=None, live=None):
     """Next-token selection on ``logits`` [B, V] with one key PER ROW.
 
     ``temperature``/``top_p`` may be scalars or per-row ``[B]`` vectors
-    (traced — sweeping values never recompiles); ``top_k``/``use_top_p``
-    stay static. ``greedy_mask`` ([B] bool, may be traced) selects argmax
-    per row — a mixed greedy/sampled batch is ONE program, which is what
-    lets the serving decode step hold heterogeneous requests."""
+    (traced — sweeping values never recompiles); ``top_k`` stays static.
+    ``greedy_mask`` ([B] bool, may be traced) selects argmax per row — a
+    mixed greedy/sampled batch is ONE program, which is what lets the
+    serving decode step hold heterogeneous requests.
+
+    ``use_top_p`` as in :func:`filter_logits`: a bool where the caller
+    knows at trace time (the offline engines' one ``top_p`` a batch);
+    ``None`` decides from the values, here a step at a time. The program
+    then takes ONE ``lax.switch`` on :func:`sample_branch` over the rows
+    that are ``live`` ([B] bool, ``None`` = all; a serving batch's free
+    slots keep their last request's settings and must not count):
+    argmax alone, the categorical draw without the nucleus filter, or the
+    whole graph. The switch is taken once, on the batch (under a
+    ``vmap`` a ``cond`` lowers to a ``select`` that runs both sides), so
+    a step whose live rows are all greedy runs no divide, no O(V log V)
+    sort, no softmax and draws no random bits. Every branch gives every live
+    row the token the whole graph would: a greedy row is
+    ``argmax(logits)`` in all three, and ``top_p >= 1`` is an exact
+    no-op of the filter by value."""
     B = logits.shape[0]
     temp = jnp.broadcast_to(jnp.asarray(temperature, jnp.float32), (B,))
     tp = jnp.broadcast_to(jnp.asarray(top_p, jnp.float32), (B,))
+    # the filter on the batch as [B, V], not under vmap as [B, 1, V]: in a
+    # conditional's branch the TPU compiler tiles the latter one row a tile
+    # (T(1,128) where the open graph gets T(8,128)) and the sort over the
+    # vocabulary takes eight times as long (20 ms against 2.6 at
+    # [48, 50304]: PERF.md section 6, PR 33)
+    t, p = temp[:, None], tp[:, None]
+    if use_top_p is not None:
+        return _draw_rows(filter_logits(logits, t, top_k, p, use_top_p),
+                          row_keys, logits, greedy_mask)
+    ones = jnp.ones((B,), bool)
+    index = sample_branch(
+        ones if live is None else jnp.asarray(live),
+        ~ones if greedy_mask is None else jnp.asarray(greedy_mask), tp)
+    return jax.lax.switch(index, (
+        lambda: jnp.argmax(logits, axis=-1).astype(jnp.int32),
+        lambda: _draw_rows(filter_logits(logits, t, top_k, p, False),
+                           row_keys, logits, greedy_mask),
+        lambda: _draw_rows(filter_logits(logits, t, top_k, p, True),
+                           row_keys, logits, greedy_mask)))
 
-    def row(l, k, t, p):
-        return sample_logits(l[None], k, t, top_k, p, greedy=False,
-                             use_top_p=use_top_p)[0]
 
-    sampled = jax.vmap(row)(logits, row_keys, temp, tp)
+def _draw_rows(filtered, row_keys, logits, greedy_mask):
+    """One categorical draw a row over its ``filtered`` logits ``[B, V]``,
+    argmax of ``logits`` where ``greedy_mask`` says so. A row's draw has
+    the ``[1, V]`` of a batch-1 :func:`sample_logits`, so its key gives
+    the bits it gives there."""
+    sampled = jax.vmap(lambda k, row: jax.random.categorical(
+        k, row[None], axis=-1)[0])(row_keys, filtered).astype(jnp.int32)
     if greedy_mask is None:
         return sampled
     greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)

@@ -48,7 +48,7 @@ import numpy as np
 from ..framework import compile_cache
 from ..io.batching import bucket_for
 from ..models.generation import (DEFAULT_PREFILL_BUCKETS, per_row_keys,
-                                 sample_logits_rows)
+                                 sample_branch, sample_logits_rows)
 from ..models.kv_cache import (cache_entries, cache_entry_kind,
                                cache_geometry, cache_nbytes,
                                cache_paths, cache_row_buffers,
@@ -79,10 +79,14 @@ class ContinuousBatchingEngine:
     """The compiled slot-scatter prefill + vector-position decode pair and
     the host-side slot table for one model.
 
-    ``top_k``/``allow_top_p`` are engine-level statics (they change the
-    compiled sampling graph); everything else — temperature, top_p value,
+    ``top_k`` is an engine-level static (it changes the compiled
+    sampling graph); everything else — temperature, top_p value,
     greedy-vs-sample, eos id, seed — is per-request and traced, so a
-    heterogeneous batch still runs the single decode program.
+    heterogeneous batch still runs the single decode program, which
+    samples what the step's live slots ask for and no more
+    (``generation.sample_logits_rows``: an all-greedy step takes an
+    argmax; the nucleus filter's sort runs only in a step where a live
+    slot set ``top_p < 1``).
 
     ``prefix_cache`` (None | BlockPool | True | byte budget | kwargs
     dict) switches admission to the paged-pool program: matched prompt
@@ -107,7 +111,7 @@ class ContinuousBatchingEngine:
     def __init__(self, model, slots: int = 4,
                  max_length: Optional[int] = None,
                  prefill_buckets: Optional[Sequence[int]] = None,
-                 top_k: int = 0, allow_top_p: bool = True,
+                 top_k: int = 0,
                  prefix_cache=None, adapter_store=None, kv_dtype=None):
         if slots < 1:
             raise ValueError(f"need at least one slot, got {slots}")
@@ -118,7 +122,6 @@ class ContinuousBatchingEngine:
         self.max_length, self.prefill_buckets = cache_geometry(
             spec, max_length, prefill_buckets or DEFAULT_PREFILL_BUCKETS)
         self.top_k = int(top_k)
-        self.allow_top_p = bool(allow_top_p)
         self.pool = self._normalize_pool(prefix_cache)
         self.store = self._normalize_store(adapter_store)
         #: whose time admit() and step() divide at their device waits.
@@ -274,6 +277,9 @@ class ContinuousBatchingEngine:
         #: and the cached positions their queries read (each slot's
         #: position, the new token's own key included)
         self.step_load = (0, 0)
+        #: which branch of the sampler the last decode step took
+        #: (``generation.sample_branch`` on the vectors it was given)
+        self.step_sample_branch = 0
         self.requests: List[Optional[object]] = [None] * B
 
     def sync_weights(self) -> None:
@@ -331,7 +337,6 @@ class ContinuousBatchingEngine:
         rows = per_row_keys(key, 1)
         next_tok = sample_logits_rows(
             logits, rows, temperature, self.top_k, top_p,
-            use_top_p=self.allow_top_p,
             greedy_mask=jnp.asarray(greedy).reshape(1))
         live_cache = constrain_cache(live_cache)
         done = next_tok[0] == eos_id
@@ -363,7 +368,6 @@ class ContinuousBatchingEngine:
         rows = per_row_keys(key, 1)
         next_tok = sample_logits_rows(
             logits, rows, temperature, self.top_k, top_p,
-            use_top_p=self.allow_top_p,
             greedy_mask=jnp.asarray(greedy).reshape(1))
         pool = scatter_cache_blocks(pool, slot_cache, write_idx)
         live_cache = scatter_cache_rows(live_cache, slot_cache, slot)
@@ -425,7 +429,7 @@ class ContinuousBatchingEngine:
             lambda k, p: per_row_keys(k, 1, position=p)[0])(keys, positions)
         next_tok = sample_logits_rows(
             logits, step_keys, temperature, self.top_k, top_p,
-            use_top_p=self.allow_top_p, greedy_mask=greedy_mask)
+            greedy_mask=greedy_mask, live=~done)
         fill = jnp.maximum(eos, 0)
         next_tok = jnp.where(done, fill, next_tok)
         done = done | (next_tok == eos)
@@ -669,6 +673,8 @@ class ContinuousBatchingEngine:
         clock = self.clock
         lora_args = () if self.store is None else (
             self.store.tensors, self._adapter_slots)
+        self.step_sample_branch = int(sample_branch(
+            ~self._done, self._greedy, self._top_p))
         with self._eval_mode():
             compile_cache.record_call(self._cc_decode)
             tok, done, self.live_cache, self._expert_load = (

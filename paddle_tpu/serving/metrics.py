@@ -30,7 +30,8 @@ from typing import Dict, List, Optional
 from ..observability import registry as _obs_registry
 from ..observability import tracing as _tracing
 
-__all__ = ["LOOP_PHASES", "LatencyHistogram", "LoopClock", "ServingMetrics"]
+__all__ = ["LOOP_PHASES", "SAMPLE_BRANCHES", "LatencyHistogram", "LoopClock",
+           "ServingMetrics"]
 
 #: What the serve loop's thread can be doing; disjoint, and together its
 #: whole wall time. ``admit_wait`` and ``decode_wait`` wait for the
@@ -40,6 +41,11 @@ __all__ = ["LOOP_PHASES", "LatencyHistogram", "LoopClock", "ServingMetrics"]
 #: but not running (the interpreter lock, another lock, the machine).
 LOOP_PHASES = ("idle", "schedule", "admit_host", "admit_wait",
                "decode_dispatch", "decode_wait", "emit")
+
+#: What a decode step's sampler ran, by ``generation.sample_branch``'s
+#: number: an argmax alone (every live slot greedy), the categorical draw
+#: without the nucleus filter, or the whole graph with its sort.
+SAMPLE_BRANCHES = ("argmax_steps", "categorical_steps", "nucleus_steps")
 
 _metrics_serial = itertools.count()
 
@@ -238,6 +244,9 @@ class ServingMetrics:
             # decode steps; the same writer, and no lock for the same
             # reason
             self._decode = [0, 0, 0]
+            # decode steps by the sampler's branch (SAMPLE_BRANCHES);
+            # the same writer again
+            self._sample = [0, 0, 0]
 
     # ------------------------------------------------------------ events
     def decode_step(self, live_slots: int, live_positions: int) -> None:
@@ -249,6 +258,12 @@ class ServingMetrics:
         d[0] += 1
         d[1] += live_slots
         d[2] += live_positions
+
+    def sample_step(self, branch: int) -> None:
+        """Book which branch of the sampler one decode step took
+        (``engine.step_sample_branch``: the program's own test, made on
+        the host vectors the step was given)."""
+        self._sample[branch] += 1
 
     def loop_phase(self, phase: str, wall_ns: int, cpu_ns: int) -> None:
         """Book one ended instance of a serve-loop phase (the loop
@@ -375,6 +390,7 @@ class ServingMetrics:
                          for p, c in self._loop.items()},
                 "decode": dict(zip(("steps", "live_slot_steps",
                                     "live_position_steps"), self._decode)),
+                "sample": dict(zip(SAMPLE_BRANCHES, self._sample)),
                 **({"compile_stats": compile_stats}
                    if compile_stats is not None else {}),
                 **({"prefix_cache": prefix_cache}

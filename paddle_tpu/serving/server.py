@@ -169,8 +169,8 @@ class InferenceServer:
     ``cache_spec()``/the cached forward (GPT/Llama families).
 
     ``slots`` fixes the decode batch geometry (the ONE compiled decode
-    program); ``top_k``/``allow_top_p`` are compile-time sampling
-    statics; every other sampling knob is per-request. Construction is
+    program); ``top_k`` is a compile-time sampling static; every other
+    sampling knob, ``top_p`` among them, is per-request. Construction is
     cheap — programs compile on first use, per prefill bucket.
     """
 
@@ -179,7 +179,7 @@ class InferenceServer:
                  prefill_buckets=None,
                  max_queue_depth: int = 64,
                  max_prefills_per_step: int = 2,
-                 top_k: int = 0, allow_top_p: bool = True,
+                 top_k: int = 0,
                  max_request_retries: int = 1,
                  prefix_cache=None, adapter_store=None,
                  shed_on_overload: bool = False,
@@ -191,7 +191,7 @@ class InferenceServer:
         self.engine = ContinuousBatchingEngine(
             network, slots=slots, max_length=max_length,
             prefill_buckets=prefill_buckets, top_k=top_k,
-            allow_top_p=allow_top_p, prefix_cache=prefix_cache,
+            prefix_cache=prefix_cache,
             adapter_store=adapter_store, kv_dtype=kv_dtype)
         self.scheduler = FifoScheduler(
             max_queue_depth=max_queue_depth,
@@ -258,12 +258,6 @@ class InferenceServer:
         request keeps ONE lane across replicas."""
         prompt = np.asarray(prompt, np.int32).ravel()
         self.engine.validate(int(prompt.shape[0]), int(max_new_tokens))
-        if top_p < 1.0 and not self.engine.allow_top_p:
-            raise ValueError(
-                "this server was built with allow_top_p=False (the "
-                "nucleus filter is not compiled into its sampling "
-                "graph); top_p requests would be silently ignored — "
-                "construct the server with allow_top_p=True")
         from ..lora.store import normalize_adapter_id
 
         adapter_id = normalize_adapter_id(adapter_id)
@@ -533,6 +527,7 @@ class InferenceServer:
         events = self.engine.step()     # leaves the clock in "emit"
         self.metrics.inc("decode_steps")
         self.metrics.decode_step(*self.engine.step_load)
+        self.metrics.sample_step(self.engine.step_sample_branch)
         per_adapter = self.engine.store is not None
         now = time.monotonic()
         for ev in events:

@@ -89,6 +89,31 @@ def as_on_tpu(show_the_gate_a_tpu, interpret_pallas):
     return interpret_pallas
 
 
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described v5e chip: the TPU's compiler is installed here and
+    refuses what the chip's would (a slice off the tiling, too much
+    VMEM), which interpret mode cannot show."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent cache
+    # and cannot be read back without the chip
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
 def pytest_configure(config):
     # tier-1 runs `-m 'not slow'` (ROADMAP): long decode/bench subprocess
     # tests opt out of the 870 s budget with this marker

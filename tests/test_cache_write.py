@@ -175,33 +175,6 @@ def test_gate_keeps_the_scatter_under_a_mesh(as_on_tpu):
 
 
 # ------------------------------------------- compiled for the chip, not run
-@pytest.fixture(scope="module")
-def one_chip():
-    """A described v5e chip: the TPU's compiler is installed here and
-    refuses what the chip's would (a slice off the tiling, too much
-    VMEM), which interpret mode cannot show."""
-    import os
-
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # a compile for a described device is written to the persistent cache
-    # and cannot be read back without the chip
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
-
-
 @pytest.mark.parametrize("cell,shape", [
     ("gpt3-medium.serve-chat", (48, 2048, 16, 64)),
     ("gpt3-xl.serve-batch", (24, 2048, 16, 128)),

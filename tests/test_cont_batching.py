@@ -203,18 +203,6 @@ def test_unseeded_sampled_requests_draw_fresh_randomness(lm, server):
     assert not np.array_equal(a, b)
 
 
-def test_top_p_rejected_on_server_without_nucleus_graph(lm):
-    """allow_top_p=False compiles sampling without the nucleus filter;
-    a top_p request on such a server must fail loudly at submit, never
-    be silently ignored. (No dispatch — construction compiles nothing.)"""
-    model, _ = lm
-    srv = InferenceServer(model, slots=1, allow_top_p=False, **GEO)
-    with pytest.raises(ValueError, match="allow_top_p"):
-        srv.submit(np.arange(4, dtype=np.int32), max_new_tokens=2,
-                   do_sample=True, top_p=0.5)
-    srv.shutdown(drain=False, timeout=10)
-
-
 def test_metrics_snapshot_shape(server):
     snap = server.snapshot()
     for k in ("slot_occupancy", "tokens_per_sec", "requests_per_sec",

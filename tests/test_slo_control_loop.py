@@ -214,8 +214,6 @@ class _StubEngine:
     def validate(self, n, m):
         pass
 
-    allow_top_p = True
-
 
 def _stub_server(**sched_kw):
     """A real InferenceServer instance driving a REAL FifoScheduler
