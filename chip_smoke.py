@@ -33,7 +33,16 @@ and the example use (hidden 1024, 16 heads, vocab 50304, 24 layers):
   prompts prefilled into rows of a 32 x 8192 latent cache, 48 decode
   steps with each row at its own position, the LOGITS held to the plain
   float32 reference's full pass, positions within rounding of a routing
-  tie to a bound of their own (``latent_logits_check``);
+  tie to a bound of their own (``latent_logits_check``); then the state
+  geometry, the benchmark's hybrid state-space configuration whole (6 GB)
+  in bfloat16: two prompts right-padded to a bucket and prefilled into
+  rows of a 128 x 8192 cache whose entries are 26 recurrent states beside
+  2 key/value pairs, 16 decode steps, the LOGITS and the final scan
+  state and window held to the reference's full pass, and two planted
+  faults (pads that move the state, a window off by one) that must each
+  fail a bound (``state_logits_check``); before it one layer's
+  recurrence through a state entry on equal inputs against float64,
+  which a bfloat16 state must fail (``state_scan_check``);
 - **four_chip**: the train model through ``DistributedTrainStep`` on
   dp=2 x mp=2 and on sdp=4 with ZeRO-2, when the host has four chips.
 
@@ -50,6 +59,7 @@ lines; none of them is a benchmark result.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import gc
 import json
 import math
@@ -561,6 +571,13 @@ LATENT_CLEAN_BOUND = 0.1
 LATENT_TIE_BOUND = 0.2
 
 
+def _bench_config(name: str) -> dict:
+    """A benchmark configuration file's content."""
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmarks", "configs", name + ".json")) as f:
+        return json.load(f)
+
+
 def _bench_harness():
     bench = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                          "benchmarks")
@@ -841,6 +858,321 @@ def latent_logits_check(config: dict, seed: int, slots: int, length: int,
     return out
 
 
+# Bounds of :func:`state_logits_check`, from readings on the chip (one v5e,
+# the configuration whole in bfloat16, rows 0 and 127 of 128 x 8192, 34
+# positions; PERF.md section 6, PR 34).
+#: The largest |logit - reference| of a position over the standard
+#: deviation of the reference's logits there: 0.107 as the program stands
+#: (rms 0.024; 0.134 before the branches' matmuls returned float32, 0.177
+#: with a bfloat16 residual stream besides), 5.87 with pads that move the
+#: state, 5.89 with a window off by one. The distance is bfloat16
+#: activations through 56 sublayers of freshly drawn weights, which grow
+#: a perturbation eightfold (in float32 too).
+STATE_LOGIT_BOUND = 0.25
+#: rms(h - reference) over rms(reference) of a row's scan state after the
+#: last step, worst of 26 layers: 0.026 as it stands, 8.03 and 1.63 with
+#: the faults; the same for the window: 0.018, 1.17 and 1.41.
+STATE_BOUND = 0.1
+WINDOW_BOUND = 0.1
+STATE_FAULTS = ("pads that move the state", "a window off by one")
+#: :func:`state_scan_check`: rms(y - reference) over rms(reference) of the
+#: recurrence's outputs on EQUAL inputs, and the same of the final state:
+#: on the chip a float32 state read 4.0e-6 and 1.7e-5 of float64, a
+#: bfloat16 state 1.1e-3 and 5.3e-3.
+STATE_SCAN_BOUND = 1e-4
+
+
+@contextlib.contextmanager
+def _planted_state_fault(fault):
+    """Plant what a correct-looking recurrent cache could hide."""
+    from unittest import mock
+
+    import jax.numpy as jnp
+    from paddle_tpu.models import lm_utils
+
+    if fault is None:
+        yield
+    elif fault == "pads that move the state":
+        with mock.patch.object(lm_utils, "block_length",
+                               lambda n: contextlib.nullcontext()):
+            yield
+    elif fault == "a window off by one":
+        write = lm_utils.write_state
+        with mock.patch.object(
+                lm_utils, "write_state", lambda cache, h, window:
+                write(cache, h, jnp.roll(window, 1, axis=1))):
+            yield
+    else:
+        raise ValueError(fault)
+
+
+def state_logits_check(config: dict, seed: int, slots: int, length: int,
+                       bucket: int, prompt_lens, steps: int = 16,
+                       logit_bound: float = STATE_LOGIT_BOUND,
+                       state_bound: float = STATE_BOUND,
+                       window_bound: float = WINDOW_BOUND,
+                       faults=STATE_FAULTS) -> dict:
+    """Prefill of a PADDED bucket, then ``steps`` decode steps through a
+    cache that holds recurrent-state entries beside keys and values, at a
+    serve cell's geometry, against the plain reference's full pass:
+    ``config`` is a benchmark configuration file's content. Two seeded
+    texts of ``prompt_lens`` tokens, right-padded to ``bucket``, go into
+    the first and the last row of a ``slots x length`` cache by the
+    engine's own admission path (``cache_row_view``, the prompt's length
+    told through ``gather_last``); the decode steps run the WHOLE batch
+    with each row at its own position (the other rows decode filler),
+    teacher-forced on seeded tokens. Compared: the logits at the last
+    prompt position and at every decode step (a position's error is the
+    largest |logit - reference| over the standard deviation of the
+    reference's logits there), and, after the last step, each row's scan
+    state and window in every recurrent layer against the state and the
+    last inputs the reference ends with (rms of the difference over the
+    reference's rms, worst layer). Then each of ``faults`` is planted in
+    turn and the same comparison must FAIL by at least one of the
+    bounds: a bound that pads that move the state or a window off by one
+    could pass holds nothing. What this comparison CANNOT see is the
+    state's own precision: on the chip a bfloat16 state read the same as
+    a float32 one here, logits and states alike (what reaches the state
+    from bfloat16 activations differs from the reference by more than the
+    state's rounding adds); :func:`state_scan_check` holds that."""
+    import jax
+    import jax.numpy as jnp
+    common = _bench_harness()
+    from paddle_tpu.models.kv_cache import (cache_entry_kinds,
+                                            cache_row_buffers,
+                                            cache_row_view, init_cache)
+    from paddle_tpu.nn.layer import (buffer_state, functional_call,
+                                     param_state)
+
+    t0 = time.perf_counter()
+    model = common.build_model(config, None, seed)
+    model.eval()
+    params, buffers = param_state(model), buffer_state(model)
+    kinds = cache_entry_kinds(model.cache_spec())
+    state_layers = [i for i, k in enumerate(kinds) if k == "state"]
+    nbytes = sum(p.size * p.dtype.itemsize for p in params.values())
+    log(f"[serve] state: model built in {time.perf_counter() - t0:.1f} s, "
+        f"{sum(p.size for p in params.values()) / 1e9:.3f} B parameters, "
+        f"{nbytes / 1e9:.2f} GB, {len(state_layers)} state entries of "
+        f"{len(kinds)}, dtypes "
+        f"{sorted({str(p.dtype) for p in params.values()})}")
+    vocab = config["config"]["vocab_size"]
+    rng = np.random.default_rng(seed)
+    texts = [rng.integers(0, vocab, n + steps).astype(np.int32)
+             for n in prompt_lens]
+    rows = (0, slots - 1)
+
+    reference = common.resolve(config["reference"])
+    refs = []
+    for n, text in zip(prompt_lens, texts):
+        t1 = time.perf_counter()
+        states = []
+        ref = reference.logits(params, config["config"], text[None],
+                               states=states)[0][n - 1:n + steps]
+        refs.append((ref, states[0]))
+        log(f"[serve] state: reference pass over {n} + {steps} tokens "
+            f"{time.perf_counter() - t1:.1f} s")
+
+    def run(fault):
+        def prefill(params, buffers, cache, ids, row, last):
+            (logits, view), _ = functional_call(
+                model, params, buffers, ids, cache=cache_row_view(cache, row),
+                position_offset=0, gather_last=last)
+            return logits[0, 0].astype(jnp.float32), cache_row_buffers(view)
+
+        def decode(params, buffers, cache, tokens, positions):
+            (logits, cache), _ = functional_call(
+                model, params, buffers, tokens, cache=cache,
+                position_offset=positions)
+            return logits[:, 0].astype(jnp.float32), cache
+
+        t1 = time.perf_counter()
+        prefill = jax.jit(prefill, donate_argnums=2)
+        decode = jax.jit(decode, donate_argnums=2)
+        cache = init_cache(model, slots, length)
+        got = {r: [] for r in rows}
+        with _planted_state_fault(fault):
+            for r, n, text in zip(rows, prompt_lens, texts):
+                ids = np.zeros((1, bucket), np.int32)
+                ids[0, :n] = text[:n]
+                logits, cache = prefill(params, buffers, cache, ids,
+                                        np.int32(r), np.int32(n - 1))
+                got[r].append(np.asarray(logits))
+            for i in range(steps):
+                tokens = np.zeros((slots, 1), np.int32)
+                positions = np.zeros(slots, np.int32)
+                for r, n, text in zip(rows, prompt_lens, texts):
+                    tokens[r, 0], positions[r] = text[n + i], n + i
+                logits, cache = decode(params, buffers, cache, tokens,
+                                       positions)
+                logits = np.asarray(logits)
+                for r in rows:
+                    got[r].append(logits[r])
+        worst, rms, short, h_err, w_err = [], [], [], [], []
+        rel = lambda a, b: float(np.sqrt(np.mean((a - b) ** 2)
+                                         / np.mean(b ** 2)))
+        for r, (ref, states) in zip(rows, refs):
+            diff = np.stack(got[r]) - ref
+            std = ref.std(axis=-1)
+            worst.extend((np.abs(diff).max(axis=-1) / std).tolist())
+            rms.extend((np.sqrt(np.mean(diff ** 2, axis=-1)) / std).tolist())
+            token = np.stack(got[r]).argmax(-1)
+            short.extend(((ref.max(-1) - ref[np.arange(len(ref)), token])
+                          / std).tolist())
+            for layer, (h_ref, u_ref) in zip(state_layers, states):
+                h, window = (np.asarray(x[r], np.float32)
+                             for x in cache[layer])
+                h_err.append(rel(h, h_ref))
+                w_err.append(rel(window, u_ref[-window.shape[0]:]))
+        del cache
+        out = {"logit_worst": float(max(worst)),
+               "logit_rms_worst": float(max(rms)),
+               "shortfall_worst": float(max(short)),
+               "state_worst": float(max(h_err)),
+               "window_worst": float(max(w_err))}
+        log(f"[serve] state, {fault or 'as it stands'}: prefill of "
+            f"{list(prompt_lens)} tokens (bucket {bucket}) into rows "
+            f"{list(rows)} of {slots} x {length}, then {steps} decode steps, "
+            f"in {time.perf_counter() - t1:.1f} s: largest |logit - "
+            f"reference| over the logits' std {out['logit_worst']:.4f} "
+            f"(bound {logit_bound}), rms {out['logit_rms_worst']:.4f}; scan "
+            f"state off by {out['state_worst']:.5f} of its rms (bound "
+            f"{state_bound}), window by {out['window_worst']:.5f} (bound "
+            f"{window_bound}); greedy tokens fall short of the reference's "
+            f"best by {out['shortfall_worst']:.4f} at most")
+        return out
+
+    def passes(o):
+        return (o["logit_worst"] <= logit_bound
+                and o["state_worst"] <= state_bound
+                and o["window_worst"] <= window_bound)
+
+    out = run(None)
+    check(passes(out),
+          f"state geometry: a logit is {out['logit_worst']:.4f} of the "
+          f"logits' std from the reference (bound {logit_bound}), the scan "
+          f"state {out['state_worst']:.5f} of its rms (bound {state_bound}), "
+          f"the window {out['window_worst']:.5f} (bound {window_bound})")
+    out["faults"] = {}
+    for fault in faults:
+        bad = out["faults"][fault] = run(fault)
+        check(not passes(bad),
+              f"state geometry: {fault} passes every bound, so they hold "
+              f"nothing: {bad}")
+    return out
+
+
+def state_scan_check(config: dict, seed: int, slots: int = 16,
+                     prompt: int = 40, bucket: int = 64, steps: int = 64,
+                     bound: float = STATE_SCAN_BOUND) -> dict:
+    """One recurrent layer's state through the cache on EQUAL inputs, at
+    the configuration's widths: seeded convolution inputs, step sizes (log
+    uniform in Mamba's [1e-3, 1e-1]), B and C go through
+    ``lm_utils.scan_with_state`` (a padded prefill of ``prompt`` positions
+    in a block of ``bucket``, then ``steps`` decode steps, every row at
+    once, the state living in a state entry as ``kv_cache.alloc_cache``
+    makes it) and through the same recurrence in numpy float64. With
+    equal inputs the outputs differ by the state's arithmetic alone:
+    float32 must come within ``bound`` of float64 (outputs and final
+    state, rms over rms) and the window must be the inputs' last ones bit
+    for bit; then the SAME run over a state leaf cast to bfloat16 must
+    fail the bound. This is what tells a bfloat16 state from a float32
+    one; :func:`state_logits_check` cannot."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models import lm_utils
+    from paddle_tpu.models.kv_cache import alloc_cache
+
+    cfg = config["config"]
+    d = cfg["mamba_expand"] * cfg["hidden_size"]
+    n, K = cfg["mamba_d_state"], cfg["mamba_d_conv"]
+    dtype = jnp.dtype(config["run"]["dtype"])
+    spec = {"num_layers": 1, "entry_kinds": ("state",), "num_kv_heads": 1,
+            "head_dim": 1, "state": (n, K - 1, d), "max_length": 1,
+            "dtype": str(dtype)}
+    total = prompt + steps
+    rng = np.random.default_rng(seed)
+    u_pre = jnp.asarray(rng.standard_normal((slots, total, d)), dtype)
+    delta = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1),
+                               (slots, total, d))).astype(np.float32)
+    Bm, Cm = (rng.standard_normal((slots, total, n)).astype(np.float32)
+              for _ in range(2))
+    w = jnp.asarray(rng.uniform(-0.5, 0.5, (K, d)), dtype)
+    b = jnp.asarray(rng.uniform(-0.5, 0.5, (d,)), dtype)
+    A = -np.broadcast_to(np.arange(1, n + 1, dtype=np.float32)[:, None],
+                         (n, d))
+    D = np.ones(d, np.float32)
+
+    def at(first, length):
+        cut = lambda x: jax.lax.dynamic_slice_in_dim(x, first, length, axis=1)
+        return lambda u: (cut(delta), cut(Bm), cut(Cm))
+
+    @jax.jit
+    def prefill(cache):
+        block = jnp.zeros((slots, bucket, d), dtype).at[:, :prompt].set(
+            u_pre[:, :prompt])
+        pad = lambda x: jnp.pad(x[:, :prompt],
+                                ((0, 0), (0, bucket - prompt), (0, 0)),
+                                constant_values=0.05)
+        with lm_utils.block_length(jnp.int32(prompt)):
+            return lm_utils.scan_with_state(
+                block, w, b, lambda u: (pad(delta), pad(Bm), pad(Cm)),
+                jnp.asarray(A), jnp.asarray(D), cache, 0)
+
+    @jax.jit
+    def step(cache, t):
+        return lm_utils.scan_with_state(
+            jax.lax.dynamic_slice_in_dim(u_pre, t, 1, axis=1), w, b,
+            at(t, 1), jnp.asarray(A), jnp.asarray(D), cache,
+            jnp.full((slots,), t, jnp.int32))
+
+    def run(state_dtype):
+        cache = alloc_cache(spec, slots, 1)[0]
+        cache = (cache[0].astype(state_dtype), cache[1])
+        y, cache = prefill(cache)
+        ys = [np.asarray(y[:, :prompt])]
+        for t in range(prompt, total):
+            y, cache = step(cache, jnp.int32(t))
+            ys.append(np.asarray(y))
+        return (np.concatenate(ys, axis=1), np.asarray(cache[0], np.float32),
+                np.asarray(cache[1], np.float32))
+
+    # the same recurrence in float64, position by position
+    f64 = lambda x: np.asarray(x, np.float64)
+    up = np.concatenate([np.zeros((slots, K - 1, d)),
+                         f64(u_pre.astype(jnp.float32))], axis=1)
+    u = f64(b.astype(jnp.float32)) + sum(
+        f64(w.astype(jnp.float32))[k] * up[:, k:k + total] for k in range(K))
+    u = u / (1.0 + np.exp(-u))
+    h = np.zeros((slots, n, d))
+    y_ref = np.empty((slots, total, d))
+    for t in range(total):
+        dt = f64(delta[:, t])
+        h = (np.exp(dt[:, None, :] * A) * h
+             + (dt * u[:, t])[:, None, :] * f64(Bm[:, t])[:, :, None])
+        y_ref[:, t] = np.sum(h * f64(Cm[:, t])[:, :, None], axis=1) + D * u[:, t]
+    rel = lambda a, r: float(np.sqrt(np.mean((a - r) ** 2) / np.mean(r ** 2)))
+    out = {}
+    for name, state_dtype in (("float32", jnp.float32),
+                              ("bfloat16", jnp.bfloat16)):
+        y, h_got, window = run(state_dtype)
+        out[name] = {"y": rel(y, y_ref), "h": rel(h_got, h),
+                     "window_exact": bool(np.array_equal(
+                         window, up[:, -(K - 1):].astype(np.float32)))}
+    log(f"[serve] state scan, {slots} rows x {prompt} positions in a block "
+        f"of {bucket} then {steps} steps, d {d}, n {n}, equal inputs, "
+        f"against float64: float32 state {out['float32']}, bfloat16 state "
+        f"{out['bfloat16']} (bound {bound})")
+    good, bad = out["float32"], out["bfloat16"]
+    check(good["y"] <= bound and good["h"] <= bound and good["window_exact"],
+          f"state scan: the float32 state is {good} from float64 on equal "
+          f"inputs (bound {bound}, and the window bit for bit)")
+    check(bad["y"] > bound or bad["h"] > bound,
+          f"state scan: a bfloat16 state passes the bound ({bad}), so it "
+          f"holds nothing")
+    return out
+
+
 # ------------------------------------------------------------ four chips
 def four_chip_phase(cfg, batch: int, seq: int, ref_first_loss: float,
                     n_devices: int = 4, loss_tol: float = 0.05,
@@ -1010,16 +1342,21 @@ def main(argv=None) -> int:
                 prompt_lens=(20, 50, 100, 200, 400, 900), n_requests=32)
             _release_device_memory()
             # the latent entry at its cell's geometry (32 slots x 8192)
-            with open(os.path.join(os.path.dirname(os.path.abspath(
-                    __file__)), "benchmarks", "configs",
-                    "xing4.0-29b-a4b.json")) as f:
-                latent = json.load(f)
+            latent = _bench_config("xing4.0-29b-a4b")
             expert_ffn_check(latent, seed=2147483659)
             _release_device_memory()
             mixer_check(latent, seed=2147483659)
-            return latent_logits_check(latent, seed=2147483659, slots=32,
-                                       length=8192, bucket=1024,
-                                       prompt_lens=(1000, 300), steps=48)
+            out = latent_logits_check(latent, seed=2147483659, slots=32,
+                                      length=8192, bucket=1024,
+                                      prompt_lens=(1000, 300), steps=48)
+            _release_device_memory()
+            # the state entry at its cell's geometry (128 slots x 8192)
+            hybrid = _bench_config("ai21-jamba2-3b")
+            out["state_scan"] = state_scan_check(hybrid, seed=2147483659)
+            out["state"] = state_logits_check(
+                hybrid, seed=2147483659, slots=128, length=8192, bucket=1024,
+                prompt_lens=(700, 2), steps=16)
+            return out
 
         _run_phase("serve", serve)
         done["serve"] = "passed"

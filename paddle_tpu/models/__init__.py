@@ -10,6 +10,7 @@ from . import bert  # noqa: F401
 from . import ernie  # noqa: F401
 from . import generation  # noqa: F401
 from . import gpt  # noqa: F401
+from . import jamba  # noqa: F401
 from . import kv_cache  # noqa: F401
 from . import llama  # noqa: F401
 from . import ouro  # noqa: F401
@@ -28,6 +29,8 @@ from .generation import (GenerationEngine, generate,  # noqa: F401
                          filter_logits, per_row_keys, sample_logits,
                          sample_logits_rows)
 from .gpt import GPTConfig, GPTForCausalLM, GPTModel, gpt_1p3b, gpt_tiny  # noqa: F401
+from .jamba import (JambaConfig, JambaForCausalLM, JambaModel,  # noqa: F401
+                    jamba_tiny)
 from .kv_cache import cache_nbytes, init_cache, scatter_cache_rows  # noqa: F401
 from .llama import (LlamaConfig, LlamaForCausalLM, LlamaModel,  # noqa: F401
                     llama2_7b, llama_tiny)

@@ -16,6 +16,19 @@ pytree under free functions. The models reach them through
 ``lm_utils.attend_with_cache`` alone, the engines and the prefix pool
 directly; this module imports nothing of theirs.
 
+A STATE entry (a recurrent mixer: ``spec["state"] = (d_state, window,
+d_inner)``, and ``spec["entry_kinds"]`` saying entry by entry which layers
+hold one) is a pair too, with rows leading and NO length axis: ``(h [B,
+d_state, d_inner] float32, window [B, window, d_inner])``, the scan state
+and the convolution's last inputs, all a slot carries whatever its
+position. The inner width lies on the lanes: ``[B, d_inner, d_state]``
+would pad each 16 to a tile's 128. It sits in the same tuple beside the
+``(k, v)`` pairs of the model's attention layers, so ``leaf[slot]`` is a
+slot's cache for every leaf and the row copies serve both. No mask hides a
+state: whoever starts a request in a row overwrites both leaves whole
+(:func:`write_state` through a :class:`CacheRow`), and the block copies,
+which need a length axis, are not for it (:func:`refuse_state_entries`).
+
 The continuous-batching decode step (one token a slot, each slot at its
 own position) has a kernel for each half, and this module is their one
 importer: the write as direct copies (:mod:`..kernels.cache_write`, gate
@@ -39,63 +52,104 @@ from ..framework.dtype import convert_dtype
 from ..kernels import cache_read, cache_write
 from ..quantization import is_quantized_kv, kv_dequantize, kv_quantize
 
-__all__ = ["cache_entries", "cache_layout", "cache_entry_kind",
-           "cache_entry_widths", "latent_attention", "cache_sharding_spec",
-           "normalize_kv_dtype", "alloc_cache", "init_cache", "cache_nbytes",
-           "cache_token_nbytes", "constrain_cache", "cache_geometry",
-           "CacheRow", "cache_row_view", "cache_row_buffers",
-           "update_kv_cache", "cache_paths", "cached_attention",
+__all__ = ["cache_entries", "state_entries", "cache_layout",
+           "cache_entry_kinds", "cache_entry_kind", "cache_entry_widths",
+           "refuse_state_entries", "latent_attention", "cache_sharding_spec",
+           "state_sharding_spec", "normalize_kv_dtype", "alloc_cache",
+           "init_cache", "cache_nbytes", "cache_split_nbytes",
+           "cache_token_nbytes", "cache_state_nbytes", "constrain_cache",
+           "cache_geometry", "CacheRow", "cache_row_view",
+           "cache_row_buffers", "update_kv_cache", "read_state",
+           "write_state", "cache_paths", "cached_attention",
            "scatter_cache_rows", "gather_cache_blocks",
            "scatter_cache_blocks"]
 
 
 # ------------------------------------------------- layout and allocation
+def cache_entry_kinds(spec: dict) -> tuple:
+    """What each pair of the cache's tuple holds, in order: ``"kv"``,
+    ``"latent"`` or ``"state"``. ``spec["entry_kinds"]`` is a model's own
+    pattern (a hybrid: one kind a layer); without it every pair is the
+    spec's one positional kind."""
+    kinds = spec.get("entry_kinds")
+    if kinds is not None:
+        return tuple(kinds)
+    return ("latent" if spec.get("latent") else "kv",) * cache_layout(spec)[0]
+
+
 def cache_entries(spec: dict) -> int:
-    """How many ``(k, v)`` entries the cache of a model's ``cache_spec()``
-    holds: one per layer application that writes keys and values. That
-    is ``spec["cache_entries"]``; a spec without the key has one per
-    layer."""
+    """How many entries of a model's ``cache_spec()`` are indexed by
+    position: one per layer application that writes keys and values (or
+    a latent pair). That is ``spec["cache_entries"]``; a spec without the
+    key has one per layer, one with a pattern those the pattern names."""
+    if spec.get("entry_kinds") is not None:
+        return sum(k != "state" for k in spec["entry_kinds"])
     return int(spec.get("cache_entries", spec["num_layers"]))
+
+
+def state_entries(spec: dict) -> int:
+    """How many entries hold a recurrent state and no positions."""
+    return sum(k == "state" for k in spec.get("entry_kinds") or ())
 
 
 def cache_layout(spec: dict):
     """``(pairs, stack)`` of a model's ``cache_spec()``: the cache is a
-    tuple of ``pairs`` ``(k, v)`` pairs whose leaves are ``[B, *stack, S,
-    Hkv, D]``. ``spec["entry_stack"]`` of the :func:`cache_entries` share
-    a leaf pair on an axis after the batch's (a looped model's recurrent
-    steps; 1 and no axis when absent), so that a program can index them
-    by a traced step. Rows lead whatever the stack: a slot's cache is
-    ``leaf[slot]`` for every model."""
-    entries = cache_entries(spec)
+    tuple of ``pairs`` pairs, and a ``(k, v)`` pair's leaves are ``[B,
+    *stack, S, Hkv, D]``. ``spec["entry_stack"]`` of the
+    :func:`cache_entries` share a leaf pair on an axis after the batch's
+    (a looped model's recurrent steps; 1 and no axis when absent), so
+    that a program can index them by a traced step. Rows lead whatever
+    the stack: a slot's cache is ``leaf[slot]`` for every model. A spec
+    with a pattern (:func:`cache_entry_kinds`) has one pair an entry of
+    it and no stack."""
     stack = int(spec.get("entry_stack", 1))
+    if spec.get("entry_kinds") is not None:
+        if stack > 1:
+            raise ValueError("entry_stack and entry_kinds do not combine")
+        return len(spec["entry_kinds"]), ()
+    entries = cache_entries(spec)
     if entries % stack:
         raise ValueError(f"cache_entries {entries} is no multiple of "
                          f"entry_stack {stack}")
     return entries // stack, ((stack,) if stack > 1 else ())
 
 
-def cache_entry_kind(spec: dict) -> str:
-    """``"latent"`` where a model's ``cache_spec()`` names a latent entry
-    (``spec["latent"]``), else ``"kv"``."""
-    return "latent" if spec.get("latent") else "kv"
+def cache_entry_kind(spec: dict, index: Optional[int] = None) -> str:
+    """What entry ``index`` of the cache holds
+    (:func:`cache_entry_kinds`) or, for the whole spec, ``"kv"`` (keys
+    and values per head), ``"latent"`` (``spec["latent"]``) or, where
+    state entries sit beside those, ``"kv+state"``."""
+    kinds = cache_entry_kinds(spec)
+    if index is not None:
+        return kinds[index]
+    by_position = {k for k in kinds if k != "state"}
+    return "+".join(sorted(by_position) + ["state"] * ("state" in kinds))
 
 
 def cache_entry_widths(spec: dict):
-    """Last-axis widths of an entry's two leaves: ``head_dim`` for keys
-    and for values, or a latent entry's ``(rank, rope_dim)``."""
+    """Last-axis widths of a positional entry's two leaves: ``head_dim``
+    for keys and for values, or a latent entry's ``(rank, rope_dim)``."""
     if spec.get("latent"):
         rank, rope_dim = spec["latent"]
         return int(rank), int(rope_dim)
     return (int(spec["head_dim"]),) * 2
 
 
-def cache_sharding_spec(batch: int, n_kv_heads: int, mesh=None, stack=0):
-    """GSPMD sharding for one cache leaf [B, S, Hkv, D] (``stack``
-    replicated axes of stacked entries after the batch's): batch over
-    dp/sdp, kv heads over mp — matching the Column-parallel K/V
-    projections, so tp decode reads/writes only local heads (no gathers).
-    Axes that don't divide evenly stay replicated: a latent entry's one
-    head always is, as the compressed vector is what every head reads."""
+def refuse_state_entries(spec: dict, who: str, why: str) -> None:
+    """Raise for a spec with state entries: ``who`` cannot serve it, for
+    ``why`` (what it would have to learn first)."""
+    states = state_entries(spec)
+    if states:
+        raise ValueError(
+            f"{who} does not support a cache with recurrent-state entries "
+            f"({states} of this model's {len(cache_entry_kinds(spec))}): "
+            f"{why}")
+
+
+def _mesh_axes(batch: int, width: int, mesh):
+    """``(mesh, batch axes, "mp" or None)`` for a cache leaf of ``batch``
+    rows and ``width`` things to split over mp; None where no axis of
+    the mesh divides either (or there is no mesh)."""
     mesh = mesh if mesh is not None else get_mesh()
     if mesh is None:
         return None
@@ -106,11 +160,46 @@ def cache_sharding_spec(batch: int, n_kv_heads: int, mesh=None, stack=0):
     if bsz <= 1 or batch % bsz != 0:
         batch_axes = None
     mp = mesh.shape.get("mp", 1)
-    head_axis = "mp" if (mp > 1 and n_kv_heads % mp == 0) else None
-    if batch_axes is None and head_axis is None:
+    split = "mp" if (mp > 1 and width % mp == 0) else None
+    if batch_axes is None and split is None:
         return None
-    return sharding(batch_axes or None, *(None,) * stack, None, head_axis,
-                    None, mesh=mesh)
+    return mesh, batch_axes or None, split
+
+
+def cache_sharding_spec(batch: int, n_kv_heads: int, mesh=None, stack=0):
+    """GSPMD sharding for one cache leaf [B, S, Hkv, D] (``stack``
+    replicated axes of stacked entries after the batch's): batch over
+    dp/sdp, kv heads over mp — matching the Column-parallel K/V
+    projections, so tp decode reads/writes only local heads (no gathers).
+    Axes that don't divide evenly stay replicated: a latent entry's one
+    head always is, as the compressed vector is what every head reads."""
+    axes = _mesh_axes(batch, n_kv_heads, mesh)
+    if axes is None:
+        return None
+    mesh, batch_axes, head_axis = axes
+    return sharding(batch_axes, *(None,) * stack, None, head_axis, None,
+                    mesh=mesh)
+
+
+def state_sharding_spec(batch: int, width: int, mesh=None):
+    """GSPMD sharding for one state leaf ``[B, n, d_inner]``: batch over
+    dp/sdp, the inner width over mp where it divides (the recurrence
+    never mixes channels), else replicated."""
+    axes = _mesh_axes(batch, width, mesh)
+    if axes is None:
+        return None
+    mesh, batch_axes, channels = axes
+    return sharding(batch_axes, None, channels, mesh=mesh)
+
+
+def _leaf_sharding(leaf):
+    """A cache leaf's sharding from its shape: a state leaf has three
+    axes, every positional one (a quantized leaf's scales too) four and
+    its stack."""
+    if leaf.ndim == 3:
+        return state_sharding_spec(leaf.shape[0], leaf.shape[-1])
+    return cache_sharding_spec(leaf.shape[0], leaf.shape[-2],
+                               stack=leaf.ndim - 4)
 
 
 def normalize_kv_dtype(kv_dtype):
@@ -127,32 +216,45 @@ def normalize_kv_dtype(kv_dtype):
 
 
 def alloc_cache(spec: dict, rows: int, length: int, dtype=None,
-                kv_dtype=None, placement=None):
+                kv_dtype=None, placed: bool = False):
     """The allocator: zeros for ``rows`` rows of ``length`` positions of
-    a model's ``cache_spec()``, a tuple of ``(k, v)`` pairs with leaves
-    ``[rows, *stack, length, Hkv, D]`` of ``dtype`` (the spec's when
-    None). ``kv_dtype="int8"`` makes each of ``k`` and ``v`` a ``(int8
-    values, float32 scales [..., Hkv, 1])`` pair (see
+    a model's ``cache_spec()``, a tuple of pairs, one an entry
+    (:func:`cache_entry_kinds`). A positional entry is ``(k, v)`` with
+    leaves ``[rows, *stack, length, Hkv, D]`` of ``dtype`` (the spec's
+    when None); a state entry ``(h [rows, d_state, d_inner] float32,
+    window [rows, window, d_inner] dtype)``, whatever ``length``.
+    ``kv_dtype="int8"`` makes each of ``k`` and ``v`` a ``(int8 values,
+    float32 scales [..., Hkv, 1])`` pair (see
     :mod:`paddle_tpu.quantization`), roughly halving the footprint at
     head_dim 64+; the scale keeps the value leaf's rank, so every
     function here maps over both leaves alike. A latent entry
     (:func:`cache_entry_widths`) is refused with it: its one compressed
     vector stands for every head's keys and values, and a per-head scale
-    has no head to belong to. Each leaf is placed by ``placement`` (a
-    sharding) as it is made, where one is given."""
+    has no head to belong to. So is a spec with state entries: the state
+    is float32 by the recurrence's own need, and a cache quantized in
+    two of its twenty-eight entries is not what the knob promises.
+    ``placed``: each leaf is put in its GSPMD layout as it is made, where
+    a mesh is installed (:func:`cache_sharding_spec`,
+    :func:`state_sharding_spec`)."""
     dtype = convert_dtype(dtype or spec["dtype"])
+    kinds = cache_entry_kinds(spec)
     quantized = normalize_kv_dtype(kv_dtype) == "int8"
-    if quantized and cache_entry_kind(spec) == "latent":
-        raise ValueError(
-            "kv_dtype='int8' is not supported with a latent cache entry "
-            "(multi-head latent attention): the entry is already the "
-            "compressed form; use kv_dtype=None")
-    pairs, stack = cache_layout(spec)
+    if quantized:
+        if "latent" in kinds:
+            raise ValueError(
+                "kv_dtype='int8' is not supported with a latent cache entry "
+                "(multi-head latent attention): the entry is already the "
+                "compressed form; use kv_dtype=None")
+        refuse_state_entries(
+            spec, "kv_dtype='int8'",
+            "the state is float32 and is not quantized; use kv_dtype=None")
+    _, stack = cache_layout(spec)
     lead = (rows,) + stack + (length, spec["num_kv_heads"])
 
     def zeros(shape, dtype):
         z = jnp.zeros(shape, dtype)
-        return z if placement is None else jax.device_put(z, placement)
+        shd = _leaf_sharding(z) if placed else None
+        return z if shd is None else jax.device_put(z, shd)
 
     def entry(width):
         if quantized:
@@ -160,8 +262,14 @@ def alloc_cache(spec: dict, rows: int, length: int, dtype=None,
                     zeros(lead + (1,), jnp.float32))
         return zeros(lead + (width,), dtype)
 
+    def state():
+        d_state, window, d_inner = spec["state"]
+        return (zeros((rows, d_state, d_inner), jnp.float32),
+                zeros((rows, window, d_inner), dtype))
+
     first, second = cache_entry_widths(spec)
-    return tuple((entry(first), entry(second)) for _ in range(pairs))
+    return tuple(state() if kind == "state" else (entry(first), entry(second))
+                 for kind in kinds)
 
 
 def init_cache(model, batch: int, max_length: Optional[int] = None,
@@ -171,11 +279,8 @@ def init_cache(model, batch: int, max_length: Optional[int] = None,
     layout when a mesh is installed: a scale leaf shares its value
     leaf's sharding spec (batch over dp/sdp, kv heads over mp)."""
     spec = model.cache_spec()
-    _, stack = cache_layout(spec)
-    return alloc_cache(
-        spec, batch, int(max_length or spec["max_length"]), dtype, kv_dtype,
-        placement=cache_sharding_spec(batch, spec["num_kv_heads"],
-                                      stack=len(stack)))
+    return alloc_cache(spec, batch, int(max_length or spec["max_length"]),
+                       dtype, kv_dtype, placed=True)
 
 
 def cache_nbytes(cache) -> int:
@@ -185,22 +290,44 @@ def cache_nbytes(cache) -> int:
                    for x in jax.tree.leaves(cache)))
 
 
+def cache_split_nbytes(spec: dict, cache):
+    """``(positional, state)``: the bytes of ``cache``'s entries that are
+    indexed by position, and of those that hold a state."""
+    by_kind = [0, 0]
+    for kind, pair in zip(cache_entry_kinds(spec), cache):
+        by_kind[kind == "state"] += cache_nbytes(pair)
+    return tuple(by_kind)
+
+
+def _unit_nbytes(spec, dtype, kv_dtype):
+    return cache_split_nbytes(spec, jax.eval_shape(
+        lambda: alloc_cache(spec, 1, 1, dtype, kv_dtype)))
+
+
 def cache_token_nbytes(spec: dict, dtype=None, kv_dtype=None) -> int:
     """Bytes a position of a row holds, from :func:`alloc_cache`'s shapes."""
-    return cache_nbytes(jax.eval_shape(
-        lambda: alloc_cache(spec, 1, 1, dtype, kv_dtype)))
+    return _unit_nbytes(spec, dtype, kv_dtype)[0]
+
+
+def cache_state_nbytes(spec: dict, dtype=None) -> int:
+    """Bytes a row holds whatever its length: its state entries'. A row
+    of ``length`` positions holds ``length * cache_token_nbytes +
+    cache_state_nbytes``."""
+    return _unit_nbytes(spec, dtype, None)[1]
 
 
 def constrain_cache(cache):
     """with_sharding_constraint on every cache leaf (inside jit), so the
-    compiled steps keep the cache resident in its sharded layout."""
-    leaf = jax.tree.leaves(cache)[0]
-    shd = cache_sharding_spec(leaf.shape[0], leaf.shape[-2],
-                              stack=leaf.ndim - 4)
-    if shd is None:
+    compiled steps keep the cache resident in its sharded layout: each
+    leaf by its own shape (:func:`_leaf_sharding`)."""
+    if get_mesh() is None:
         return cache
-    return jax.tree.map(
-        lambda x: jax.lax.with_sharding_constraint(x, shd), cache)
+
+    def constrain(x):
+        shd = _leaf_sharding(x)
+        return x if shd is None else jax.lax.with_sharding_constraint(x, shd)
+
+    return jax.tree.map(constrain, cache)
 
 
 def cache_geometry(spec: dict, max_length, prefill_buckets: Sequence[int],
@@ -368,6 +495,38 @@ def update_kv_cache(cache, k_new, v_new, position_offset, entry=None):
                                       entry)
     return (_write_window(k_cache, k_new, pos, entry),
             _write_window(v_cache, v_new, pos, entry))
+
+
+def read_state(cache):
+    """``(h [B, d_state, d_inner] float32, window [B, window, d_inner])``
+    of a state entry's pair; of its one row where the pair's leaves
+    stand for a row (:class:`CacheRow`). A continuation reads it; a
+    sequence that starts at position 0 does not, whatever the row held."""
+    def whole(x):
+        if isinstance(x, CacheRow):
+            return jax.lax.dynamic_slice_in_dim(x.buf, x.row, 1, axis=0)
+        return x
+
+    return whole(cache[0]), whole(cache[1])
+
+
+def write_state(cache, h, window):
+    """The state entry's pair with ``h`` and ``window`` in it, WHOLE:
+    every row of plain leaves (a decode step advances every slot at
+    once, free ones too: nobody reads theirs before :func:`write_state`
+    through a row view overwrites it) or the one row that
+    :class:`CacheRow` leaves stand for (an admission: the row holds
+    exactly the prompt's state afterwards, nothing of its last
+    request's)."""
+    def put(buf, new):
+        if isinstance(buf, CacheRow):
+            zero = jnp.zeros((), jnp.int32)
+            return CacheRow(jax.lax.dynamic_update_slice(
+                buf.buf, new.astype(buf.dtype), (buf.row, zero, zero)),
+                buf.row)
+        return new.astype(buf.dtype)
+
+    return put(cache[0], h), put(cache[1], window)
 
 
 # ------------------------------------------------------------------ read

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+import contextlib
 from typing import Optional
 
 import jax
@@ -33,6 +34,7 @@ from ..distributed.parallel.mp_layers import (
     VocabParallelEmbedding,
     parallel_matmul,
 )
+from ..framework.dtype import get_default_dtype, set_default_dtype
 from ..nn import functional as F
 from ..nn.initializer import Normal
 from ..nn.layer import Layer
@@ -41,7 +43,7 @@ from .lm_utils import (attend_with_cache, causal_attention,
                        constrain_seq as _constrain_seq, repeat_kv)
 
 __all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM", "LlamaAttention",
-           "LlamaMLP", "rotary_embed", "llama_tiny",
+           "LlamaMLP", "rotary_embed", "born_as", "llama_tiny",
            "llama2_7b", "llama_loss_fn", "llama_flops_per_token"]
 
 
@@ -54,7 +56,7 @@ class LlamaConfig:
     num_kv_heads: Optional[int] = None  # None = MHA; < num_heads = GQA
     intermediate_size: Optional[int] = None  # default: llama 8/3 rule
     max_position_embeddings: int = 4096
-    rope_theta: float = 10000.0
+    rope_theta: Optional[float] = 10000.0  # None: attention without positions
     rms_norm_eps: float = 1e-5
     initializer_range: float = 0.02
     tie_word_embeddings: bool = False  # llama unties
@@ -122,6 +124,19 @@ def rotary_embed(q, k, theta: float, position_offset=0, inv_freq=None):
 _repeat_kv = repeat_kv
 
 
+@contextlib.contextmanager
+def born_as(dtype):
+    """Parameters made inside are drawn in ``dtype`` (``Layer.__init__``
+    reads the default type): a model of billions of parameters born in
+    float32 and cast afterwards would not fit the chip it is served from."""
+    before = get_default_dtype()
+    set_default_dtype(dtype)
+    try:
+        yield
+    finally:
+        set_default_dtype(before)
+
+
 # ------------------------------------------------------------------ layers
 class LlamaAttention(Layer):
     def __init__(self, cfg: LlamaConfig):
@@ -153,8 +168,10 @@ class LlamaAttention(Layer):
         k = self.k_proj(x).reshape(B, L, cfg.num_kv_heads, self.head_dim)
         v = self.v_proj(x).reshape(B, L, cfg.num_kv_heads, self.head_dim)
         # RoPE turns by position_offset (traced for cached decode steps),
-        # so the cache stores POST-rotation keys
-        q, k = rotary_embed(q, k, cfg.rope_theta, position_offset)
+        # so the cache stores POST-rotation keys; a family whose other
+        # layers carry the order (jamba.py) says rope_theta=None
+        if cfg.rope_theta is not None:
+            q, k = rotary_embed(q, k, cfg.rope_theta, position_offset)
         if cache is not None:
             out, cache = attend_with_cache(
                 q, k, v, cache, position_offset,

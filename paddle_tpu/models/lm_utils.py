@@ -9,7 +9,9 @@ fuses softmax+CE but still materializes full logits.
 """
 from __future__ import annotations
 
+import contextlib
 import math
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -19,11 +21,13 @@ from ..distributed.parallel.recompute import recompute_wrap
 from ..kernels import flash_attention as fa
 from ..nn import functional as F
 from ..nn.layer import Layer
-from .kv_cache import cached_attention, latent_attention, update_kv_cache
+from .kv_cache import (cached_attention, latent_attention, read_state,
+                       update_kv_cache, write_state)
 
 __all__ = ["chunked_lm_loss", "DecoderBlockList", "constrain_seq",
            "causal_attention", "repeat_kv", "attend_with_cache",
            "latent_block_attention", "attend_with_latent_cache",
+           "selective_scan", "scan_with_state", "block_length",
            "cached_lm_forward"]
 
 
@@ -160,14 +164,133 @@ def attend_with_latent_cache(q_nope, q_rope, c_new, k_rope_new, w_uk, w_uv,
         return jnp.einsum("blhc,chv->blhv", o_c, w_uv), cache
 
 
+# ----------------------------------------------------- recurrent state
+# Trace-time state, thread-local as kv_cache.cache_paths is: how many of
+# the block's positions are real, where the block is right-padded.
+_BLOCK = threading.local()
+
+
+@contextlib.contextmanager
+def block_length(n):
+    """The block traced under this context holds ``n`` real positions (a
+    traced scalar) and padding after them. Attention needs no telling (a
+    pad's key sits behind the position mask); a recurrence would run on
+    through the pads, so :func:`scan_with_state` reads it."""
+    outer = getattr(_BLOCK, "n", None)
+    _BLOCK.n = n
+    try:
+        yield
+    finally:
+        _BLOCK.n = outer
+
+
+# Positions a trip of the prefill scan's loop advances: the recurrence is
+# sequential in time, and a trip of one position is mostly the loop's own
+# cost on the chip.
+SCAN_UNROLL = 8
+
+
+def selective_scan(u, delta, A, Bm, Cm, D, h0):
+    """The selective state-space recurrence, float32 throughout, per
+    channel ``c`` and state ``s``:
+
+        h_t[s, c] = exp(delta_t[c] A[s, c]) h_{t-1}[s, c] + delta_t[c] u_t[c] B_t[s]
+        y_t[c]    = sum_s h_t[s, c] C_t[s] + D[c] u_t[c]
+
+    ``u``, ``delta`` [B, L, d]; ``A`` [n, d]; ``Bm``, ``Cm`` [B, L, n];
+    ``D`` [d]; ``h0`` [B, n, d]. Returns ``(y [B, L, d], h_L)``. The inner
+    width stays on the lanes. One position is one fused update (the
+    decode step); a block is a ``lax.scan`` over time with the state as
+    carry, so ``[L, n, d]`` never exists. ``delta_t = 0`` leaves the
+    state as it was, exactly (``exp(0) = 1``, ``0 * u * B = 0``)."""
+    def step(h, x):
+        u_t, d_t, b_t, c_t = x                       # [B, d] x2, [B, n] x2
+        h = (jnp.exp(d_t[:, None, :] * A) * h
+             + (d_t * u_t)[:, None, :] * b_t[:, :, None])
+        return h, jnp.sum(h * c_t[:, :, None], axis=1)
+
+    xs = (u, delta, Bm, Cm)
+    if u.shape[1] == 1:
+        h, y = step(h0, tuple(x[:, 0] for x in xs))
+        y = y[:, None]
+    else:
+        h, y = jax.lax.scan(step, h0,
+                            tuple(jnp.swapaxes(x, 0, 1) for x in xs),
+                            unroll=min(SCAN_UNROLL, u.shape[1]))
+        y = jnp.swapaxes(y, 0, 1)
+    return y + D * u, h
+
+
+def scan_with_state(u_pre, conv_weight, conv_bias, ssm_params, A, D,
+                    cache=None, position_offset=0):
+    """A recurrent mixer's one call into the cache, as
+    :func:`attend_with_cache` is attention's: the causal depthwise
+    convolution over time, the mixer's own ``ssm_params(u) -> (delta,
+    B, C)`` (float32), and :func:`selective_scan`, started from what the
+    cache's state entry holds and leaving there what the next token
+    needs. ``u_pre`` [B, L, d] is the convolution's input, ``conv_weight``
+    [K, d] (``conv_weight[k]`` multiplies the input ``K - 1 - k``
+    positions back), ``conv_bias`` [d], ``A`` [n, d] (negative), ``D``
+    [d]. Returns ``(y [B, L, d] float32, cache)``.
+
+    Three shapes of the one recurrence. A sequence that starts at
+    position 0 (static ``position_offset == 0``: prefill, or no cache at
+    all) starts from a zero state and a zero window WHATEVER the row
+    held: no mask hides a freed slot's state. Any other offset continues
+    from the cached pair, a block at a time (chunked continuation) or one
+    token a row, every row at once (the decode step: ``[B]`` positions or
+    a scalar; the recurrence does not read them). Under
+    :func:`block_length` only the first ``n`` positions are real: delta
+    is forced to 0 on the pads, so the state written is the state after
+    exactly ``n`` tokens, and the window written is the last ``K - 1``
+    REAL inputs (zeros, or the cached window's tail, to their left)."""
+    B, L, d = u_pre.shape
+    K = conv_weight.shape[0]
+    f32 = jnp.float32
+    fresh = cache is None or (isinstance(position_offset, int)
+                              and position_offset == 0)
+    if fresh:
+        h0 = jnp.zeros((B, A.shape[0], d), f32)
+        window = jnp.zeros((B, K - 1, d), u_pre.dtype)
+    else:
+        h0, window = read_state(cache)
+    n = getattr(_BLOCK, "n", None)
+    with jax.named_scope("conv"):
+        ext = jnp.concatenate([window.astype(u_pre.dtype), u_pre], axis=1)
+        w = conv_weight.astype(f32)
+        u = conv_bias.astype(f32) + sum(
+            w[k] * ext[:, k:k + L].astype(f32) for k in range(K))
+        u = jax.nn.silu(u)
+    with jax.named_scope("ssm_params"):
+        delta, Bm, Cm = ssm_params(u)
+        if n is not None:
+            real = jnp.arange(L, dtype=jnp.int32) < n
+            delta = jnp.where(real[None, :, None], delta, 0.0)
+    with jax.named_scope("scan" if L > 1 else "state_update"):
+        y, h = selective_scan(u, delta, A, Bm, Cm, D, h0)
+    if cache is not None:
+        # ext[j] is the input at block position j - (K - 1): the last
+        # K - 1 real ones end at position n - 1
+        tail = (ext[:, L:] if n is None else
+                jax.lax.dynamic_slice_in_dim(ext, n, K - 1, axis=1))
+        cache = write_state(cache, h, tail)
+    return y, cache
+
+
 def cached_lm_forward(backbone, logits_fn, input_ids, cache,
                       position_offset, gather_last):
     """The serving-side CausalLM forward shared by GPT and Llama: run the
     backbone (cache-threaded when given), optionally slice the hidden
     states to the single ``gather_last`` position BEFORE the head
     projection (so serving never materializes [B, L, vocab]), and return
-    ``logits`` or ``(logits, new_cache)``."""
-    h = backbone(input_ids, cache=cache, position_offset=position_offset)
+    ``logits`` or ``(logits, new_cache)``. ``gather_last`` is the index
+    of the block's last REAL token wherever a caller right-pads a prompt
+    to its bucket, so it also tells the backbone how long the block
+    really is (:func:`block_length`): a recurrent mixer must not run on
+    through the pads."""
+    with (contextlib.nullcontext() if gather_last is None
+          else block_length(gather_last + 1)):
+        h = backbone(input_ids, cache=cache, position_offset=position_offset)
     if cache is not None:
         h, cache = h
     if gather_last is not None:

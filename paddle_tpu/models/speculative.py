@@ -71,7 +71,7 @@ from ..io.batching import bucket_for
 from .generation import (filter_logits, per_row_keys, sample_logits,
                          sample_logits_rows, DEFAULT_PREFILL_BUCKETS)
 from .kv_cache import (cache_geometry, constrain_cache, init_cache,
-                       normalize_kv_dtype)
+                       normalize_kv_dtype, refuse_state_entries)
 
 __all__ = ["SpeculativeEngine", "build_draft_model"]
 
@@ -131,6 +131,11 @@ class SpeculativeEngine:
         self.k = int(k)
         self.spec = spec = model.cache_spec()
         self.dspec = dspec = draft_model.cache_spec()
+        for who, its in (("target", spec), ("draft", dspec)):
+            refuse_state_entries(
+                its, f"speculative decoding (the {who} model)",
+                "a rejected draft would need the state rolled back to the "
+                "last accepted token, which no one keeps yet")
         self.kv_dtype = normalize_kv_dtype(kv_dtype)
         self.draft_kv_dtype = normalize_kv_dtype(
             kv_dtype if draft_kv_dtype is None else draft_kv_dtype)

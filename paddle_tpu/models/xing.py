@@ -38,7 +38,6 @@ file's, listed in ``benchmarks/configs/xing4.0-29b-a4b.json`` under
 """
 from __future__ import annotations
 
-import contextlib
 import itertools
 import math
 from dataclasses import dataclass
@@ -49,13 +48,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..distributed.parallel.mp_layers import VocabParallelEmbedding
-from ..framework.dtype import get_default_dtype, set_default_dtype
 from ..nn.initializer import Constant, Initializer, Normal
 from ..nn.layer import Layer
 from ..nn.layers.common import Linear
 from ..nn.layers.expert_ffn import ExpertFFN
 from ..nn.layers.norm import RMSNorm
-from .llama import LlamaConfig, LlamaForCausalLM, LlamaMLP, rotary_embed
+from .llama import (LlamaConfig, LlamaForCausalLM, LlamaMLP, born_as,
+                    rotary_embed)
 from .lm_utils import (DecoderBlockList, attend_with_latent_cache,
                        latent_block_attention)
 
@@ -424,18 +423,6 @@ class XingModel(Layer):
         return h if cache is None else (h, cache)
 
 
-@contextlib.contextmanager
-def _born_as(dtype):
-    """Parameters made inside are drawn in ``dtype`` (``Layer.__init__``
-    reads the default type)."""
-    before = get_default_dtype()
-    set_default_dtype(dtype)
-    try:
-        yield
-    finally:
-        set_default_dtype(before)
-
-
 class XingForCausalLM(LlamaForCausalLM):
     """LM head model; :class:`LlamaForCausalLM`'s contract over the
     latent, sparse, multi-stream backbone."""
@@ -443,7 +430,7 @@ class XingForCausalLM(LlamaForCausalLM):
     backbone_cls = XingModel
 
     def __init__(self, cfg: XingConfig):
-        with _born_as(cfg.dtype):
+        with born_as(cfg.dtype):
             super().__init__(cfg)
 
     def _logits(self, h):

@@ -338,12 +338,16 @@ class Layer:
 
     load_dict = set_state_dict
 
-    def _set_by_path(self, path: str, value):
+    def _owner_of(self, path: str):
+        """``(layer, leaf name)`` of the parameter or buffer at ``path``."""
         parts = path.split(".")
         layer = self
         for p in parts[:-1]:
             layer = layer._sub_layers[p]
-        leaf = parts[-1]
+        return layer, parts[-1]
+
+    def _set_by_path(self, path: str, value):
+        layer, leaf = self._owner_of(path)
         if leaf in layer._parameters:
             layer._parameters[leaf] = value
         elif leaf in layer._buffers:
@@ -352,16 +356,16 @@ class Layer:
             raise KeyError(f"no parameter or buffer named {path}")
 
     def _get_by_path(self, path: str):
-        parts = path.split(".")
-        layer = self
-        for p in parts[:-1]:
-            layer = layer._sub_layers[p]
-        leaf = parts[-1]
+        layer, leaf = self._owner_of(path)
         if leaf in layer._parameters:
             return layer._parameters[leaf]
         return layer._buffers[leaf]
 
     # ------------------------------------------------------------- dtype
+    #: names of a layer's own parameters that a cast of the model leaves
+    #: in the type they were made in (a recurrence's float32 constants)
+    keeps_dtype = ()
+
     def to(self, dtype=None):
         if dtype is not None:
             d = convert_dtype(dtype)
@@ -369,6 +373,9 @@ class Layer:
             # every old array alive until the last is cast (both copies
             # of a 2.7B-parameter model: 16 GB on a 16 GB chip)
             for name in [n for n, _ in self.named_parameters()]:
+                owner, leaf = self._owner_of(name)
+                if leaf in owner.keeps_dtype:
+                    continue
                 p = self._get_by_path(name)
                 if jnp.issubdtype(p.dtype, np.floating):
                     self._set_by_path(name, p.astype(d))

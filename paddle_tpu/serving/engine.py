@@ -25,10 +25,19 @@ dimension as ``B`` independent *slots*:
 
 Steady state therefore holds at ``#prefill_buckets + 1`` compiled
 programs — the generation engine's compile discipline, now under
-multi-tenant traffic. Freed slots are reusable immediately: stale cache
-rows are harmless because the per-row position mask never lets a query
-see beyond its own request's frontier, and every position is rewritten
-before it first becomes visible.
+multi-tenant traffic. Freed slots are reusable immediately. Of an entry
+indexed by position (keys and values, a latent pair) a stale row is
+harmless: the per-row position mask never lets a query see beyond its own
+request's frontier, and every position is rewritten before it first
+becomes visible. Of a STATE entry (a recurrent mixer's scan state and
+convolution window, ``kv_cache.write_state``) nothing is masked, and a
+freed slot's state goes on being advanced by every filler step; so an
+admission starts from zeros whatever the row held and overwrites both
+leaves of the row whole, with the state after exactly the prompt's tokens:
+the prompt's true length reaches the mixers of every prefill program
+through ``gather_last`` (``lm_utils.cached_lm_forward`` opens
+``block_length``), so the bucket's pads do not move it. ``reset()`` builds
+the cache anew, zeros.
 
 Per-request sampled streams are *placement-invariant*: slot keys fold
 ``(position, row=0)`` exactly like a solo batch-1 ``generate()``, so a
@@ -52,10 +61,11 @@ from ..models.generation import (DEFAULT_PREFILL_BUCKETS, per_row_keys,
 from ..models.kv_cache import (cache_entries, cache_entry_kind,
                                cache_geometry, cache_nbytes,
                                cache_paths, cache_row_buffers,
-                               cache_row_view, constrain_cache,
-                               gather_cache_blocks, init_cache,
-                               normalize_kv_dtype, scatter_cache_blocks,
-                               scatter_cache_rows)
+                               cache_row_view, cache_split_nbytes,
+                               constrain_cache, gather_cache_blocks,
+                               init_cache, normalize_kv_dtype,
+                               scatter_cache_blocks, scatter_cache_rows,
+                               state_entries)
 from ..lora import adapter_rows as _adapter_rows_ctx
 from ..lora.store import AdapterStore, normalize_adapter_id
 from ..nn.layer import buffer_state, functional_call, param_state
@@ -247,6 +257,12 @@ class ContinuousBatchingEngine:
         self._buffers = buffer_state(self.model)
         self.live_cache = init_cache(self.model, self.slots, self.max_length,
                                      kv_dtype=self.kv_dtype)
+        positional, state = cache_split_nbytes(self.spec, self.live_cache)
+        self._bytes_per_token = positional // (self.slots * self.max_length)
+        #: bytes of recurrent state a slot holds whatever its length, from
+        #: the leaves as allocated; None for a model without state entries
+        self.state_bytes_per_slot: Optional[int] = (
+            state // self.slots if state_entries(self.spec) else None)
         if self.pool is not None:
             self.pool.reset()
         if self.store is not None:
@@ -315,7 +331,8 @@ class ContinuousBatchingEngine:
 
     def cache_bytes_per_slot(self) -> int:
         """HBM bytes one slot's KV occupies in the live batch — the
-        number the ``kv_dtype="int8"`` halving claim is asserted on."""
+        number the ``kv_dtype="int8"`` halving claim is asserted on. A
+        slot's recurrent state, where the model has any, is in it."""
         return cache_nbytes(self.live_cache) // self.slots
 
     def _prefill_fn(self, params, buffers, live_cache, ids, slot,
@@ -713,7 +730,9 @@ class ContinuousBatchingEngine:
     def release(self, slot: int) -> None:
         """Free ``slot`` immediately — no batch drain. The stale cache
         rows stay; the position mask keeps them invisible to whoever is
-        admitted next. The slot's adapter-page pin drops with it (the
+        admitted next, and a recurrent state (which the filler steps go
+        on advancing) is overwritten whole by the next admission. The
+        slot's adapter-page pin drops with it (the
         freed slot decodes as the zero adapter)."""
         self.requests[slot] = None
         self._done[slot] = True
@@ -754,13 +773,20 @@ class ContinuousBatchingEngine:
         for attention (``kv_cache.cached_attention``: ``"kernel"``, by
         position, or ``"xla"``, the whole leaf under a mask), both None
         until it has been traced. ``cache_entry`` is what an entry holds:
-        ``"kv"`` (keys and values per head) or ``"latent"`` (one
-        compressed vector and one shared rotated key a position)."""
+        ``"kv"`` (keys and values per head), ``"latent"`` (one
+        compressed vector and one shared rotated key a position) or
+        ``"kv+state"``: then ``cache_entries`` counts the entries
+        indexed by position alone, and ``state_entries`` and
+        ``state_bytes_per_slot`` (what a slot holds whatever its length,
+        as allocated) stand beside them."""
+        state = self.state_bytes_per_slot
         return {"prefill": compile_cache.cache_stats(self._cc_prefill),
                 "decode": compile_cache.cache_stats(self._cc_decode),
                 "cache_write": self._cache_write,
                 "cache_read": self._cache_read,
                 "cache_entries": cache_entries(self.spec),
                 "cache_entry": cache_entry_kind(self.spec),
-                "cache_bytes_per_token":
-                    self.cache_bytes_per_slot() // self.max_length}
+                "cache_bytes_per_token": self._bytes_per_token,
+                **({} if state is None else {
+                    "state_entries": state_entries(self.spec),
+                    "state_bytes_per_slot": state})}

@@ -247,6 +247,9 @@ class ServingMetrics:
             # decode steps by the sampler's branch (SAMPLE_BRANCHES);
             # the same writer again
             self._sample = [0, 0, 0]
+            # [admissions, prompt tokens, bucket tokens] of a model with
+            # recurrent-state entries; the same writer again
+            self._state = [0, 0, 0]
 
     # ------------------------------------------------------------ events
     def decode_step(self, live_slots: int, live_positions: int) -> None:
@@ -264,6 +267,16 @@ class ServingMetrics:
         (``engine.step_sample_branch``: the program's own test, made on
         the host vectors the step was given)."""
         self._sample[branch] += 1
+
+    def state_admission(self, prompt_tokens: int, bucket_tokens: int) -> None:
+        """Book one admission of a model whose cache holds a recurrent
+        state: the prompt's real tokens and the bucket it was padded to
+        (every position of which the prefill scan walks; the pads must
+        not move the state)."""
+        s = self._state
+        s[0] += 1
+        s[1] += prompt_tokens
+        s[2] += bucket_tokens
 
     def loop_phase(self, phase: str, wall_ns: int, cpu_ns: int) -> None:
         """Book one ended instance of a serve-loop phase (the loop
@@ -338,8 +351,12 @@ class ServingMetrics:
     def snapshot(self, compile_stats: Optional[dict] = None,
                  prefix_cache: Optional[dict] = None,
                  adapter_store: Optional[dict] = None,
-                 moe: Optional[dict] = None) -> dict:
+                 moe: Optional[dict] = None,
+                 state_bytes_per_slot: Optional[int] = None) -> dict:
         """One plain dict of everything — the serve_bench JSON shape.
+        ``state_bytes_per_slot`` (``ContinuousBatchingEngine``'s: what a
+        slot holds of recurrent state, None for a model with none) brings
+        the ``"state"`` block: admissions, their prompt and bucket tokens.
         ``moe`` (``ContinuousBatchingEngine.expert_load()``: the decode
         steps' expert load, fetched from the device for this snapshot)
         rides along for a model with an expert FFN.
@@ -398,6 +415,11 @@ class ServingMetrics:
                 **({"adapter_store": adapter_store}
                    if adapter_store is not None else {}),
                 **({"moe": moe} if moe is not None else {}),
+                **({"state": dict(
+                    zip(("admissions", "prompt_tokens", "bucket_tokens"),
+                        self._state),
+                    state_bytes_per_slot=state_bytes_per_slot)}
+                   if state_bytes_per_slot is not None else {}),
                 **({"per_adapter": {
                     name: {"requests": e["requests"],
                            "tokens": e["tokens"],

@@ -45,7 +45,7 @@ import numpy as np
 
 from ..models.kv_cache import (alloc_cache, cache_entries, cache_entry_widths,
                                cache_layout, cache_token_nbytes,
-                               normalize_kv_dtype)
+                               normalize_kv_dtype, refuse_state_entries)
 
 __all__ = ["BlockPool", "PrefixHit", "StorePlan", "chain_digests",
            "KV_WIRE_VERSION", "DEFAULT_MIGRATE_CHUNK_BYTES",
@@ -161,6 +161,11 @@ class BlockPool:
                  max_length: Optional[int] = None,
                  max_blocks: int = 4096, kv_dtype=None):
         spec = model.cache_spec()
+        refuse_state_entries(
+            spec, "a prefix cache (BlockPool)",
+            "a hit would need a snapshot of the state at a block boundary, "
+            "which no one takes yet; serve this model with "
+            "prefix_cache=None")
         self.spec = spec
         self.block_tokens = int(block_tokens)
         if self.block_tokens < 1:

@@ -358,14 +358,17 @@ class InferenceServer:
         eviction numbers when a prefix cache is attached, the adapter
         registry residency/eviction numbers when an adapter store is, and
         the decode steps' expert load (``"moe"``: read from the device
-        here, and only here) when the model has an expert FFN."""
+        here, and only here) when the model has an expert FFN, and the
+        admissions' prompt and bucket tokens (``"state"``) when its cache
+        holds a recurrent state."""
         pool = self.engine.pool
         store = self.engine.store
         return self.metrics.snapshot(
             self.engine.cache_stats(),
             prefix_cache=None if pool is None else pool.stats(),
             adapter_store=None if store is None else store.stats(),
-            moe=self.engine.expert_load())
+            moe=self.engine.expert_load(),
+            state_bytes_per_slot=self.engine.state_bytes_per_slot)
 
     def metrics_text(self) -> str:
         """Prometheus text exposition of the process metrics registry —
@@ -569,6 +572,8 @@ class InferenceServer:
             if req.adapter_id is not None:
                 tags["adapter"] = req.adapter_id
             self.metrics.inc("prefills")
+            if self.engine.state_bytes_per_slot is not None:
+                self.metrics.state_admission(len(req.prompt), tags["bucket"])
             if self.engine.pool is not None:
                 tags["prefix_hit_tokens"] = int(hit_tokens)
                 h.cache_hit_tokens = hit_tokens

@@ -143,6 +143,50 @@ def test_latent_logits_check_holds_a_near_tie_to_its_own_bound():
             prompt_lens=(20, 7), steps=4, tie_margin=2.0)
 
 
+def _hybrid_tiny():
+    config = chip_smoke._bench_config("ai21-jamba2-3b")
+    config["config"].update(
+        vocab_size=256, hidden_size=32, num_layers=4, num_heads=4,
+        intermediate_size=64, max_position_embeddings=256,
+        attn_layer_period=4, attn_layer_offset=1, mamba_d_state=8,
+        mamba_dt_rank=8, initializer_range=0.2)
+    config["run"]["dtype"] = "float32"
+    return config
+
+
+def test_state_logits_check_tiny():
+    """float32 on both sides: the rehearsal's error is rounding alone,
+    and the two planted faults each fail a bound (the check raises if
+    one passes); a prompt of 2 tokens leaves a window with a zero in it."""
+    out = chip_smoke.state_logits_check(
+        _hybrid_tiny(), seed=2 ** 31 + 11, slots=3, length=64, bucket=32,
+        prompt_lens=(30, 2), steps=4, logit_bound=1e-4, state_bound=1e-4,
+        window_bound=1e-4)
+    assert out["logit_worst"] < 1e-4 and out["state_worst"] < 1e-5
+    assert set(out["faults"]) == set(chip_smoke.STATE_FAULTS)
+    assert out["faults"]["pads that move the state"]["state_worst"] > 1e-2
+    assert out["faults"]["a window off by one"]["window_worst"] > 1e-2
+
+
+def test_state_scan_check_tiny():
+    """Equal inputs: float32 against float64 is rounding alone, the window
+    bit for bit, and a bfloat16 state is told apart by a factor of a
+    thousand."""
+    out = chip_smoke.state_scan_check(
+        _hybrid_tiny(), seed=3, slots=3, prompt=5, bucket=8, steps=12,
+        bound=1e-5)
+    assert out["float32"]["window_exact"] and out["bfloat16"]["window_exact"]
+    assert out["float32"]["h"] < 1e-6 and out["bfloat16"]["h"] > 1e-4
+
+
+def test_state_logits_check_fails_bounds_that_hold_nothing():
+    with pytest.raises(chip_smoke.CheckFailed, match="passes every bound"):
+        chip_smoke.state_logits_check(
+            _hybrid_tiny(), seed=5, slots=2, length=64, bucket=16,
+            prompt_lens=(9, 2), steps=2, logit_bound=10.0, state_bound=10.0,
+            window_bound=10.0)
+
+
 def test_expert_ffn_check_tiny():
     out = chip_smoke.expert_ffn_check(
         _latent_tiny(), seed=2 ** 31 + 3, row_counts=(6, 80),
