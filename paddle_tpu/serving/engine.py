@@ -14,14 +14,22 @@ dimension as ``B`` independent *slots*:
   samples the request's first token. One program per prefill bucket, for
   every slot. (With a prefix pool the slot's row is assembled from pool blocks
   and scattered in, ``kv_cache.scatter_cache_rows``.)
-- **step** advances ALL slots one token with a *vector* of per-slot
+- **launch** / **collect** (``step`` is one after the other) advance ALL
+  slots one token with a *vector* of per-slot
   positions (the ``[B]`` ``position_offset`` path through
   ``kv_cache.cached_attention`` / ``update_kv_cache``, on a TPU both
   through a kernel: a slot's new row lands by direct copy and only the
   blocks its positions fill are read; and the models' position
   tables), per-slot PRNG keys / eos ids / sampling params, and a
   traced greedy mask. Exactly ONE compiled program, regardless of which
-  requests currently share the batch.
+  requests currently share the batch. What a step hands the next (each
+  slot's new token and done flag) stays on the device: ``launch()``
+  feeds the last launch's two output vectors straight into the program,
+  and what the host alone knows (an admission's first token, a freed
+  slot, a request that its length ends) goes in as an override the
+  program merges first thing. So a second launch can be made while the
+  first still runs, and ``collect()`` reads a launch back one behind
+  (``InferenceServer``'s loop does exactly that).
 
 Steady state therefore holds at ``#prefill_buckets + 1`` compiled
 programs — the generation engine's compile discipline, now under
@@ -29,8 +37,13 @@ multi-tenant traffic. Freed slots are reusable immediately. Of an entry
 indexed by position (keys and values, a latent pair) a stale row is
 harmless: the per-row position mask never lets a query see beyond its own
 request's frontier, and every position is rewritten before it first
-becomes visible. Of a STATE entry (a recurrent mixer's scan state and
-convolution window, ``kv_cache.write_state``) nothing is masked, and a
+becomes visible. A launch still in flight when a slot is admitted into
+decodes filler there; the prefill program is enqueued behind it and
+takes ``live_cache`` from it by donation, so the order of the writes
+into the row (the filler step's, then the admission's whole row, state
+entries included) is the device's queue order. Of a STATE entry (a
+recurrent mixer's scan state and convolution window,
+``kv_cache.write_state``) nothing is masked, and a
 freed slot's state goes on being advanced by every filler step; so an
 admission starts from zeros whatever the row held and overwrites both
 leaves of the row whole, with the state after exactly the prompt's tokens:
@@ -46,9 +59,10 @@ the batch.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 from dataclasses import dataclass
-from typing import List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -83,6 +97,22 @@ class SlotEvent:
     slot: int
     token: int
     done: bool
+
+
+@dataclass
+class _Launch:
+    """A decode step the device was handed and the host has not read
+    back: its two output vectors, the request that was live in each slot
+    when it was launched, and the tags of the launch's span."""
+
+    tok: jax.Array
+    done: jax.Array
+    live: Dict[int, object]
+    tags: Optional[dict]
+    #: False once the host knows it has ended on the device (an
+    #: admission's read-back waited it out): a launch behind it overlaps
+    #: nothing
+    running: bool = True
 
 
 class ContinuousBatchingEngine:
@@ -251,8 +281,9 @@ class ContinuousBatchingEngine:
     # ------------------------------------------------------------- state
     def reset(self) -> None:
         """(Re)build the live batch: fresh cache, all slots free, weights
-        re-snapshotted. Also the crash-recovery path — a fault mid-step
-        may leave donated buffers half-written, so recovery starts clean."""
+        re-snapshotted, launches not read back forgotten. Also the
+        crash-recovery path — a fault mid-step may leave donated buffers
+        half-written, so recovery starts clean."""
         self._params = param_state(self.model)
         self._buffers = buffer_state(self.model)
         self.live_cache = init_cache(self.model, self.slots, self.max_length,
@@ -282,18 +313,28 @@ class ContinuousBatchingEngine:
                                                jnp.int32)}
         self._adapter_slots = np.zeros(B, np.int32)
         self._positions = np.zeros(B, np.int32)
+        # what the last launch handed the next, on the device, and what
+        # the host puts in its place where it knows better (`_override`:
+        # a slot admitted, freed or ended by its length since)
+        self._carry = (jnp.zeros(B, jnp.int32), jnp.ones(B, bool))
+        self._override = np.ones(B, bool)
         self._tokens = np.zeros(B, np.int32)
-        self._done = np.ones(B, bool)          # free slots sit "done"
+        #: slots the next launch does not count live: free, ended on eos
+        #: (read back) or by length (known ahead: `_owed` tokens to go)
+        self._done = np.ones(B, bool)
+        self._owed = np.zeros(B, np.int32)
+        #: launches not read back yet, oldest first; two at most
+        self._flights: collections.deque = collections.deque()
         self._keys = np.zeros((B, 2), np.uint32)
         self._eos = np.full(B, -1, np.int32)
         self._temp = np.ones(B, np.float32)
         self._top_p = np.ones(B, np.float32)
         self._greedy = np.ones(B, bool)
-        #: what the last decode step had to serve: the slots live in it
-        #: and the cached positions their queries read (each slot's
-        #: position, the new token's own key included)
+        #: what the last launch had to serve: the slots live in it and
+        #: the cached positions their queries read (each slot's position,
+        #: the new token's own key included)
         self.step_load = (0, 0)
-        #: which branch of the sampler the last decode step took
+        #: which branch of the sampler the last launch took
         #: (``generation.sample_branch`` on the vectors it was given)
         self.step_sample_branch = 0
         self.requests: List[Optional[object]] = [None] * B
@@ -310,6 +351,22 @@ class ContinuousBatchingEngine:
     @property
     def active_count(self) -> int:
         return sum(r is not None for r in self.requests)
+
+    @property
+    def live_count(self) -> int:
+        """Slots the next launch would decode for: occupied, not ended
+        on eos, and owed a token beyond those of the launches in flight."""
+        return int(self.slots - self._done.sum())
+
+    @property
+    def in_flight(self) -> int:
+        """Launches not collected yet (0, 1 or 2)."""
+        return len(self._flights)
+
+    def drop_flights(self) -> None:
+        """Forget the launches not read back (their slots' requests have
+        been released or are about to be)."""
+        self._flights.clear()
 
     def occupancy(self) -> float:
         return self.active_count / self.slots
@@ -412,13 +469,18 @@ class ContinuousBatchingEngine:
         with _adapter_rows_ctx(pages, rows):
             return self._decode_fn(params, buffers, live_cache, *rest)
 
-    def _decode_fn(self, params, buffers, live_cache, tokens, positions,
-                   keys, done, eos, temperature, top_p, greedy_mask,
-                   load=None):
-        """``load`` (None for a model without experts: no leaf, the same
+    def _decode_fn(self, params, buffers, live_cache, carry, override,
+                   tokens, positions, keys, done, eos, temperature, top_p,
+                   greedy_mask, load=None):
+        """``carry`` is the launch before's ``(next_tok, done)``, still on
+        the device; where ``override`` is set the host's ``tokens`` and
+        ``done`` take their place (:meth:`launch`). ``load`` (None for a
+        model without experts: no leaf, the same
         program as before it existed) is the running expert load, summed
         here over the slots that are live in this step (``~done``: a free
         slot decodes filler and is not counted) and handed back."""
+        tokens = jnp.where(override, tokens, carry[0])[:, None]
+        done = jnp.where(override, done, carry[1])
         with contextlib.ExitStack() as stack:
             stack.enter_context(jax.named_scope("decode"))
             paths = stack.enter_context(cache_paths())
@@ -563,7 +625,9 @@ class ContinuousBatchingEngine:
         the first token), and how many prompt tokens were served from
         the prefix cache (0 without a pool). The live batch keeps
         decoding other slots' requests before/after this call — only
-        this call itself runs the prefill program.
+        this call itself runs the prefill program, which the device takes
+        up behind the launch in flight, if there is one: the read-back
+        of the first token waits that launch out too.
 
         Two boundaries of ``self.clock`` lie inside: the prefill's
         dispatch has returned (``admit_wait`` begins: the first token's
@@ -662,13 +726,17 @@ class ContinuousBatchingEngine:
         # tpu-lint: disable=R1(admission's single batched sync point — the first token must reach the client now)
         first_h, fin_h = jax.device_get((tok, done0))
         clock.enter("admit_host")
+        for flight in self._flights:    # the prefill ran behind them
+            flight.running = False
         first = int(first_h)
         fin = bool(fin_h)
         self.requests[slot] = request
         self._adapter_slots[slot] = a_row
         self._positions[slot] = L
+        self._override[slot] = True
         self._tokens[slot] = first
-        self._done[slot] = fin
+        self._owed[slot] = int(request.max_new_tokens) - 1
+        self._done[slot] = fin or self._owed[slot] == 0
         self._keys[slot] = key
         self._eos[slot] = eos
         self._temp[slot] = request.temperature
@@ -676,56 +744,111 @@ class ContinuousBatchingEngine:
         self._greedy[slot] = request.greedy
         return first, fin, hit_tokens
 
-    def step(self) -> List[SlotEvent]:
-        """One decode iteration over the WHOLE live batch. Returns one
-        event per occupied, not-yet-done slot (its new token and done
-        flag); free slots decode as masked filler. The per-step host read
-        of ``[B]`` tokens is what streams results out — continuous
-        batching's equivalent of the generate() loop's done-check.
-
-        Two boundaries of ``self.clock`` lie inside: the decode
-        program's dispatch has returned (``decode_wait`` begins) and the
-        tokens are on the host (``emit`` begins); their spans carry the
-        tags of the ``decode_dispatch`` span the caller opened."""
-        clock = self.clock
+    def _decode_inputs(self) -> tuple:
+        """What a launch hands the decode program behind the weights and
+        the cache, in its order. The host's vectors go as copies: the
+        runtime may read an argument after the call has returned, and
+        admit(), release() and the next launch write into them while the
+        program is still queued."""
         lora_args = () if self.store is None else (
-            self.store.tensors, self._adapter_slots)
+            self.store.tensors, self._adapter_slots.copy())
+        host = (self._override, self._tokens, self._positions, self._keys,
+                self._done, self._eos, self._temp, self._top_p, self._greedy)
+        return (*lora_args, self._carry, *(v.copy() for v in host),
+                self._expert_load)
+
+    def launch(self) -> bool:
+        """Hand the device one decode iteration over the WHOLE live
+        batch and return without waiting for it: the first half of a
+        step. Its tokens and done flags stay on the device for the next
+        launch and are on their way to the host for :meth:`collect`.
+        Live in it are the occupied slots that have not ended: on eos,
+        as far as a ``collect()`` has shown, or by length, which the
+        host knows ahead (a request complete with the launches in flight
+        is not decoded for again, and its slot decodes masked filler
+        like a free one). A live slot's position rises by one; what the
+        launch had to serve is in ``step_load`` and
+        ``step_sample_branch``. One launch may be made ahead of the one
+        not collected yet, and no more. Returns whether it was made
+        ahead: behind a launch the host does not know to have ended.
+
+        The caller has opened the ``decode_dispatch`` span of
+        ``self.clock``; its tags are kept for the spans of the launch's
+        ``collect()``."""
+        if len(self._flights) > 1:
+            raise RuntimeError("two launches are in flight: collect() the "
+                               "older before launching again")
+        ahead = bool(self._flights) and self._flights[-1].running
+        live = ~self._done
         self.step_sample_branch = int(sample_branch(
-            ~self._done, self._greedy, self._top_p))
+            live, self._greedy, self._top_p))
         with self._eval_mode():
             compile_cache.record_call(self._cc_decode)
             tok, done, self.live_cache, self._expert_load = (
                 self._decode_compiled(
-                    self._params, self._buffers, self.live_cache, *lora_args,
-                    self._tokens[:, None], self._positions, self._keys,
-                    self._done, self._eos, self._temp, self._top_p,
-                    self._greedy, self._expert_load))
+                    self._params, self._buffers, self.live_cache,
+                    *self._decode_inputs()))
+        # the copy to the host starts when the program ends, not when
+        # collect() asks
+        tok.copy_to_host_async()
+        done.copy_to_host_async()
+        self._carry = (tok, done)
+        slots = np.flatnonzero(live).tolist()
+        self._flights.append(_Launch(
+            tok, done, {i: self.requests[i] for i in slots},
+            self.clock.tags))
+        self._positions[live] += 1
+        self._owed[live] -= 1
+        self.step_load = (len(slots), int(self._positions[live].sum()))
+        # what this launch completes by length is done for the next one,
+        # and only the host knows: the one override it leaves behind
+        self._override = live & (self._owed == 0)
+        self._done |= self._override
+        return ahead
+
+    def collect(self) -> List[SlotEvent]:
+        """Read the oldest launch back: the second half of a step.
+        Returns one event per slot that was live in it (its new token
+        and whether that was its eos), less the slots whose request has
+        been released or replaced since. The per-launch host read of
+        ``[B]`` tokens is what streams results out — continuous
+        batching's equivalent of the generate() loop's done-check. A
+        slot that ends on eos here was still counted live in a launch
+        made ahead of this read: the device's own done carry made it
+        decode filler there, and the event is dropped.
+
+        Two boundaries of ``self.clock`` lie inside: the wait for the
+        launch begins (``decode_wait``) and the tokens are on the host
+        (``emit`` begins); their spans carry the tags of the launch's
+        ``decode_dispatch`` span."""
+        clock = self.clock
+        flight = self._flights.popleft()
+        clock.enter("decode_wait", "serve.decode.wait", tags=flight.tags)
         # one batched transfer for the whole [B] step readback (token +
-        # done vectors) instead of two serialized np.array round-trips;
-        # np.array then makes writable copies: admit() scribbles slots
-        clock.enter("decode_wait", "serve.decode.wait", tags=clock.tags)
-        # tpu-lint: disable=R1(the per-step [B]-token readback IS the streaming output; one batched transfer per decode step)
-        tok_h, done_h = jax.device_get((tok, done))
-        clock.enter("emit", "serve.emit", tags=clock.tags)
-        toks = np.array(tok_h)
-        dns = np.array(done_h)
+        # done vectors) instead of two serialized np.array round-trips
+        # tpu-lint: disable=R1(the per-step [B]-token readback IS the streaming output; one batched transfer per decode step, asked for at the launch)
+        toks, dns = jax.device_get((flight.tok, flight.done))
+        clock.enter("emit", "serve.emit", tags=flight.tags)
         events: List[SlotEvent] = []
-        keys_read = 0
-        for i, req in enumerate(self.requests):
-            if req is None:
+        for i, req in flight.live.items():
+            if self.requests[i] is not req:
                 continue
-            if self._done[i]:
-                # finished but not yet released (server frees it right
-                # after dispatching events) — nothing new to report
-                continue
-            events.append(SlotEvent(i, int(toks[i]), bool(dns[i])))
-            self._positions[i] += 1
-            keys_read += int(self._positions[i])
-        self.step_load = (len(events), keys_read)
-        self._tokens = toks
-        self._done = dns | ~np.asarray(
-            [r is not None for r in self.requests])
+            ev = SlotEvent(i, int(toks[i]), bool(dns[i]))
+            events.append(ev)
+            if ev.done:
+                self._done[i] = True
+                for ahead in self._flights:
+                    ahead.live.pop(i, None)
         return events
+
+    def step(self) -> List[SlotEvent]:
+        """One decode iteration, launched and read back at once: the
+        synchronous use of the two halves, for an engine driven by
+        hand."""
+        if self._flights:
+            raise RuntimeError("a launch is in flight: collect() it first")
+        self.launch()
+        return self.collect()
 
     def release(self, slot: int) -> None:
         """Free ``slot`` immediately — no batch drain. The stale cache
@@ -735,6 +858,7 @@ class ContinuousBatchingEngine:
         slot's adapter-page pin drops with it (the
         freed slot decodes as the zero adapter)."""
         self.requests[slot] = None
+        self._override[slot] = True
         self._done[slot] = True
         self._positions[slot] = 0
         self._tokens[slot] = 0

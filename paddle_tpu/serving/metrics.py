@@ -39,6 +39,10 @@ __all__ = ["LOOP_PHASES", "SAMPLE_BRANCHES", "LatencyHistogram", "LoopClock",
 #: tokens); ``idle`` waits for work; the other four are host work: wall
 #: less CPU in one of those is time the thread was runnable or blocked
 #: but not running (the interpreter lock, another lock, the machine).
+#: The loop runs one step ahead: a pass's ``decode_dispatch`` launches
+#: step N+1 (``engine.launch``), its ``decode_wait`` and ``emit`` belong
+#: to step N (``engine.collect``), so each is still booked once a step
+#: and the three counts agree once the loop has drained.
 LOOP_PHASES = ("idle", "schedule", "admit_host", "admit_wait",
                "decode_dispatch", "decode_wait", "emit")
 
@@ -240,10 +244,10 @@ class ServingMetrics:
             # loop, and no lock on its side: a reset swaps the table, so
             # a booking that races it lands in the old one
             self._loop = {p: [0, 0, 0] for p in LOOP_PHASES}
-            # [steps, live slots, cached positions read] summed over
-            # decode steps; the same writer, and no lock for the same
-            # reason
-            self._decode = [0, 0, 0]
+            # [steps, live slots, cached positions read, steps launched
+            # ahead] summed over decode steps; the same writer, and no
+            # lock for the same reason
+            self._decode = [0, 0, 0, 0]
             # decode steps by the sampler's branch (SAMPLE_BRANCHES);
             # the same writer again
             self._sample = [0, 0, 0]
@@ -252,15 +256,20 @@ class ServingMetrics:
             self._state = [0, 0, 0]
 
     # ------------------------------------------------------------ events
-    def decode_step(self, live_slots: int, live_positions: int) -> None:
+    def decode_step(self, live_slots: int, live_positions: int,
+                    ahead: bool = False) -> None:
         """Book one decode step's load (``engine.step_load``): the slots
         live in it and the cached positions their queries read. What a
         step must move from memory follows from these and the model's
-        shapes, whatever program ran it."""
+        shapes, whatever program ran it. ``ahead`` (what
+        ``engine.launch`` returned): the step was handed to the device
+        while the one before it still ran, so the host's turn between
+        the two cost no device time."""
         d = self._decode
         d[0] += 1
         d[1] += live_slots
         d[2] += live_positions
+        d[3] += ahead
 
     def sample_step(self, branch: int) -> None:
         """Book which branch of the sampler one decode step took
@@ -406,7 +415,8 @@ class ServingMetrics:
                              "cpu_s": c[2] * 1e-9}
                          for p, c in self._loop.items()},
                 "decode": dict(zip(("steps", "live_slot_steps",
-                                    "live_position_steps"), self._decode)),
+                                    "live_position_steps",
+                                    "launched_ahead_steps"), self._decode)),
                 "sample": dict(zip(SAMPLE_BRANCHES, self._sample)),
                 **({"compile_stats": compile_stats}
                    if compile_stats is not None else {}),

@@ -5,10 +5,23 @@ single-threaded by construction — no lock around jax); any number of
 client threads ``submit()`` and consume per-request streams. The loop per
 iteration: sweep deadline-expired queue entries, admit up to
 ``max_prefills_per_step`` requests into free slots (each one bucketed
-prefill dispatch), then run ONE decode step for the whole live batch and
+prefill dispatch), then LAUNCH one decode step for the whole live batch,
+and only then read back the step launched in the iteration before and
 fan its tokens out to the request handles. Finished slots free
 immediately — a new request admits into the hole while everyone else
 keeps decoding.
+
+The loop is always one step ahead of what it has read (``engine.launch``
+/ ``engine.collect``): the device runs step N while the host hands it
+step N+1, and runs N+1 while the host emits N's tokens and schedules, so
+the host's turn costs device time only where it outlasts the program. A
+step's input tokens never visit the host. What the host learns one
+launch late is an eos (the slot decodes one masked filler step and frees
+a step later; that step's token reaches nobody); a request's length it
+knows ahead. An admission waits out the step in flight, then the
+pipeline refills with the next launch. The loop reads the last launch
+back before it sleeps or ends. A client can observe nothing of this but
+timing: every stream is token for token what a synchronous loop serves.
 
 Where the loop thread's time goes is measured where it happens: its
 ``LoopClock`` divides every pass into the phases of
@@ -445,15 +458,19 @@ class InferenceServer:
             self.metrics.loop_phase)
         while True:
             with self._cv:
+                # quiet: no slot occupied, nothing queued, and the last
+                # launch read back
                 while (not self._stop and self.engine.active_count == 0
-                       and self.scheduler.depth == 0):
+                       and self.scheduler.depth == 0
+                       and not self.engine.in_flight):
                     # entered anew at every wake-up, so that a snapshot
                     # lacks at most one wait of an idle stretch
                     clock.enter("idle")
                     self._cv.wait(0.1)
                 if self._stop:
                     if not self._drain or (self.engine.active_count == 0
-                                           and self.scheduler.depth == 0):
+                                           and self.scheduler.depth == 0
+                                           and not self.engine.in_flight):
                         break
             try:
                 self._tick()
@@ -484,6 +501,7 @@ class InferenceServer:
                 self.metrics.inc("requests_failed")
                 self._adapter_fail(req)
                 req.handle._fail(err)
+        self.engine.drop_flights()      # an undrained end: nobody's tokens
         self.metrics.set_active_slots(0)
         self.metrics.set_queue_depth(0)
 
@@ -516,25 +534,33 @@ class InferenceServer:
                     # recovery — dropping them would hang their clients
                     self._recover(e, extra=admits[i:])
                     return
-        live = self.engine.active_count
+        engine = self.engine
         self.metrics.set_queue_depth(self.scheduler.depth)
-        self.metrics.set_active_slots(live)
-        if live == 0:
+        self.metrics.set_active_slots(engine.active_count)
+        # the step launched in the tick before is read back behind this
+        # tick's launch; with nobody owed a further token there is no
+        # launch, and the read alone drains the pipeline
+        behind, live = engine.in_flight, engine.live_count
+        if not (behind or live):
             return
         fault_point("serve.step")
-        # the step's three spans share one tag dict: its number, as
-        # snapshot()["decode_steps"] will count it, and the live slots
-        clock.enter("decode_dispatch", "serve.decode.dispatch",
-                    tags={"step": self.metrics.decode_steps + 1,
-                          "live": live})
-        events = self.engine.step()     # leaves the clock in "emit"
-        self.metrics.inc("decode_steps")
-        self.metrics.decode_step(*self.engine.step_load)
-        self.metrics.sample_step(self.engine.step_sample_branch)
-        per_adapter = self.engine.store is not None
+        if live:
+            # the step's three spans share one tag dict: its number, as
+            # snapshot()["decode_steps"] will count it, and the live slots
+            clock.enter("decode_dispatch", "serve.decode.dispatch",
+                        tags={"step": self.metrics.decode_steps + 1,
+                              "live": live})
+            ahead = engine.launch()
+            self.metrics.inc("decode_steps")
+            self.metrics.decode_step(*engine.step_load, ahead)
+            self.metrics.sample_step(engine.step_sample_branch)
+        if not behind:
+            return
+        events = engine.collect()       # leaves the clock in "emit"
+        per_adapter = engine.store is not None
         now = time.monotonic()
         for ev in events:
-            req = self.engine.requests[ev.slot]
+            req = engine.requests[ev.slot]
             h = req.handle
             h._push(ev.token)
             self.metrics.inc("tokens_emitted")

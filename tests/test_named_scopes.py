@@ -51,9 +51,7 @@ def test_decode_program_names_decode_sample_and_the_model_parts(cfg):
     eng = ContinuousBatchingEngine(m, slots=2, max_length=32,
                                    prefill_buckets=(16,))
     lowered = eng._decode_compiled.lower(
-        eng._params, eng._buffers, eng.live_cache, eng._tokens[:, None],
-        eng._positions, eng._keys, eng._done, eng._eos, eng._temp,
-        eng._top_p, eng._greedy)
+        eng._params, eng._buffers, eng.live_cache, *eng._decode_inputs())
     found, _ = _scopes(lowered)
     assert {"decode", "sample", "attention", "mlp", "lm_head"} <= found
     assert "prefill" not in found

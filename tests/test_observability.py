@@ -425,7 +425,9 @@ def test_served_request_yields_one_trace_lane(lm, fleet):
     lanes = {e["tid"] for e in ct["traceEvents"] if e["ph"] in ("X", "i")}
     assert len(lanes) == 1          # ONE merged lane for the request
     # the 4 decode steps: untraced lane, consecutive step numbers, each
-    # a dispatch, a wait and an emit span that follow one another
+    # a dispatch, a wait and an emit span; the loop is one step ahead, so
+    # a step's wait begins where the NEXT step's dispatch ends, and the
+    # last step's behind a pass that had nothing left to launch
     steps = {}
     for s in tracing.spans():
         if s["name"] in ("serve.decode.dispatch", "serve.decode.wait",
@@ -437,8 +439,15 @@ def test_served_request_yields_one_trace_lane(lm, fleet):
     for n in mine:
         d, w, e = (steps[n][k] for k in ("serve.decode.dispatch",
                                          "serve.decode.wait", "serve.emit"))
-        assert d["t1"] == w["t0"] and w["t1"] == e["t0"]
+        assert d["t1"] <= w["t0"] and w["t1"] == e["t0"]
         assert d["tags"] == w["tags"] == e["tags"] == {"step": n, "live": 1}
+        if n + 1 in steps:
+            ahead = steps[n + 1]["serve.decode.dispatch"]
+            assert d["t1"] <= ahead["t0"] and ahead["t1"] == w["t0"]
+        else:
+            before = max(s["t1"] for s in tracing.spans(name="serve.schedule")
+                         if s["t1"] <= w["t0"])
+            assert before == w["t0"] > steps[n - 1]["serve.emit"]["t1"]
     # a second request gets its own id and its own lane
     h2 = router.submit(_prompt(cfg, 6, seed=2), max_new_tokens=3)
     h2.result(timeout=300)

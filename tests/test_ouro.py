@@ -387,17 +387,17 @@ def test_decode_counters_and_scopes_through_the_server(lm):
         d = srv.snapshot()["decode"]
         # prompt 10: the first token comes from the prefill, then four
         # steps whose queries sit at positions 10..13 and read 11..14 keys
+        # (every step but the first handed over while the one before ran)
         assert d == {"steps": 4, "live_slot_steps": 4,
-                     "live_position_steps": 11 + 12 + 13 + 14}
+                     "live_position_steps": 11 + 12 + 13 + 14,
+                     "launched_ahead_steps": 3}
         assert srv.statusz()["snapshot"]["compile_stats"][
             "cache_entries"] == cfg.total_ut_steps * cfg.num_layers
         srv.metrics.reset()
         assert srv.snapshot()["decode"]["steps"] == 0
         lowered = srv.engine._decode_compiled.lower(
             srv.engine._params, srv.engine._buffers, srv.engine.live_cache,
-            srv.engine._tokens[:, None], srv.engine._positions,
-            srv.engine._keys, srv.engine._done, srv.engine._eos,
-            srv.engine._temp, srv.engine._top_p, srv.engine._greedy)
+            *srv.engine._decode_inputs())
     found = _scopes(lowered)
     assert {"decode", "ut_step", "attention", "mlp", "lm_head"} <= found
     # served logits are the last step's: the gate is not in the program

@@ -199,8 +199,7 @@ def test_the_sort_is_inside_one_branch_of_one_switch(lm, program):
         else None, **GEO)
     if program == "decode":
         args = (eng._params, eng._buffers, eng.live_cache,
-                eng._tokens[:, None], eng._positions, eng._keys, eng._done,
-                eng._eos, eng._temp, eng._top_p, eng._greedy)
+                *eng._decode_inputs())
         fn = eng._decode_fn
     else:
         scalars = (np.asarray([0, 0], np.uint32), np.int32(-1),

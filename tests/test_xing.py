@@ -573,10 +573,7 @@ def test_snapshot_and_statusz_carry_the_expert_load(lm):
         assert status["compile_stats"]["cache_entry"] == "latent"
         lowered = srv.engine._decode_compiled.lower(
             srv.engine._params, srv.engine._buffers, srv.engine.live_cache,
-            srv.engine._tokens[:, None], srv.engine._positions,
-            srv.engine._keys, srv.engine._done, srv.engine._eos,
-            srv.engine._temp, srv.engine._top_p, srv.engine._greedy,
-            srv.engine._expert_load)
+            *srv.engine._decode_inputs())
     assert {"decode", "attention", "mla", "absorb", "latent_read", "moe",
             "router", "dispatch", "experts", "shared_expert", "combine",
             "hc_pre", "sinkhorn", "hc_post", "mlp", "lm_head"} \

@@ -164,8 +164,10 @@ def _print_step_latencies(spans: list, xp: dict, program: str) -> None:
         if s["name"] in ("serve.decode.dispatch", "serve.decode.wait"):
             steps.setdefault(s["tags"].get("step"), {})[s["name"]] = s
     rel = lambda t: (t - xp["start_s"]) * 1e9          # noqa: E731
+    # the loop is one step ahead: a step's wait begins a launch later
+    # than its own dispatch ends
     win = sorted((rel(v["serve.decode.dispatch"]["t0"]),
-                  rel(v["serve.decode.wait"]["t0"]),
+                  rel(v["serve.decode.dispatch"]["t1"]),
                   rel(v["serve.decode.wait"]["t1"]))
                  for v in steps.values() if len(v) == 2)
     runs = sorted((s, s + d) for dev in xp["devices"]

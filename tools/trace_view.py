@@ -38,7 +38,9 @@ counts only where no child covers) beside the by-neighbouring-programs
 table of ``benchmarks/harness/trace_reduce.py``. Before it attributes
 anything it checks causality: every run of ``--program`` (default the
 decode program) must start and end on the device inside one step's
-``serve.decode.dispatch`` .. ``serve.decode.wait``; if over 1 % do not,
+``serve.decode.dispatch`` .. ``serve.decode.wait`` (the two share the
+step's tags; the loop launches a step ahead, so the next step's dispatch
+lies between them); if over 1 % do not,
 the clocks disagree, and it says by how much and stops.
 
     python tools/trace_view.py spans.json --xplane t.xplane.pb -o m.json
