@@ -19,7 +19,12 @@ microseconds dispatch. This module makes that cost visible and bounded:
 - :func:`enable_persistent_cache` wires jax's persistent compilation cache
   (at ``JAX_COMPILATION_CACHE_DIR`` when set, else a fixed path in the
   checkout), so a restarted process pays tracing but not backend
-  compilation.
+  compilation;
+- :func:`watched_jit` / :func:`program_scopes` keep, for the programs
+  built through it (the serving engine's two, ``TrainStep``'s step), the
+  executables they ran and say which ``jax.named_scope`` each of their
+  instructions belongs to: what turns a device trace into time by scope
+  (:mod:`paddle_tpu.observability.scopes`).
 
 Trace count is the retrace signal, not XLA's internal executable cache:
 a trace is exactly one new specialization from the framework's point of
@@ -31,14 +36,18 @@ import contextlib
 import functools
 import itertools
 import os
+import json
 import threading
+import time
 import warnings
+import weakref
 from typing import Any, Callable, Dict, Optional
 
 __all__ = [
     "RetraceError", "cache_stats", "reset_stats", "instrument",
     "register_name", "retrace_guard", "enable_persistent_cache",
-    "backend_compile_stats", "initialize_from_flags",
+    "backend_compile_stats", "initialize_from_flags", "watched_jit",
+    "program_scopes", "export_program_scopes", "programs_with_scopes",
 ]
 
 
@@ -129,6 +138,10 @@ def record_trace(name: str, signature: str) -> None:
 def record_call(name: str) -> None:
     with _lock:
         _entry(name).calls += 1
+        prog = _programs.get(_base(name))
+        pending = prog is not None and prog.name == name and prog.pending
+    if pending:
+        _keep_executables(prog)
 
 
 def cache_stats(name: Optional[str] = None) -> dict:
@@ -179,6 +192,162 @@ def instrument(fn: Callable, name: Optional[str] = None) -> Callable:
 
     wrapped.__cc_name__ = key
     return wrapped
+
+
+
+# ------------------------------------------- executables by named scope
+class _Program:
+    """A watched program: its jitted callable (weakly: it closes over its
+    engine or step), the specializations traced and not kept yet, and the
+    executables kept, each with its scope map once somebody asked."""
+    __slots__ = ("name", "kind", "module", "jitted", "pending", "kept",
+                 "keep_s")
+
+    def __init__(self, name: str, kind: str, module: str):
+        self.name, self.kind, self.module = name, kind, module
+        self.jitted = lambda: None  # a weakref once the jit exists
+        self.pending: list = []     # (args, kwargs) of abstract leaves
+        self.kept: list = []        # [Compiled, scope map or None]
+        self.keep_s = 0.0           # what keeping them cost the callers
+
+
+#: the newest watched program of each name's base (``register_name`` adds
+#: a serial per instance): a second server replaces the first one's entry
+_programs: Dict[str, _Program] = {}
+
+
+def _base(name: str) -> str:
+    return name.split("#", 1)[0]
+
+
+def watched_jit(fn: Callable, name: str, kind: str, **jit_kwargs):
+    """``jax.jit(instrument(fn, name), **jit_kwargs)`` that also keeps
+    what :func:`program_scopes` needs: the executable of every
+    specialization it is traced for. ``kind`` names the program in a table
+    of device time (``"decode"``, ``"prefill"``, ``"train"``).
+
+    Nothing is held that holds ``fn`` (the engine, the step, their
+    parameters). The trace leaves its abstract arguments behind; the
+    first :func:`record_call` of ``name`` after it takes
+    ``jitted.lower(*abstract).compile()``, which in jax 0.9 answers from
+    the caches the call itself filled (no second trace, no backend
+    compile: milliseconds), and keeps that ``Compiled``. Where it would
+    NOT answer from them (arguments committed to a sharding that the
+    abstract ones do not carry, a mesh) the lowering is dropped and the
+    program has no map: nothing is ever compiled for the map's sake. A
+    step costs :func:`record_call` one more test."""
+    import jax
+
+    # "jit__decode_fn": what a profiler calls a run of it, less the hash
+    prog = _Program(name, kind, "jit_" + getattr(fn, "__name__", ""))
+    traced = instrument(fn, name)
+
+    def abstract(x):
+        # a static value (no shape) stays as it is
+        if not (hasattr(x, "shape") and hasattr(x, "dtype")):
+            return x
+        weak = getattr(getattr(x, "aval", None), "weak_type", False)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, weak_type=weak)
+
+    @functools.wraps(traced)
+    def noting(*args, **kwargs):
+        spec = jax.tree.map(abstract, (args, kwargs))
+        with _lock:
+            prog.pending.append(spec)
+        return traced(*args, **kwargs)
+
+    jitted = jax.jit(noting, **jit_kwargs)
+    prog.jitted = weakref.ref(jitted)
+    with _lock:
+        _programs[_base(name)] = prog
+    return jitted
+
+
+def _keep_executables(prog: _Program) -> None:
+    from ..distributed.mesh import get_mesh
+
+    with _lock:
+        pending, prog.pending = prog.pending, []
+    jitted, mesh = prog.jitted(), get_mesh()
+    if jitted is None or (mesh is not None and mesh.size > 1):
+        return      # sharded arguments: the abstract ones would not match
+    t0 = time.perf_counter()
+    for spec in pending:
+        args, kwargs = spec[:2]
+        lowered = jitted.lower(*args, **kwargs)
+        # the call's own executable, or none: `MeshComputation.compile`
+        # keeps what it compiled, so an executable there means the call
+        # went through this very lowering
+        if getattr(getattr(lowered, "_lowering", None), "_executable",
+                   None) is not None:
+            with _lock:
+                prog.kept.append([lowered.compile(), None])
+        elif len(spec) == 2:
+            # traced by a `lower()` that no call has followed yet: the
+            # next call of the program gets one more look
+            with _lock:
+                prog.pending.append(spec + ("again",))
+        else:
+            warnings.warn(
+                f"{prog.name}: the lowering for its abstract arguments is "
+                f"not the one the call compiled; no scope map is kept",
+                RuntimeWarning, stacklevel=3)
+    prog.keep_s += time.perf_counter() - t0
+
+
+def program_scopes() -> Dict[str, dict]:
+    """``{"<program name>@<i>": {"kind", "module", "keep_s", "ops": {event
+    key: op_name}}}`` for every executable kept of the watched programs
+    (the i-th specialization of the program registered under that name):
+    which ``jax.named_scope`` path each instruction of the optimized HLO
+    belongs to, keyed as a profiler's device event of it is
+    (:func:`paddle_tpu.observability.scopes.event_key`); ``module`` is the
+    name a profiler gives a run of the program, less its hash; ``keep_s``
+    is what keeping the program's executables cost its callers, all told.
+
+    The text is parsed when first asked for and the map kept. Asking
+    traces nothing and compiles nothing (the executables were kept by
+    :func:`watched_jit`'s hook, outside any window), so it is safe
+    inside a :func:`retrace_guard` and between two readings of
+    :func:`cache_stats`; it works after the engine or the step is gone.
+
+    Trap: jax leaves op metadata out of the persistent cache's key unless
+    told otherwise, so an executable loaded from a cache directory that
+    another tree wrote carries THAT tree's scope names, and a join against
+    a trace books time under names this tree does not have, or under
+    ``unscoped``. :func:`enable_persistent_cache` tells it otherwise; a
+    cache turned on through jax's own configuration is the case to
+    mind."""
+    from ..observability.scopes import parse_hlo_scopes
+
+    with _lock:
+        progs = list(_programs.values())
+    out = {}
+    for prog in progs:
+        for i, slot in enumerate(list(prog.kept)):
+            if slot[1] is None:
+                slot[1] = parse_hlo_scopes(slot[0].as_text())
+            out[f"{prog.name}@{i}"] = {"kind": prog.kind,
+                                       "module": prog.module,
+                                       "keep_s": prog.keep_s,
+                                       "ops": slot[1]}
+    return out
+
+
+def export_program_scopes(path: str) -> int:
+    """Write :func:`program_scopes` to ``path`` as JSON (what
+    ``tools/trace_view.py --scopes`` reads beside a profile of this
+    process); returns the number of executables written."""
+    scopes = program_scopes()
+    with open(path, "w") as f:
+        json.dump(scopes, f)
+    return len(scopes)
+
+
+def programs_with_scopes() -> int:
+    """How many executables :func:`program_scopes` would describe."""
+    with _lock:
+        return sum(len(p.kept) for p in _programs.values())
 
 
 class _Guard:
@@ -274,6 +443,11 @@ def enable_persistent_cache(cache_dir: Optional[str] = None,
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       float(min_compile_secs))
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # jax leaves op metadata (the named scopes, file and line) out of the
+    # cache's key by default: an executable another tree wrote would then
+    # come back with that tree's scope names in it, and program_scopes()
+    # would book device time under them
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     _persistent_dir = cache_dir
     _listen_for_backend_compiles()
     return cache_dir

@@ -314,9 +314,12 @@ class TrainStep(StepSeams):
         self._cc_name = compile_cache.register_name(
             f"{type(self).__name__}:{type(model).__name__}")
         self._traced = compile_cache.instrument(self._step, self._cc_name)
-        # two specializations when accumulating: accumulate-only / apply
-        self._compiled = jax.jit(self._traced, donate_argnums=donate_argnums,
-                                 static_argnames=("do_update",))
+        # two specializations when accumulating: accumulate-only / apply;
+        # watched, so that program_scopes() can say what scope each
+        # instruction of the step belongs to
+        self._compiled = compile_cache.watched_jit(
+            self._step, self._cc_name, "train",
+            donate_argnums=donate_argnums, static_argnames=("do_update",))
         # FLAGS_check_nan_inf / watchdog variant: also reduces grads/params
         # finiteness in-graph (framework/debugging.py) — compiled on first use
         self._compiled_checked = None

@@ -183,6 +183,7 @@ class GPTEmbeddings(Layer):
             default_initializer=Normal(0.0, cfg.initializer_range))
         self.dropout = Dropout(cfg.hidden_dropout_prob)
 
+    @jax.named_scope("embed")
     def forward(self, input_ids, position_offset=0):
         L = input_ids.shape[1]
         h = self.word_embeddings(input_ids)
@@ -214,9 +215,11 @@ class GPTModel(Layer):
         if cache is not None:
             x, cache = self.h(x, caches=cache,
                               position_offset=position_offset)
-            return self.ln_f(x), cache
-        x = self.h(x)
-        return self.ln_f(x)
+        else:
+            x = self.h(x)
+        with jax.named_scope("final_norm"):
+            x = self.ln_f(x)
+        return x if cache is None else (x, cache)
 
 
 def _BlockList(cfg: GPTConfig):

@@ -286,12 +286,16 @@ class JambaModel(Layer):
     def forward(self, input_ids, cache=None, position_offset=0):
         """Final hidden states [B, L, C] float32, with the updated cache
         when one is given."""
-        x = self.embed_tokens(input_ids).astype(jnp.float32)
+        with jax.named_scope("embed"):
+            x = self.embed_tokens(input_ids).astype(jnp.float32)
         if cache is None:
-            return self.final_layernorm(self.layers(x))
-        x, cache = self.layers(x, caches=cache,
-                               position_offset=position_offset)
-        return self.final_layernorm(x), cache
+            x = self.layers(x)
+        else:
+            x, cache = self.layers(x, caches=cache,
+                                   position_offset=position_offset)
+        with jax.named_scope("final_norm"):
+            x = self.final_layernorm(x)
+        return x if cache is None else (x, cache)
 
 
 class JambaForCausalLM(LlamaForCausalLM):

@@ -457,6 +457,7 @@ def _rows_by_dma(k_cache, v_cache, new, pos) -> bool:
             and cache_write.rows_fit(v_cache, new))
 
 
+@jax.named_scope("cache_write")
 def update_kv_cache(cache, k_new, v_new, position_offset, entry=None):
     """Write ``k_new``/``v_new`` [B, L, Hkv, D] into the preallocated
     ``(k, v)`` cache pair at ``position_offset`` along the length axis
@@ -541,6 +542,7 @@ def _reads_by_position(q, k_cache, v_cache, pos) -> bool:
             and cache_read.reads_fit(v_cache, q))
 
 
+@jax.named_scope("cache_read")
 def cached_attention(q, k_cache, v_cache, position_offset, entry=None):
     """Dot-product attention of ``q`` [B, L, H, D] against the FULL cache
     [B, S, Hkv, D] (entry ``entry`` of ``[B, E, S, Hkv, D]`` leaves where
@@ -603,6 +605,7 @@ def _read_whole(q, k_cache, v_cache, position_offset, entry=None):
     return out.reshape(B, L, H, D)
 
 
+@jax.named_scope("cache_read")
 def latent_attention(q_c, q_r, c_cache, kr_cache, position_offset, scale):
     """Attention IN THE LATENT SPACE against the full cache of a latent
     entry, for single-token decode and chunked continuation: ``q_c`` [B,

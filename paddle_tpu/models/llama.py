@@ -244,14 +244,17 @@ class LlamaModel(Layer):
         self.norm = RMSNorm(cfg.hidden_size, epsilon=cfg.rms_norm_eps)
 
     def forward(self, input_ids, cache=None, position_offset=0):
-        x = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            x = self.embed_tokens(input_ids)
         x = _constrain_seq(x, self.cfg)
         if cache is not None:
             x, cache = self.layers(x, caches=cache,
                                    position_offset=position_offset)
-            return self.norm(x), cache
-        x = self.layers(x)
-        return self.norm(x)
+        else:
+            x = self.layers(x)
+        with jax.named_scope("final_norm"):
+            x = self.norm(x)
+        return x if cache is None else (x, cache)
 
 
 class LlamaForCausalLM(Layer):

@@ -158,9 +158,8 @@ def attend_with_latent_cache(q_nope, q_rope, c_new, k_rope_new, w_uk, w_uv,
                                           w_uk, w_uv, scale), cache
         with jax.named_scope("absorb"):
             q_c = jnp.einsum("blhn,chn->blhc", q_nope, w_uk)
-        with jax.named_scope("latent_read"):
-            o_c = latent_attention(q_c, q_rope, cache[0], cache[1],
-                                   position_offset, scale)
+        o_c = latent_attention(q_c, q_rope, cache[0], cache[1],
+                               position_offset, scale)
         return jnp.einsum("blhc,chv->blhv", o_c, w_uv), cache
 
 
@@ -268,12 +267,12 @@ def scan_with_state(u_pre, conv_weight, conv_bias, ssm_params, A, D,
             delta = jnp.where(real[None, :, None], delta, 0.0)
     with jax.named_scope("scan" if L > 1 else "state_update"):
         y, h = selective_scan(u, delta, A, Bm, Cm, D, h0)
-    if cache is not None:
-        # ext[j] is the input at block position j - (K - 1): the last
-        # K - 1 real ones end at position n - 1
-        tail = (ext[:, L:] if n is None else
-                jax.lax.dynamic_slice_in_dim(ext, n, K - 1, axis=1))
-        cache = write_state(cache, h, tail)
+        if cache is not None:
+            # ext[j] is the input at block position j - (K - 1): the last
+            # K - 1 real ones end at position n - 1
+            tail = (ext[:, L:] if n is None else
+                    jax.lax.dynamic_slice_in_dim(ext, n, K - 1, axis=1))
+            cache = write_state(cache, h, tail)
     return y, cache
 
 
@@ -317,17 +316,21 @@ class DecoderBlockList(Layer):
             self.add_sublayer(str(i), block_cls(cfg))
 
     def forward(self, x, caches=None, position_offset=0, cache_entry=None):
+        # "block": what a block does itself (its norms, the residual adds)
+        # is booked there; attention, mlp and the rest open their own
         if caches is None:
             for blk in self._sub_layers.values():
                 fn = (recompute_wrap(blk, policy=self.cfg.recompute_policy)
                       if self.cfg.use_recompute else blk)
-                x = fn(x)
+                with jax.named_scope("block"):
+                    x = fn(x)
             return x
         new_caches = []
         kw = {} if cache_entry is None else {"cache_entry": cache_entry}
         for blk, cache in zip(self._sub_layers.values(), caches):
-            x, cache = blk(x, cache=cache, position_offset=position_offset,
-                           **kw)
+            with jax.named_scope("block"):
+                x, cache = blk(x, cache=cache,
+                               position_offset=position_offset, **kw)
             new_caches.append(cache)
         return x, tuple(new_caches)
 

@@ -150,19 +150,19 @@ class OuroModel(Layer):
         and in bfloat16 the sum's rounding (2**-9 of the stream) is 3 % of
         it, 384 times a token. Measured at 48 layers x 4 steps: it halves
         the logits' distance from the float32 reference (PERF.md PR 27)."""
-        x = constrain_seq(self.embed_tokens(input_ids), self.cfg)
-        x = x.astype(jnp.float32)
+        with jax.named_scope("embed"):
+            x = constrain_seq(self.embed_tokens(input_ids), self.cfg)
+            x = x.astype(jnp.float32)
 
         def step(carry, t):
             x, cache = carry
-            with jax.named_scope("ut_step"):
-                if cache is None:
-                    x = self.layers(x)
-                else:
-                    x, cache = self.layers(x, caches=cache,
-                                           position_offset=position_offset,
-                                           cache_entry=t)
-                x = self.norm(x)
+            if cache is None:
+                x = self.layers(x)
+            else:
+                x, cache = self.layers(x, caches=cache,
+                                       position_offset=position_offset,
+                                       cache_entry=t)
+            x = self.norm(x)
             lam = None
             if exit_gates:
                 with jax.named_scope("exit_gate"):
@@ -170,9 +170,13 @@ class OuroModel(Layer):
                         self.early_exit_gate(x)[..., 0].astype(jnp.float32))
             return (x, cache), lam
 
-        (x, cache), lam = jax.lax.scan(
-            step, (x, cache),
-            jnp.arange(self.cfg.total_ut_steps, dtype=jnp.int32))
+        # around the loop, not inside its body: what the compiler hoists
+        # out of the body (a weight re-laid once a token for all the
+        # passes) is the loop's too
+        with jax.named_scope("ut_step"):
+            (x, cache), lam = jax.lax.scan(
+                step, (x, cache),
+                jnp.arange(self.cfg.total_ut_steps, dtype=jnp.int32))
         out = x if cache is None else (x, cache)
         return (out, lam) if exit_gates else out
 

@@ -319,6 +319,7 @@ class StreamMixer(Layer):
         self.bias = self.create_parameter(
             (n * n + 2 * n,), attr=_DiagBias(n, _RES_DIAG))
 
+    @jax.named_scope("streams")
     def pre(self, X):
         """``(h [B, L, C], (H_post [n, B, L], M))`` of streams ``X`` [B, L,
         n, C] float32; ``M[j][i]`` [B, L] as :func:`sinkhorn` returns it."""
@@ -345,15 +346,16 @@ class StreamMixer(Layer):
                          cfg.hc_sinkhorn_iters, cfg.hc_eps)
         return h, (h_post, M)
 
-    @jax.named_scope("hc_post")
+    @jax.named_scope("streams")
     def post(self, X, y, mix):
         """``X'_j = sum_i M[j, i] X_i + H_post[j] y``."""
         h_post, M = mix
         n = self.cfg.hc_mult
         y = y.astype(jnp.float32)
-        return jnp.stack(
-            [sum(M[j][i][..., None] * X[:, :, i] for i in range(n))
-             + h_post[j][..., None] * y for j in range(n)], axis=2)
+        with jax.named_scope("hc_post"):
+            return jnp.stack(
+                [sum(M[j][i][..., None] * X[:, :, i] for i in range(n))
+                 + h_post[j][..., None] * y for j in range(n)], axis=2)
 
 
 class XingBlock(Layer):
@@ -411,15 +413,18 @@ class XingModel(Layer):
     def forward(self, input_ids, cache=None, position_offset=0):
         """Final hidden states [B, L, C] float32 (the streams summed,
         normed), with the updated cache when one is given."""
-        x = self.embed_tokens(input_ids).astype(jnp.float32)
-        X = jnp.broadcast_to(x[:, :, None, :],
-                             x.shape[:2] + (self.cfg.hc_mult, x.shape[-1]))
+        with jax.named_scope("embed"):
+            x = self.embed_tokens(input_ids).astype(jnp.float32)
+            X = jnp.broadcast_to(
+                x[:, :, None, :],
+                x.shape[:2] + (self.cfg.hc_mult, x.shape[-1]))
         if cache is None:
             X = self.layers(X)
         else:
             X, cache = self.layers(X, caches=cache,
                                    position_offset=position_offset)
-        h = self.norm(jnp.sum(X, axis=2))
+        with jax.named_scope("final_norm"):
+            h = self.norm(jnp.sum(X, axis=2))
         return h if cache is None else (h, cache)
 
 

@@ -199,13 +199,13 @@ class ContinuousBatchingEngine:
         else:
             donate = (2,) if on_device else ()
             prefill = self._prefill_lora_fn if lora else self._prefill_fn
-        self._prefill_compiled = jax.jit(
-            compile_cache.instrument(prefill, self._cc_prefill),
-            donate_argnums=donate)
-        self._decode_compiled = jax.jit(
-            compile_cache.instrument(
-                self._decode_lora_fn if lora else self._decode_fn,
-                self._cc_decode),
+        # watched: the executables stay behind for program_scopes(), which
+        # says what scope each instruction of a device trace belongs to
+        self._prefill_compiled = compile_cache.watched_jit(
+            prefill, self._cc_prefill, "prefill", donate_argnums=donate)
+        self._decode_compiled = compile_cache.watched_jit(
+            self._decode_lora_fn if lora else self._decode_fn,
+            self._cc_decode, "decode",
             donate_argnums=(2,) if on_device else ())
         self.reset()
 
@@ -902,7 +902,9 @@ class ContinuousBatchingEngine:
         ``"kv+state"``: then ``cache_entries`` counts the entries
         indexed by position alone, and ``state_entries`` and
         ``state_bytes_per_slot`` (what a slot holds whatever its length,
-        as allocated) stand beside them."""
+        as allocated) stand beside them. ``programs_with_scopes`` counts
+        the executables the process could describe instruction by
+        instruction (``compile_cache.program_scopes()``)."""
         state = self.state_bytes_per_slot
         return {"prefill": compile_cache.cache_stats(self._cc_prefill),
                 "decode": compile_cache.cache_stats(self._cc_decode),
@@ -911,6 +913,8 @@ class ContinuousBatchingEngine:
                 "cache_entries": cache_entries(self.spec),
                 "cache_entry": cache_entry_kind(self.spec),
                 "cache_bytes_per_token": self._bytes_per_token,
+                "programs_with_scopes":
+                    compile_cache.programs_with_scopes(),
                 **({} if state is None else {
                     "state_entries": state_entries(self.spec),
                     "state_bytes_per_slot": state})}

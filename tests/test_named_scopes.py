@@ -1,7 +1,9 @@
 """``jax.named_scope`` on the model's parts: op metadata that names the
 device operations of a profile by attention / MLP / loss head / optimizer
-and prefill / decode / sample. The names must reach the lowered programs
-the benchmark runs: the train step and the serving decode step."""
+and prefill / decode / sample. The names must reach the programs the
+benchmark runs, the train step and the serving decode step: here as they
+are lowered; ``tests/test_program_scopes.py`` holds them in the optimized
+text of the executables, instruction by instruction."""
 import re
 
 import numpy as np
@@ -9,18 +11,19 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu.models.gpt import GPTForCausalLM, gpt_tiny
+from paddle_tpu.observability.scopes import scope_names
 from paddle_tpu.optimizer import AdamW
 
 
 def _scopes(lowered):
-    """The scope names in the programs' op metadata: the path segments of
-    every ``loc("jit(f)/jit(main)/<scope>/.../<primitive>")``, with the
-    transforms' wrappers (``jvp(...)``, ``transpose(...)``) taken off."""
+    """The scope names in a lowered program's op metadata: the segments of
+    every ``loc("jit(f)/jit(main)/<scope>/.../<primitive>")`` as the
+    program's one rule reads a path (``scope_names``: the transforms'
+    wrappers, ``jvp(...)``, ``transpose(...)``, taken off)."""
     text = lowered.as_text(debug_info=True)
     found = set()
     for path in re.findall(r'loc\("([^"]+)"', text):
-        for seg in path.split("/"):
-            found.add(re.sub(r"^(?:\w+\()+|\)+$", "", seg))
+        found.update(scope_names(path))
     return found, text
 
 
@@ -36,7 +39,8 @@ def test_train_step_names_attention_mlp_loss_head_and_optimizer(cfg):
                         loss_fn=None, inputs_fn=lambda b: b)
     ids = np.ones((2, 16), np.int32)
     found, text = _scopes(step.lower((ids, ids)))
-    assert {"attention", "mlp", "loss_head", "optimizer"} <= found
+    assert {"embed", "block", "attention", "mlp", "final_norm", "loss_head",
+            "optimizer"} <= found
     # metadata only: the program's text without it holds none of them
     plain = step.lower((ids, ids)).as_text()
     assert "loss_head" not in plain and "optimizer" not in plain
@@ -53,15 +57,18 @@ def test_decode_program_names_decode_sample_and_the_model_parts(cfg):
     lowered = eng._decode_compiled.lower(
         eng._params, eng._buffers, eng.live_cache, *eng._decode_inputs())
     found, _ = _scopes(lowered)
-    assert {"decode", "sample", "attention", "mlp", "lm_head"} <= found
+    assert {"decode", "sample", "embed", "block", "attention", "cache_write",
+            "cache_read", "mlp", "final_norm", "lm_head"} <= found
     assert "prefill" not in found
     lowered = eng._prefill_compiled.lower(
         eng._params, eng._buffers, eng.live_cache,
         np.zeros((1, 16), np.int32), np.int32(0), np.int32(3), eng._keys[0],
         np.int32(-1), np.float32(1), np.float32(1), np.bool_(True))
     found, _ = _scopes(lowered)
-    assert {"prefill", "sample", "attention", "mlp"} <= found
-    assert "decode" not in found
+    # a prefill attends over its own block: it writes the cache and does
+    # not read it
+    assert {"prefill", "sample", "attention", "cache_write", "mlp"} <= found
+    assert "decode" not in found and "cache_read" not in found
 
 
 def test_generate_programs_name_prefill_decode_and_sample(cfg):

@@ -127,3 +127,74 @@ def test_innermost_segments_give_a_parent_only_what_no_child_covers():
     assert trace_view.innermost_segments(spans) == [
         (0.0, 3.0, "serve.prefill.dispatch"), (3.0, 8.0, "serve.prefill.wait"),
         (8.0, 10.0, "serve.admit"), (10.0, 11.0, "serve.schedule")]
+
+
+# ------------------------------------------------ --scopes: time by scope
+def _hand_made_reduction():
+    """A reduced trace (``trace_reduce.reduce_trace``'s shape) of one
+    decode step, no span beside it: three instructions and the ``while``
+    that encloses two of them."""
+    tile = "{1,0:T(8,128)(2,1)}"
+    ev = lambda name, opcode, operand, tail="": (
+        f"%{name} = bf16[8,128]{tile} {opcode}(bf16[8,128]{tile} "
+        f"%{operand}){tail}")
+    ops = {
+        ev("fusion.1", "fusion", "p.1",
+           ", kind=kLoop, calls=%fused_computation.1"): (4, 600.0),
+        ev("fusion.2", "fusion", "fusion.1",
+           ", kind=kLoop, calls=%fused_computation.2"): (4, 300.0),
+        ev("copy.3", "copy", "fusion.2"): (1, 100.0),
+        ev("while.4", "while", "tuple.1",
+           ", condition=%cond.1, body=%body.1"): (1, 900.0),
+    }
+    return {"devices": [{"name": "/device:TPU:0", "ops": ops,
+                         "busy_ns": 1000.0}],
+            "busy_s": 1000e-9, "window_s": 1100e-9}
+
+
+def test_scope_lines_of_a_hand_made_reduction_and_map():
+    from paddle_tpu.observability.scopes import event_key
+
+    red = _hand_made_reduction()
+    names = ["jit(_decode_fn)/decode/block/attention/cache_write/while/body/"
+             "dynamic_update_slice",
+             "jit(_decode_fn)/decode/block/moe/experts/ragged_dot", "",
+             "jit(_decode_fn)/decode/block/attention/cache_write/while"]
+    maps = {"serve:decode:M#1@0": {
+        "kind": "decode",
+        "ops": {event_key(t): n for t, n in zip(red["devices"][0]["ops"],
+                                                names)}}}
+    lines = trace_view.scope_lines(red, maps)
+    # the while's 900 ns are its body's once more: 1000 ns of leaves
+    assert lines[0].startswith("device time by scope: leaves 0.0000 s "
+                               "against busy 0.0000 s (+0.00 %)")
+    rows = [" ".join(line.split()) for line in lines[1:]]
+    assert rows[0] == "0.0000 s 60.00 % decode / cache_write"
+    assert rows[1] == "0.0000 s 30.00 % decode / moe"
+    assert rows[2] == "0.0000 s 30.00 % moe / experts"
+    assert rows[3] == "0.0000 s 10.00 % decode / unscoped"
+    assert rows[5].startswith("0.0000 s 10.00 % decode / unscoped: %copy = "
+                              "bf16[8,128] copy")
+
+
+def test_main_prints_time_by_scope_without_spans(tmp_path, capsys):
+    """``--xplane F --scopes S.json`` and no span file: the recorded
+    trace against a map that books its flash kernels under attention."""
+    from paddle_tpu.observability.scopes import event_key
+
+    red = trace_view._trace_reduce().reduce_trace(XPLANE)
+    ops = {event_key(t): ("jit(_step)/jvp(block)/jvp(attention)/pallas_call"
+                          if "_flash_" in t else "jit(_step)/jvp(block)/add")
+           for d in red["devices"] for t in d["ops"]}
+    path = tmp_path / "scopes.json"
+    path.write_text(json.dumps(
+        {"TrainStep:GPT#0@0": {"kind": "train", "ops": ops}}))
+    assert trace_view.main(["--xplane", XPLANE, "--scopes", str(path)]) == 0
+    out = capsys.readouterr().out.splitlines()
+    assert out[0].startswith("device time by scope: leaves ")
+    rows = {" ".join(line.split()[4:]): float(line.split()[2])
+            for line in out[1:3]}
+    assert set(rows) == {"train / block", "train / attention"}
+    assert sum(rows.values()) == pytest.approx(100.0, abs=0.02)
+    assert trace_view.main(["--scopes", str(path), "--xplane",
+                            str(tmp_path / "missing.pb")]) == 2

@@ -574,7 +574,7 @@ def test_snapshot_and_statusz_carry_the_expert_load(lm):
         lowered = srv.engine._decode_compiled.lower(
             srv.engine._params, srv.engine._buffers, srv.engine.live_cache,
             *srv.engine._decode_inputs())
-    assert {"decode", "attention", "mla", "absorb", "latent_read", "moe",
-            "router", "dispatch", "experts", "shared_expert", "combine",
-            "hc_pre", "sinkhorn", "hc_post", "mlp", "lm_head"} \
-        <= _scopes(lowered)
+    assert {"decode", "attention", "mla", "absorb", "cache_write",
+            "cache_read", "moe", "router", "dispatch", "experts",
+            "shared_expert", "combine", "streams", "hc_pre", "sinkhorn",
+            "hc_post", "mlp", "lm_head"} <= _scopes(lowered)

@@ -45,6 +45,16 @@ the clocks disagree, and it says by how much and stops.
 
     python tools/trace_view.py spans.json --xplane t.xplane.pb -o m.json
 
+``--scopes <S.json>`` (with ``--xplane``, spans optional) prints the
+device's time by program and named scope: ``S.json`` is what
+``compile_cache.export_program_scopes(path)`` wrote in the process that
+was profiled (which ``jax.named_scope`` each instruction of its compiled
+programs belongs to), joined with the trace's device events by
+``benchmarks/harness/scope_time.py``, the code the benchmark's
+``model.*_share`` metrics read.
+
+    python tools/trace_view.py --xplane t.xplane.pb --scopes S.json
+
 Exit codes: 0 ok; 2 no spans found / unreadable input; 3 the spans and
 the trace are not on one clock.
 """
@@ -233,15 +243,32 @@ STEP_SPANS = ("serve.decode.dispatch", "serve.decode.wait")
 UNCOVERED = "(no span)"
 
 
-def _trace_reduce():
+def _harness(module: str):
     """The device side of a trace is the benchmark's reduction, not a
     copy of it."""
+    import importlib
+
     bench = os.path.join(ROOT, "benchmarks")
     if bench not in sys.path:
         sys.path.insert(0, bench)
-    from harness import trace_reduce
+    return importlib.import_module("harness." + module)
 
-    return trace_reduce
+
+def _trace_reduce():
+    return _harness("trace_reduce")
+
+
+def scope_lines(reduced: dict, maps: dict) -> List[str]:
+    """The table of device time by program and named scope, as the
+    benchmark logs it: ``reduced`` a trace as ``reduce_trace`` gives it,
+    ``maps`` what ``compile_cache.export_program_scopes`` wrote."""
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
+    from paddle_tpu.observability import scopes
+
+    st = _harness("scope_time")
+    return st.lines(st.join(reduced["devices"], maps, scopes),
+                    reduced["busy_s"], _trace_reduce().op_key)
 
 
 def read_xplane(path: str) -> dict:
@@ -436,7 +463,7 @@ def report_xplane(spans: List[dict], xp: dict, program: str) -> int:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("inputs", nargs="+",
+    ap.add_argument("inputs", nargs="*",
                     help="flight dumps / span lists / chrome traces")
     ap.add_argument("-o", "--output", default=None,
                     help="merged chrome-trace JSON path")
@@ -454,7 +481,26 @@ def main(argv=None) -> int:
     ap.add_argument("--program", default="decode",
                     help="with --xplane: the device program whose runs "
                          "the causality check places (default: decode)")
+    ap.add_argument("--scopes", default=None, metavar="S.json",
+                    help="with --xplane: what compile_cache."
+                         "export_program_scopes() wrote in the profiled "
+                         "process; print device time by program and "
+                         "named scope (needs no spans)")
     args = ap.parse_args(argv)
+
+    if args.scopes:
+        if not args.xplane:
+            ap.error("--scopes needs --xplane")
+        try:
+            with open(args.scopes) as f:
+                maps = json.load(f)
+            reduced = _trace_reduce().reduce_trace(args.xplane)
+        except Exception as e:
+            print(f"trace_view: {type(e).__name__}: {e}", file=sys.stderr)
+            return 2
+        print("\n".join(scope_lines(reduced, maps)))
+        if not args.inputs:
+            return 0
 
     spans: List[dict] = []
     for path in args.inputs:
