@@ -17,7 +17,9 @@ and the example use (hidden 1024, 16 heads, vocab 50304, 24 layers):
 - **serve**: the per-slot cache write's kernels against the scatter,
   element for element, and the read's kernels against XLA's read of the
   whole leaf, to a stated bound, at the row geometries of the benchmark's
-  three serve cells; then ``InferenceServer(slots=8)`` with the default
+  three serve cells and at its sparse cell's latent pair (``c`` of 512
+  beside ``k_r`` of 64, one head, under 32 query heads); then
+  ``InferenceServer(slots=8)`` with the default
   prefill buckets, warmed up, then 32 concurrent mixed-length requests,
   greedy and sampled, with zero compiles allowed, the decode program's
   cache write and read on their kernels, and one greedy stream compared
@@ -31,9 +33,10 @@ and the example use (hidden 1024, 16 heads, vocab 50304, 24 layers):
   against the reference's on equal streams (``mixer_check``), and the
   model built whole (11 GB: born in float32 it would not fit), two
   prompts prefilled into rows of a 32 x 8192 latent cache, 48 decode
-  steps with each row at its own position, the LOGITS held to the plain
-  float32 reference's full pass, positions within rounding of a routing
-  tie to a bound of their own (``latent_logits_check``); then the state
+  steps with each row at its own position (which must read the cache
+  by the latent body's kernel), the LOGITS held to the plain float32
+  reference's full pass, positions within rounding of a routing tie to
+  a bound of their own (``latent_logits_check``); then the state
   geometry, the benchmark's hybrid state-space configuration whole (6 GB)
   in bfloat16: two prompts right-padded to a bucket and prefilled into
   rows of a 128 x 8192 cache whose entries are 26 recurrent states beside
@@ -342,6 +345,10 @@ def greedy_parity(model, prompt, served, solo) -> str:
 #: the serve cells' cache rows (gpt3-medium 16 x 64, gpt3-xl 16 x 128,
 #: ouro-2.6b 16 x 128 in leaves of stacked entries), in shorter leaves
 CELL_LEAVES = ((8, 1024, 16, 64), (8, 1024, 16, 128), (4, 3, 512, 16, 128))
+#: the sparse serve cell's latent pair, in a shorter leaf: (slots, length,
+#: query heads, rank, rotated width) of ``c [slots, length, 1, rank]`` and
+#: ``k_r [slots, length, 1, rotated]``
+LATENT_LEAVES = ((8, 2048, 32, 512, 64),)
 
 
 def _leaf_case(n: int, shape, dtype):
@@ -394,31 +401,29 @@ def cache_write_check(leaves=CELL_LEAVES, dtype="bfloat16") -> None:
                   f"differ from the scatter's, {written} written")
 
 
-def cache_read_check(leaves=CELL_LEAVES, dtype="bfloat16") -> None:
+def cache_read_check(leaves=CELL_LEAVES, latent=LATENT_LEAVES,
+                     dtype="bfloat16") -> None:
     """``kernels.cache_read.read_by_position`` against XLA's read of the
     whole leaf under a mask (``kv_cache._read_whole``), on random leaves
-    with every slot at a position of its own. The truth is XLA's path on
-    f32 copies; the kernel (f32 scores and softmax, one rounding at the
-    end) is held to a bf16 step of the largest output, and XLA's path in
-    ``dtype`` (bf16 scores and weights) is reported beside it. The gate
-    is ``cached_attention``'s own (``kv_cache._reads_by_position``)."""
+    with every slot at a position of its own, and
+    ``read_latent_by_position`` against ``kv_cache._latent_read_whole``
+    on random latent pairs (``latent``). The truth is XLA's path on f32
+    copies; the kernel (f32 scores and softmax, one rounding at the end)
+    is held to a bf16 step of the largest output, and XLA's path in
+    ``dtype`` (bf16 scores and weights) is reported beside it. The gates
+    are ``cached_attention``'s and ``latent_attention``'s own
+    (``kv_cache._reads_by_position``, ``_latent_reads_by_position``)."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.kernels import cache_read
     from paddle_tpu.models import kv_cache
 
-    xla = kv_cache._read_whole
-    for n, shape in enumerate(leaves):
-        k, v, (q, _), pos, entry = _leaf_case(n, shape, dtype)
-        check(kv_cache._reads_by_position(q, k, v, pos),
-              f"cache read: the gate refuses a leaf {list(shape)} {dtype}")
-        got = jax.jit(lambda *a: cache_read.read_by_position(*a))(
-            q, k, v, pos, entry).astype(jnp.float32)
-        plain = jax.jit(xla)(q, k, v, pos, entry).astype(jnp.float32)
-        truth = jax.jit(xla)(*(x.astype(jnp.float32) for x in (q, k, v)),
-                             pos, entry)
+    def held(shape, got, xla, operands, rest):
+        plain = jax.jit(xla)(*operands, *rest).astype(jnp.float32)
+        truth = jax.jit(xla)(*(x.astype(jnp.float32) for x in operands),
+                             *rest)
         bound = _bf16_step(float(jnp.max(jnp.abs(truth))))
-        differ = float(jnp.max(jnp.abs(got - truth)))
+        differ = float(jnp.max(jnp.abs(got.astype(jnp.float32) - truth)))
         log(f"[serve] cache read {list(shape)}: kernel vs the f32 read max "
             f"abs difference {differ:.3e} (bound {bound:.3e}); XLA's "
             f"{dtype} read {float(jnp.max(jnp.abs(plain - truth))):.3e}")
@@ -426,6 +431,29 @@ def cache_read_check(leaves=CELL_LEAVES, dtype="bfloat16") -> None:
               f"cache read {list(shape)}: the kernel is {differ:.3e} from "
               f"the f32 read, more than a bf16 step of the largest output "
               f"({bound:.3e})")
+
+    for n, shape in enumerate(leaves):
+        k, v, (q, _), pos, entry = _leaf_case(n, shape, dtype)
+        check(kv_cache._reads_by_position(q, k, v, pos),
+              f"cache read: the gate refuses a leaf {list(shape)} {dtype}")
+        got = jax.jit(lambda *a: cache_read.read_by_position(*a))(
+            q, k, v, pos, entry)
+        held(shape, got, kv_cache._read_whole, (q, k, v), (pos, entry))
+    for n, (slots, length, heads, rank, rope) in enumerate(latent):
+        keys = jax.random.split(jax.random.PRNGKey(len(leaves) + n), 5)
+        c, kr, q_c, q_r = (
+            jax.random.normal(key, shape, dtype) for key, shape in zip(keys, (
+                (slots, length, 1, rank), (slots, length, 1, rope),
+                (slots, 1, heads, rank), (slots, 1, heads, rope))))
+        pos = jax.random.randint(keys[4], (slots,), 0, length)
+        pos = pos.at[0].set(0).at[-1].set(length - 1)
+        check(kv_cache._latent_reads_by_position(q_c, q_r, c, kr, pos),
+              f"cache read: the gate refuses a latent pair "
+              f"{list(c.shape)} + {list(kr.shape)} {dtype}")
+        scale = (rank // 4 + rope) ** -0.5     # heads of rank / 4 + rope
+        got = cache_read.read_latent_by_position(q_c, q_r, c, kr, pos, scale)
+        held((slots, length, heads, rank, rope), got,
+             kv_cache._latent_read_whole, (q_c, q_r, c, kr), (pos, scale))
 
 
 def serve_phase(cfg, slots: int, prompt_lens, n_requests: int,
@@ -730,6 +758,7 @@ def mixer_check(config: dict, seed: int, positions: int = 256,
 
 def latent_logits_check(config: dict, seed: int, slots: int, length: int,
                         bucket: int, prompt_lens, steps: int = 16,
+                        expect_cache_read: str = "kernel",
                         clean_bound: float = LATENT_CLEAN_BOUND,
                         tie_bound: float = LATENT_TIE_BOUND,
                         tie_margin: float = LATENT_TIE_MARGIN) -> dict:
@@ -741,8 +770,10 @@ def latent_logits_check(config: dict, seed: int, slots: int, length: int,
     the first and the last row of a ``slots x length`` cache by the
     engine's own admission path (``cache_row_view``); the decode steps
     run the WHOLE batch with each row at its own position (the other
-    rows decode filler), teacher-forced on seeded tokens. Compared are
-    the logits at the last prompt position and at every decode step,
+    rows decode filler), teacher-forced on seeded tokens, and must read
+    the cache by ``expect_cache_read`` (the kernel on the chip, ``"xla"``
+    on the CPU). Compared are the logits at the last prompt position and
+    at every decode step,
     both rows: a position's error is the largest |logit - reference|
     over the standard deviation of the reference's logits there (the
     rms over the vocabulary is reported beside it). The reference also
@@ -753,7 +784,7 @@ def latent_logits_check(config: dict, seed: int, slots: int, length: int,
     import jax
     import jax.numpy as jnp
     common = _bench_harness()
-    from paddle_tpu.models.kv_cache import (cache_row_buffers,
+    from paddle_tpu.models.kv_cache import (cache_paths, cache_row_buffers,
                                             cache_row_view, init_cache)
     from paddle_tpu.nn.layer import (buffer_state, functional_call,
                                      param_state)
@@ -795,16 +826,20 @@ def latent_logits_check(config: dict, seed: int, slots: int, length: int,
         logits, cache = prefill(params, buffers, cache, ids, np.int32(r),
                                 np.int32(n - 1))
         got[r].append(np.asarray(logits))
-    for i in range(steps):
-        tokens = np.zeros((slots, 1), np.int32)
-        positions = np.zeros(slots, np.int32)
-        for r, n, text in zip(rows, prompt_lens, texts):
-            tokens[r, 0], positions[r] = text[n + i], n + i
-        logits, cache = decode(params, buffers, cache, tokens, positions)
-        logits = np.asarray(logits)
-        for r in rows:
-            got[r].append(logits[r])
+    with cache_paths() as paths:       # open around the decode step's trace
+        for i in range(steps):
+            tokens = np.zeros((slots, 1), np.int32)
+            positions = np.zeros(slots, np.int32)
+            for r, n, text in zip(rows, prompt_lens, texts):
+                tokens[r, 0], positions[r] = text[n + i], n + i
+            logits, cache = decode(params, buffers, cache, tokens, positions)
+            logits = np.asarray(logits)
+            for r in rows:
+                got[r].append(logits[r])
     del cache
+    check(paths["read"] == {expect_cache_read},
+          f"latent geometry: the decode step reads its cache by "
+          f"{sorted(paths['read'])}, want {expect_cache_read}")
     log(f"[serve] latent: prefill of {list(prompt_lens)} tokens (bucket "
         f"{bucket}) into rows {list(rows)} of {slots} x {length}, then "
         f"{steps} decode steps, in {time.perf_counter() - t0:.1f} s")

@@ -34,8 +34,10 @@ own position) has a kernel for each half, and this module is their one
 importer: the write as direct copies (:mod:`..kernels.cache_write`, gate
 :func:`_rows_by_dma`) and the read by position, over the blocks that a
 slot's positions fill and no others (:mod:`..kernels.cache_read`, gate
-:func:`_reads_by_position`). A gate reads what the trace shows (backend,
-mesh, shapes, dtypes); every other shape keeps XLA's path.
+:func:`_reads_by_position` for a ``(k, v)`` pair and
+:func:`_latent_reads_by_position` for a latent one, whose write has no
+kernel yet). A gate reads what the trace shows (backend, mesh, shapes,
+dtypes); every other shape keeps XLA's path.
 """
 from __future__ import annotations
 
@@ -424,7 +426,9 @@ def cache_paths():
     this context issues its per-slot cache writes, ``"dma"``
     (:mod:`..kernels.cache_write`) or ``"scatter"`` (the vmapped
     ``dynamic_update_slice``), and its cache reads, ``"kernel"``
-    (:mod:`..kernels.cache_read`) or ``"xla"`` (the masked einsums)."""
+    (:mod:`..kernels.cache_read`: a ``(k, v)`` pair's read by position or
+    a latent pair's) or ``"xla"`` (the masked einsums over the whole
+    leaf, :func:`_read_whole`'s or :func:`latent_attention`'s)."""
     outer = getattr(_PATHS, "noted", None)
     noted = _PATHS.noted = {"write": set(), "read": set()}
     try:
@@ -440,7 +444,7 @@ def _note(kind: str, path: str) -> None:
 
 
 def _per_slot_on_one_tpu(pos) -> bool:
-    """What both kernels' gates ask first: ``[B]`` positions (the
+    """What every kernel's gate asks first: ``[B]`` positions (the
     continuous-batching decode step), a TPU, and no mesh over more than
     one device."""
     mesh = get_mesh()
@@ -605,6 +609,15 @@ def _read_whole(q, k_cache, v_cache, position_offset, entry=None):
     return out.reshape(B, L, H, D)
 
 
+def _latent_reads_by_position(q_c, q_r, c_cache, kr_cache, pos) -> bool:
+    """:func:`_reads_by_position` for a latent pair: the kernel takes a
+    TPU's per-slot (``[B]``-position) read for one query a slot from
+    plain leaves on one device that :func:`cache_read.latent_reads_fit`;
+    the einsums over the whole leaf take everything else."""
+    return (_per_slot_on_one_tpu(pos)
+            and cache_read.latent_reads_fit(c_cache, kr_cache, q_c, q_r))
+
+
 @jax.named_scope("cache_read")
 def latent_attention(q_c, q_r, c_cache, kr_cache, position_offset, scale):
     """Attention IN THE LATENT SPACE against the full cache of a latent
@@ -619,9 +632,28 @@ def latent_attention(q_c, q_r, c_cache, kr_cache, position_offset, scale):
     under :func:`cached_attention`'s position mask (scalar or per-row
     ``[B]`` offsets), float32 scores and softmax. Returns [B, L, H, rank];
     the caller applies the values' up-projection. No position is ever
-    decompressed. XLA's path over every position of the leaf: the read
-    by position has no kernel for this entry yet."""
+    decompressed.
+
+    The continuous-batching decode step's shape (one query a slot, a
+    ``[B]`` vector of positions, plain leaves on one TPU) reads only the
+    blocks that positions ``0 ... position_offset[b]`` of slot b fill,
+    ``c``'s once for scores and weighted sum
+    (:func:`_latent_reads_by_position`); every other shape takes XLA's
+    einsums over every position of the leaf."""
+    # tpu-lint: disable=R2(the gate reads the backend and the leaves' static type, shape and dtype — one program per cache layout)
+    if _latent_reads_by_position(q_c, q_r, c_cache, kr_cache,
+                                 jnp.asarray(position_offset, jnp.int32)):
+        _note("read", "kernel")
+        return cache_read.read_latent_by_position(
+            q_c, q_r, c_cache, kr_cache, position_offset, scale)
     _note("read", "xla")
+    return _latent_read_whole(q_c, q_r, c_cache, kr_cache, position_offset,
+                              scale)
+
+
+def _latent_read_whole(q_c, q_r, c_cache, kr_cache, position_offset, scale):
+    """:func:`latent_attention` as XLA issues it: three einsums, two of
+    them over every position of ``c``, under the mask."""
     c, kr = c_cache[:, :, 0], kr_cache[:, :, 0]            # [B, S, width]
     L, S = q_c.shape[1], c.shape[1]
     s = (jnp.einsum("blhc,bsc->bhls", q_c, c.astype(q_c.dtype),

@@ -48,18 +48,22 @@ def interpret_pallas():
         k["interpret"] = True
         return orig(*a, **k)
 
-    # the read is jitted on its own: no trace of it crosses this fixture
-    cache_read.read_by_position.clear_cache()
+    # the reads are jitted on their own: no trace of one crosses this fixture
+    reads = (cache_read.read_by_position, cache_read.read_latent_by_position)
+    for read in reads:
+        read.clear_cache()
     with mock.patch.object(fa.pl, "pallas_call", interp):
         yield calls
-    cache_read.read_by_position.clear_cache()
+    for read in reads:
+        read.clear_cache()
 
 
 @pytest.fixture()
 def show_the_gate_a_tpu(monkeypatch):
     """A callable after which the gates of the decode step's cache
     kernels (``kv_cache._rows_by_dma`` for the write,
-    ``kv_cache._reads_by_position`` for the read) see a TPU backend, for
+    ``kv_cache._reads_by_position`` and ``_latent_reads_by_position`` for
+    the read) see a TPU backend, for
     the length of a gate's own call only: nothing else in the process
     takes the CPU for a TPU."""
     from unittest import mock
@@ -74,7 +78,8 @@ def show_the_gate_a_tpu(monkeypatch):
         return gate
 
     def show():
-        for name in ("_rows_by_dma", "_reads_by_position"):
+        for name in ("_rows_by_dma", "_reads_by_position",
+                     "_latent_reads_by_position"):
             monkeypatch.setattr(kv_cache, name,
                                 shown(getattr(kv_cache, name)))
 

@@ -1,10 +1,10 @@
 """The decode step's cache read by position (``kernels/cache_read.py``)
 against XLA's read of the whole leaf under a mask
-(``kv_cache.cached_attention``'s einsums): the kernels in Pallas interpret
-mode, to the tolerance of an f32 softmax; what lies past a slot's frontier
-kept out; the gate that chooses between the two; and an engine decoding
-the same tokens either way. On the CPU the programs themselves always take
-XLA's path."""
+(``kv_cache.cached_attention``'s einsums, and ``latent_attention``'s for a
+latent pair): the kernels in Pallas interpret mode, to the tolerance of an
+f32 softmax; what lies past a slot's frontier kept out; the gate that
+chooses between the two; and an engine decoding the same tokens either
+way. On the CPU the programs themselves always take XLA's path."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -228,9 +228,172 @@ def test_gate_takes_a_mesh_of_one(as_on_tpu):
     assert paths == {"kernel"}
 
 
+
+# ------------------------------------------------------- the latent pair
+LATENT_BLOCK = cache_read._LATENT_BLOCK
+LS = 2 * LATENT_BLOCK
+#: the file's positions at the latent body's own block length
+LATENT_POSITIONS = np.array([0, LATENT_BLOCK - 1, LATENT_BLOCK,
+                             LATENT_BLOCK + 1, LS - 1, 77], np.int32)
+#: (query heads, rank, rotated width, dtype): ``c`` [B, LS, 1, rank] is
+#: row-major, ``k_r`` [B, LS, 1, rope] lives with S on the lanes
+LATENT = {
+    "rank128-bf16": (16, 128, 16, jnp.bfloat16),
+    "rank256-rope64-bf16": (32, 256, 64, jnp.bfloat16),
+    "rank128-f32": (8, 128, 8, jnp.float32),
+    "rank256-rope64-f32": (16, 256, 64, jnp.float32),
+}
+SCALE = 0.11
+
+
+def _latent_operands(case, seed=0, length=LS):
+    heads, rank, rope, dtype = LATENT[case]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (B, 1, heads, rank), dtype),
+            jax.random.normal(ks[1], (B, 1, heads, rope), dtype),
+            jax.random.normal(ks[2], (B, length, 1, rank), dtype),
+            jax.random.normal(ks[3], (B, length, 1, rope), dtype))
+
+
+def _latent_xla(q_c, q_r, c, kr, pos):
+    """``latent_attention``'s own einsum path, on f32 copies."""
+    return kv_cache._latent_read_whole(
+        *(x.astype(jnp.float32) for x in (q_c, q_r, c, kr)), pos, SCALE)
+
+
+@pytest.mark.parametrize("case", list(LATENT))
+def test_latent_kernel_agrees_with_xla(interpret_pallas, case):
+    dtype = LATENT[case][-1]
+    q_c, q_r, c, kr = _latent_operands(case)
+    pos = jnp.asarray(LATENT_POSITIONS)
+    assert cache_read.latent_reads_fit(c, kr, q_c, q_r)
+    got = cache_read.read_latent_by_position(q_c, q_r, c, kr, pos, SCALE)
+    _close(got, _latent_xla(q_c, q_r, c, kr, pos), dtype)
+    assert interpret_pallas == ["_shared_key_kernel"]
+    # position 0 attends to one position alone: every head reads its c
+    np.testing.assert_allclose(
+        np.asarray(got[0, 0], np.float32),
+        np.broadcast_to(np.asarray(c[0, 0], np.float32), got.shape[2:]),
+        atol=TOLERANCE[dtype], rtol=0)
+
+
+def test_the_latent_kernels_scale_is_applied_as_given(interpret_pallas):
+    """A traced scale (YaRN's, computed in a program) is the same call."""
+    q_c, q_r, c, kr = _latent_operands("rank128-f32", seed=4)
+    pos = jnp.asarray(LATENT_POSITIONS)
+    for scale in (0.03, 0.3):
+        got = jax.jit(cache_read.read_latent_by_position)(
+            q_c, q_r, c, kr, pos, jnp.float32(scale))
+        want = kv_cache._latent_read_whole(q_c, q_r, c, kr, pos, scale)
+        _close(got, want, jnp.float32)
+    assert interpret_pallas == ["_shared_key_kernel"]     # one trace
+
+
+@pytest.mark.parametrize("stale", [np.nan, np.inf, -3e38, 3e38])
+@pytest.mark.parametrize("dirty", ["c", "k_r", "both"])
+@pytest.mark.parametrize("case", ["rank128-bf16", "rank128-f32"])
+def test_what_lies_past_a_latent_frontier_never_reaches_the_output(
+        interpret_pallas, case, dirty, stale):
+    """``c`` is keys AND values: a stale row must stay out of the scores
+    and out of the weighted sum, where a weight of zero would not keep a
+    NaN out; ``k_r`` is keys alone."""
+    dtype = LATENT[case][-1]
+    q_c, q_r, c, kr = _latent_operands(case, seed=2)
+    pos = jnp.asarray(LATENT_POSITIONS)
+
+    def fill(x, value):
+        past = _past_the_frontier(x.shape, LATENT_POSITIONS)
+        return jnp.where(past, jnp.asarray(value, dtype), x)
+
+    clean = fill(c, 0), fill(kr, 0)
+    leaves = (fill(c, stale) if dirty != "k_r" else clean[0],
+              fill(kr, stale) if dirty != "c" else clean[1])
+    got = cache_read.read_latent_by_position(q_c, q_r, *leaves, pos, SCALE)
+    _close(got, _latent_xla(q_c, q_r, *clean, pos), dtype)
+    # and to the bit what the same call gives on clean rows
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32),
+        np.asarray(cache_read.read_latent_by_position(
+            q_c, q_r, *clean, pos, SCALE), np.float32))
+
+
+def test_latent_positions_past_the_leaf_read_all_of_it(interpret_pallas):
+    q_c, q_r, c, kr = _latent_operands("rank128-bf16", seed=5)
+    pos = jnp.asarray([3, LS, LS + 7, 2, 0, LS - 1], jnp.int32)
+    _close(cache_read.read_latent_by_position(q_c, q_r, c, kr, pos, SCALE),
+           _latent_xla(q_c, q_r, c, kr, pos), jnp.bfloat16)
+
+
+def _latent_read(case):
+    """``latent_attention`` on a latent pair, varied by ``case``; returns
+    (result, the read paths it noted, the einsums' result alone)."""
+    q_c, q_r, c, kr = _latent_operands("rank128-bf16", seed=7)
+    pos = jnp.asarray(LATENT_POSITIONS)
+    if case == "scalar-position":
+        pos = jnp.int32(9)
+    elif case == "two-tokens":
+        q_c, q_r = (jnp.concatenate([x, x + 1], axis=1) for x in (q_c, q_r))
+        pos = jnp.minimum(pos, LS - 2)
+    elif case == "short-leaf":        # a block and a half
+        c, kr = (x[:, :LATENT_BLOCK * 3 // 2] for x in (c, kr))
+        pos = pos % c.shape[1]
+    elif case == "ragged-rank":       # 192: a tile and a half of lanes
+        q_c, c = (jnp.concatenate([x, x[..., :64]], -1) for x in (q_c, c))
+    elif case == "wide-rope":         # 128 rotated: row-major, not S on lanes
+        q_r, kr = (jnp.tile(x, (1, 1, 1, 8)) for x in (q_r, kr))
+    elif case == "ragged-heads":      # 12 query heads: no whole bf16 tile
+        q_c, q_r = (x[:, :, :12] for x in (q_c, q_r))
+    elif case == "two-dtypes":
+        kr = kr.astype(jnp.float32)
+    elif case == "f32-query":         # the kernel takes any query dtype
+        q_c, q_r = (x.astype(jnp.float32) for x in (q_c, q_r))
+    with kv_cache.cache_paths() as paths:
+        got = kv_cache.latent_attention(q_c, q_r, c, kr, pos, SCALE)
+    assert paths["write"] == set()
+    return got, paths["read"], kv_cache._latent_read_whole(q_c, q_r, c, kr,
+                                                           pos, SCALE)
+
+
+@pytest.mark.parametrize("case,path", [
+    ("plain", "kernel"), ("f32-query", "kernel"), ("scalar-position", "xla"),
+    ("two-tokens", "xla"), ("short-leaf", "xla"), ("ragged-rank", "xla"),
+    ("wide-rope", "xla"), ("ragged-heads", "xla"), ("two-dtypes", "xla")])
+def test_latent_gate_on_a_tpu(as_on_tpu, case, path):
+    got, paths, want = _latent_read(case)
+    assert paths == {path}
+    assert bool(as_on_tpu) == (path == "kernel")      # a kernel was traced
+    assert got.dtype == want.dtype and got.shape == want.shape
+    if path == "xla":                 # letter for letter
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+    else:                             # the einsums round the weights to bf16 too
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   atol=2e-2, rtol=0)
+
+
+def test_latent_gate_on_the_cpu_keeps_xla(interpret_pallas):
+    got, paths, want = _latent_read("plain")
+    assert paths == {"xla"} and interpret_pallas == []
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("devices,path", [(2, "xla"), (1, "kernel")])
+def test_latent_gate_under_a_mesh(as_on_tpu, devices, path):
+    from paddle_tpu.distributed.mesh import init_mesh, set_mesh
+
+    init_mesh(devices=jax.devices()[:devices], dp=devices)
+    try:
+        _, paths, _ = _latent_read("plain")
+    finally:
+        set_mesh(None)
+    assert paths == {path} and bool(as_on_tpu) == (path == "kernel")
+
+
 # ------------------------------------------------------------- the engine
-def _decode(model, cfg, steps=5):
-    eng = ContinuousBatchingEngine(model, slots=3, max_length=128,
+def _decode(model, cfg, steps=5, max_length=128):
+    eng = ContinuousBatchingEngine(model, slots=3, max_length=max_length,
                                    prefill_buckets=(32,))
     assert eng.cache_stats()["cache_read"] is None       # nothing traced
     rng = np.random.default_rng(4)
@@ -261,6 +424,31 @@ def test_engine_with_grouped_heads_decodes_the_same_tokens_on_either_path(
     direct, eng = _decode(model, cfg)
     assert eng.cache_stats()["cache_read"] == "kernel"
     assert "_columns_kernel" in interpret_pallas
+    assert direct == plain
+    assert all(len(t) == 6 for t in direct)
+
+
+def test_engine_with_a_latent_entry_decodes_the_same_tokens_on_either_path(
+        show_the_gate_a_tpu, interpret_pallas):
+    """Xing tiny at widths the latent body takes (a rank of 128, 8 query
+    heads in f32, a cache of one block): one key a position under every
+    head, YaRN's scale past 32 positions."""
+    from paddle_tpu.models.xing import XingForCausalLM, xing_tiny
+
+    pt.seed(3)
+    cfg = xing_tiny(num_heads=8, kv_lora_rank=128,
+                    max_position_embeddings=LATENT_BLOCK)
+    model = XingForCausalLM(cfg)
+    model.eval()
+    plain, eng = _decode(model, cfg, max_length=LATENT_BLOCK)
+    assert eng.cache_stats()["cache_read"] == "xla"
+    assert eng.cache_stats()["cache_entry"] == "latent"
+    assert interpret_pallas == []
+    show_the_gate_a_tpu()
+    direct, eng = _decode(model, cfg, max_length=LATENT_BLOCK)
+    assert eng.cache_stats()["cache_read"] == "kernel"
+    assert eng.cache_stats()["cache_write"] == "scatter"    # no kernel yet
+    assert set(interpret_pallas) == {"_shared_key_kernel"}
     assert direct == plain
     assert all(len(t) == 6 for t in direct)
 

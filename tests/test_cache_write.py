@@ -4,6 +4,7 @@ Pallas interpret mode, bit for bit over the whole leaf; the gate that
 chooses between the two; and engines decoding the same tokens either way
 (with the read's kernel, ``tests/test_cache_read.py``, beside the
 write's). On the CPU the programs themselves always take the scatter."""
+import re
 from unittest import mock
 
 import jax
@@ -229,11 +230,13 @@ def test_a_latent_layers_write_and_read_compile_for_the_chip_in_place(
         one_chip, show_the_gate_a_tpu):
     """``xing4.0-29b-a4b.serve-reason``'s cache pair, ``(c [32, 8192, 1,
     512], k_r [32, 8192, 1, 64])``, through ``attend_with_latent_cache``
-    as the decode program runs it: one head is no whole tile, so both
-    gates keep XLA's paths (the scatter's ``while`` a leaf, the read of
-    the whole leaf under the mask), the leaves alias their outputs, and
-    a leaf with one head is held without padding: the program's arguments
-    are the leaves' own bytes."""
+    as the decode program runs it: one head is no whole tile, so the
+    write keeps the scatter (a ``while`` a leaf); the read is the latent
+    body's kernel, handed each leaf as the chip keeps it (``c`` row-major,
+    ``k_r`` with S on the lanes: bitcasts, no copy or transpose of a
+    leaf); the leaves alias their outputs, and a leaf with one head is
+    held without padding: the program's arguments are the leaves' own
+    bytes."""
     show_the_gate_a_tpu()
     slots, length, heads, rank, rope, nope = 32, 8192, 32, 512, 64, 128
 
@@ -252,17 +255,28 @@ def test_a_latent_layers_write_and_read_compile_for_the_chip_in_place(
             arg((slots, 1, heads, rope)), arg((slots, 1, rank)),
             arg((slots, 1, 1, rope)), arg((rank, heads, nope)),
             arg((rank, heads, nope)), arg((slots,), jnp.int32)).compile()
-    assert paths == {"write": {"scatter"}, "read": {"xla"}}
+    assert paths == {"write": {"scatter"}, "read": {"kernel"}}
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text and text.count(" while(") == 2
+    assert text.count("tpu_custom_call") == 1
+    assert "cache_read_latent_by_position" in text
+    assert text.count(" while(") == 2
+    # a leaf reaches the kernel relabelled, never moved: the only
+    # instructions that yield something of a leaf's size are the entry's
+    # parameters, the scatters' loops and bitcasts
+    moved = [line.split(" = ")[0].strip() for line in text.splitlines()
+             if re.search(r" = bf16\[32,(8192,(1,)?(512|64)|64,8192)\]", line)
+             and not re.search(r" (parameter|bitcast|while|get-tuple-element|"
+                               r"dynamic-update-slice)\(", line)]
+    assert moved == []
     memory = compiled.memory_analysis()
     leaf_bytes = sum(2 * int(np.prod(shape)) for shape in leaves)
     assert leaf_bytes == 32 * 8192 * 1152
     assert memory.alias_size_in_bytes == leaf_bytes
     # the leaves, two 4 MB up-projections and the step's rows
     assert memory.argument_size_in_bytes < leaf_bytes * 1.05
-    # float32 scores [32, 32, 1, 8192] and their softmax, not a leaf
-    assert memory.temp_size_in_bytes < leaf_bytes // 4
+    # the queries and the output, a megabyte each: no scores over 8192
+    # positions, not a leaf
+    assert memory.temp_size_in_bytes < leaf_bytes // 256
 
 
 @pytest.mark.parametrize("rows", [32, 2048])

@@ -111,7 +111,8 @@ def test_latent_logits_check_tiny():
     and with 8 experts most positions are far from a tie."""
     out = chip_smoke.latent_logits_check(
         _latent_tiny(), seed=2 ** 31 + 11, slots=3, length=64, bucket=32,
-        prompt_lens=(30, 9), steps=4, clean_bound=1e-4, tie_bound=1e-4)
+        prompt_lens=(30, 9), steps=4, expect_cache_read="xla",
+        clean_bound=1e-4, tie_bound=1e-4)
     assert out["positions"] == 10 and out["argmax_agree"] == 10
     assert out["clean"] >= 5 and out["rms_worst"] < 1e-5
 
@@ -130,7 +131,7 @@ def test_latent_logits_check_notices_another_rows_cache(monkeypatch):
     with pytest.raises(chip_smoke.CheckFailed, match="latent geometry"):
         chip_smoke.latent_logits_check(
             _latent_tiny(), seed=5, slots=2, length=64, bucket=32,
-            prompt_lens=(20, 7), steps=4)
+            prompt_lens=(20, 7), steps=4, expect_cache_read="xla")
 
 
 def test_latent_logits_check_holds_a_near_tie_to_its_own_bound():
@@ -140,7 +141,18 @@ def test_latent_logits_check_holds_a_near_tie_to_its_own_bound():
     with pytest.raises(chip_smoke.CheckFailed, match="holds too few"):
         chip_smoke.latent_logits_check(
             _latent_tiny(), seed=5, slots=2, length=64, bucket=32,
-            prompt_lens=(20, 7), steps=4, tie_margin=2.0)
+            prompt_lens=(20, 7), steps=4, expect_cache_read="xla",
+            tie_margin=2.0)
+
+
+def test_latent_logits_check_notices_the_whole_leaf_read():
+    """On the CPU the decode step keeps XLA's einsums: the check as the
+    chip runs it, which expects the kernel, must fail."""
+    with pytest.raises(chip_smoke.CheckFailed,
+                       match=r"reads its cache by \['xla'\]"):
+        chip_smoke.latent_logits_check(
+            _latent_tiny(), seed=5, slots=2, length=64, bucket=32,
+            prompt_lens=(20, 7), steps=4)
 
 
 def _hybrid_tiny():
@@ -275,15 +287,30 @@ def test_serve_phase_notices_the_whole_leaf_read():
 
 def test_cache_read_check_tiny(as_on_tpu):
     chip_smoke.cache_read_check(
-        leaves=((3, 256, 4, 64), (3, 128, 16, 128), (2, 3, 128, 16, 128)))
-    assert sorted(set(as_on_tpu)) == ["_columns_kernel", "_rows_kernel"]
+        leaves=((3, 256, 4, 64), (3, 128, 16, 128), (2, 3, 128, 16, 128)),
+        latent=((3, 1024, 16, 128, 16),))
+    assert sorted(set(as_on_tpu)) == ["_columns_kernel", "_rows_kernel",
+                                      "_shared_key_kernel"]
 
 
 def test_cache_read_check_holds_the_kernel_to_its_bound(as_on_tpu,
                                                         monkeypatch):
     monkeypatch.setattr(chip_smoke, "_bf16_step", lambda x: 1e-9)
     with pytest.raises(chip_smoke.CheckFailed, match="more than a bf16 step"):
-        chip_smoke.cache_read_check(leaves=((3, 128, 16, 128),))
+        chip_smoke.cache_read_check(leaves=((3, 128, 16, 128),), latent=())
+
+
+def test_cache_read_check_holds_the_latent_kernel_to_its_bound(as_on_tpu,
+                                                               monkeypatch):
+    monkeypatch.setattr(chip_smoke, "_bf16_step", lambda x: 1e-9)
+    with pytest.raises(chip_smoke.CheckFailed, match="more than a bf16 step"):
+        chip_smoke.cache_read_check(leaves=(), latent=((3, 512, 16, 128, 16),))
+
+
+def test_cache_read_check_notices_a_latent_pair_the_gate_refuses(as_on_tpu):
+    """A length that is no multiple of the latent body's block."""
+    with pytest.raises(chip_smoke.CheckFailed, match="refuses a latent pair"):
+        chip_smoke.cache_read_check(leaves=(), latent=((3, 384, 16, 128, 16),))
 
 
 @pytest.fixture(scope="module")
